@@ -1,0 +1,500 @@
+"""The quickest proof that the system still starts on the chip.
+
+`python chip_smoke.py` (no arguments) drives the device path once, through
+the entry points a user would call, at the widths the repo ships as default:
+
+  trainer   `python -m dragonfly2_tpu.trainer.server` — the one process that
+            owns the accelerator — is started as a child, fed synthetic
+            telemetry over the real RPC surface (train_open / train_chunk /
+            train_close), and trains the MLP (256, 256, 128) and the GNN
+            (hidden 256, embed 128, 3 layers, batch 4096) on a 1,024-host
+            K=16 graph. Only the step counts are cut. Checked: run status ok,
+            both models in last_result, every loss finite, no swallowed
+            training or native-export error, and on several devices the Dense
+            kernels split over them, not copied. The child is stopped by PID
+            and waited for before anything else opens the chip.
+  artifacts params.msgpack + graph.npz + scorer.dfsc on disk.
+  scorer    (child, pinned to the host CPU) the produced scorer.dfsc scores
+            one 40-candidate round through NativeScorer in agreement with
+            GNNScorer.
+  device    (child, opens the chip) tpuvm/staging.py: a safetensors file from
+            a seed is staged unsharded and under a NamedSharding over all
+            local devices, pulled back and compared bit for bit. On more than
+            one device it also drives train_async under {data: n} so node
+            rows and the pair batch are seen to span them.
+  platform  the platform the TRAINER PROCESS reported must be "tpu".
+
+This parent never imports jax: a parent that has touched JAX holds the chip
+and a child that needs it then fails or hangs. On success the last line of
+stdout is one JSON object, `{"ok": true, "device": {...}, ...}`, and the exit
+code is 0. On any failure nothing is written to stdout's last line as a
+result: the same summary goes to stderr with "ok": false and the exit code is
+non-zero. The size flags exist for the CPU test (tests/test_chip_smoke.py)
+and for the second shape (`--hosts 16384 --gnn-hidden 512`).
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import json
+import math
+import os
+import signal
+import subprocess
+import sys
+import tempfile
+import time
+from pathlib import Path
+
+HERE = Path(__file__).resolve().parent
+RESULT_PREFIX = "CHIP_SMOKE_CHILD "
+# one wall-clock budget for the whole smoke (the contract allows 1200 s)
+BUDGET_S = 1100.0
+
+
+def _log(msg: str) -> None:
+    print(f"chip_smoke: {msg}", file=sys.stderr, flush=True)
+
+
+def _cache_entries(cache_dir: Path) -> int:
+    return sum(1 for p in cache_dir.rglob("*") if p.is_file()) if cache_dir.is_dir() else 0
+
+
+def _spawn(args: list[str], log_path: Path) -> subprocess.Popen:
+    """Start a child in its own process group, output to a log file."""
+    with open(log_path, "wb") as log:
+        return subprocess.Popen(
+            [sys.executable, *args],
+            cwd=HERE, stdout=log, stderr=subprocess.STDOUT, start_new_session=True,
+        )
+
+
+def _stop(proc: subprocess.Popen) -> None:
+    """Stop a child by its PID and wait for it: SIGTERM, then SIGKILL to the
+    whole group — nothing this script started outlives it."""
+    if proc.poll() is None:
+        proc.send_signal(signal.SIGTERM)
+        try:
+            proc.wait(timeout=30)
+        except subprocess.TimeoutExpired:
+            pass
+    try:
+        os.killpg(proc.pid, signal.SIGKILL)
+    except ProcessLookupError:
+        pass
+    proc.wait()
+
+
+def _tail(path: Path, n: int = 25) -> str:
+    try:
+        return "\n".join(path.read_text(errors="replace").splitlines()[-n:])
+    except OSError:
+        return ""
+
+
+def _run_child(name: str, args: list[str], tmp: Path, *, timeout: float) -> dict:
+    """Run one `chip_smoke.py --child ...` to completion; its result is the
+    JSON after RESULT_PREFIX on its last matching line."""
+    log_path = tmp / f"{name}.log"
+    proc = _spawn([str(HERE / "chip_smoke.py"), *args], log_path)
+    try:
+        proc.wait(timeout=timeout)
+    except subprocess.TimeoutExpired:
+        return {"ok": False, "error": f"{name} child exceeded {timeout:.0f}s"}
+    finally:
+        _stop(proc)
+    for line in reversed(log_path.read_text(errors="replace").splitlines()):
+        if line.startswith(RESULT_PREFIX):
+            return json.loads(line[len(RESULT_PREFIX):])
+    return {"ok": False, "error": f"{name} child rc={proc.returncode}:\n{_tail(log_path)}"}
+
+
+# ---- phase: trainer (parent side: RPC only) --------------------------------
+
+
+async def _drive_trainer(addr: str, args: argparse.Namespace, deadline: float) -> dict:
+    from dragonfly2_tpu.rpc.trainer import RemoteTrainerClient
+    from dragonfly2_tpu.scheduler.announcer import CHUNK_ROWS
+    from dragonfly2_tpu.trainer.synthetic import synth_telemetry_records
+
+    client = RemoteTrainerClient(addr)
+    try:
+        downloads, probes = synth_telemetry_records(
+            args.downloads, args.probes, args.hosts, seed=args.seed
+        )
+        token = await client.train_open("chip-smoke", 0)
+        for kind, arr in (("downloads", downloads), ("probes", probes)):
+            for start in range(0, len(arr), CHUNK_ROWS):
+                await client.train_chunk(  # dflint: disable=DF025 already batched: CHUNK_ROWS rows per trip, the announcer's own upload shape
+                    token, kind, arr[start : start + CHUNK_ROWS]
+                )
+        await client.train_close(token)
+        while True:
+            status = await client.status()
+            if status["trains_started"] >= 1 and not status["training"]:
+                break
+            if time.monotonic() > deadline:
+                return {"ok": False, "error": "training did not finish in time", "status": status}
+            await asyncio.sleep(1.0)
+        history = await client.train_history(limit=1)
+    finally:
+        await client.close()
+    return {"status": status, "run": (history["runs"] or [None])[0]}
+
+
+def _check_trainer(out: dict) -> dict:
+    """Everything the trainer's own report must say for the phase to pass."""
+    if "error" in out:
+        return out
+    status, run = out["status"], out["run"]
+    device = {k: status.get(k) for k in ("platform", "device_kind", "device_count")}
+    if run is None:
+        return {"ok": False, "error": "trainer kept no run manifest", "device": device}
+    result = status["last_result"] or {}
+    problems: list[str] = []
+    if run["status"] != "ok":
+        problems.append(f"run status {run['status']!r}, error {run['error']!r}")
+    losses: dict[str, list] = {}
+    for m in ("mlp", "gnn"):
+        info = run["models"].get(m)
+        if m not in result or info is None:
+            problems.append(f"no {m} in last_result")
+            continue
+        values = [v for _, v in info["curve"]] + [info["final_loss"]]
+        losses[m] = [info["steps"], info["final_loss"]]
+        if not all(isinstance(v, float) and math.isfinite(v) for v in values):
+            problems.append(f"{m}: non-finite or missing loss")
+        if info["native_export_error"]:
+            problems.append(f"native export failed: {info['native_export_error']}")
+    placement = (run["models"].get("gnn") or {}).get("placement")
+    if placement:
+        # Dense kernels split over "model": each device of the mesh holds
+        # bytes / model_parallel — four copies or one device would not
+        kernels, mp = placement["kernels"], placement["mesh"]["model"]
+        per_dev = kernels["per_device_bytes"]
+        if len(per_dev) != device["device_count"] or any(b * mp != kernels["bytes"] for b in per_dev):
+            problems.append(f"kernels not split {mp} ways over the devices: {kernels}")
+    else:
+        problems.append("run manifest carries no mesh/placement")
+    return {
+        "ok": not problems, "problems": problems, "device": device,
+        "mesh": placement and placement["mesh"], "placement": placement, "losses": losses,
+        "gnn_artifact": (result.get("gnn") or {}).get("artifact"),
+        "mlp_artifact": (result.get("mlp") or {}).get("artifact"),
+        "wall_s": run["wall_s"],
+        "device_peak_bytes": run["device_peak_bytes"],
+    }
+
+
+def _trainer_phase(args: argparse.Namespace, tmp: Path, deadline: float) -> dict:
+    log_path = tmp / "trainer.log"
+    server_args = [
+        "-m", "dragonfly2_tpu.trainer.server", "--port", "0",
+        "--model-dir", str(tmp / "models"),
+        "--gnn-steps", str(args.gnn_steps), "--mlp-steps", str(args.mlp_steps),
+    ]
+    if args.gnn_hidden is not None:
+        server_args += ["--gnn-hidden", str(args.gnn_hidden)]
+    proc = _spawn(server_args, log_path)
+    try:
+        addr = None
+        while addr is None:
+            for line in log_path.read_text(errors="replace").splitlines():
+                if line.startswith("TRAINER_READY "):
+                    addr = line.split()[1]
+            if addr is None:
+                if proc.poll() is not None:
+                    return {"ok": False, "error": f"trainer exited rc={proc.returncode}:\n{_tail(log_path)}"}
+                if time.monotonic() > deadline:
+                    return {"ok": False, "error": f"trainer not ready in time:\n{_tail(log_path)}"}
+                time.sleep(0.5)
+        _log(f"trainer pid {proc.pid} ready at {addr}")
+        out = _check_trainer(asyncio.run(_drive_trainer(addr, args, deadline)))
+        if not out["ok"]:
+            out["log_tail"] = _tail(log_path)
+        return out
+    finally:
+        # by PID, and waited for: the chip is free before the next phase
+        _stop(proc)
+
+
+def _artifacts_phase(trainer: dict) -> dict:
+    missing = []
+    for key, names in (
+        ("mlp_artifact", ("params.msgpack",)),
+        ("gnn_artifact", ("params.msgpack", "graph.npz", "scorer.dfsc")),
+    ):
+        d = trainer.get(key)
+        missing += [f"{key}/{n}" for n in names if not d or not (Path(d) / n).is_file()]
+    return {"ok": not missing, "missing": missing}
+
+
+# ---- children (these import jax) -------------------------------------------
+
+
+def _child_scorer(artifact: str) -> dict:
+    """NativeScorer vs GNNScorer on one 40-candidate round, host CPU."""
+    from dragonfly2_tpu.utils import jaxenv
+
+    jaxenv.pin_host_cpu()
+    import numpy as np
+
+    from dragonfly2_tpu.models.features import FEATURE_DIM
+    from dragonfly2_tpu.models.scorer import GNNScorer
+    from dragonfly2_tpu.trainer import artifacts
+
+    model, params = artifacts.load_gnn(artifact)
+    graph, _hosts = artifacts.load_graph(artifact)
+    jax_scorer = GNNScorer(model, params)
+    jax_scorer.refresh(graph)
+    native = artifacts.load_native(artifact)  # builds with g++; missing = failure
+    rng = np.random.default_rng(0)
+    n = jax_scorer.num_nodes
+    child = np.full(40, rng.integers(0, n), np.int32)
+    parent = rng.integers(0, n, size=40).astype(np.int32)
+    feats = rng.random((40, FEATURE_DIM)).astype(np.float32)
+    a = native.score(feats, child=child, parent=parent)
+    b = jax_scorer.score(feats, child=child, parent=parent)
+    err = float(np.max(np.abs(a - b)))
+    # bf16 JAX head vs f32 C++ head (the tolerance tests/test_native.py pins)
+    ok = bool(a.shape == (40,) and np.all(np.isfinite(a)) and np.all(np.isfinite(b)) and err <= 3e-2)
+    return {"ok": ok, "max_abs_diff": err, "nodes": n, **jaxenv.device_report()}
+
+
+def _child_device(tmp: str, stage_mib: int, seed: int) -> dict:
+    """Opens the accelerator: staging round trip, and on several devices the
+    data-parallel GNN run."""
+    from dragonfly2_tpu.utils import jaxenv
+
+    cache = jaxenv.enable_compile_cache()
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dragonfly2_tpu.tpuvm import safetensors as stlib
+    from dragonfly2_tpu.tpuvm.staging import stage_tensors
+
+    out: dict = {**jaxenv.device_report(), "cache_dir": str(cache)}
+    n_dev = out["device_count"]
+    rng = np.random.default_rng(seed)
+    rows = max(n_dev * 8, stage_mib * (1 << 20) // (4096 * 2))
+    rows -= rows % (n_dev * 8)
+    # finite bf16 bit patterns (exponent never all-ones): bits must survive
+    # H2D + D2H exactly
+    tensors = {
+        "w_bf16": rng.integers(0, 1 << 16, (rows, 4096), dtype=np.uint16) & np.uint16(0xBFFF),
+        "bias_f32": rng.standard_normal(4096).astype(np.float32),
+        "table_f32": rng.standard_normal((n_dev * 16, 24)).astype(np.float32),
+        "ids_i32": rng.integers(-(1 << 31), (1 << 31) - 1, (n_dev * 4, 7)).astype(np.int32),
+        "flags_u8": rng.integers(0, 256, 3, dtype=np.uint8),
+    }
+    path = stlib.write_safetensors(Path(tmp) / "smoke.safetensors", tensors, bf16_names=["w_bf16"])
+    mesh = Mesh(np.asarray(jax.local_devices()), ("x",))
+    row_sharded = NamedSharding(mesh, P("x"))
+
+    def sharding_for(name: str):
+        return row_sharded if tensors[name].shape[0] % n_dev == 0 else NamedSharding(mesh, P())
+
+    mismatched = []
+    for label, shardings in (("unsharded", None), ("sharded", sharding_for)):
+        staged = stage_tensors(path, shardings=shardings)
+        for name, want in tensors.items():
+            got = np.asarray(staged[name])  # dflint: disable=DF033 one D2H pull per staged tensor is the check itself
+            if name == "w_bf16":
+                got = got.view(np.uint16)
+            if got.shape != want.shape or got.tobytes() != want.tobytes():
+                mismatched.append(f"{label}:{name}")
+        if shardings is not None:
+            out["sharded_w_devices"] = len({s.device.id for s in staged["w_bf16"].addressable_shards})
+            if out["sharded_w_devices"] != n_dev:
+                mismatched.append("sharded:w_bf16 does not span every device")
+        del staged
+    out["staged_bytes"] = int(sum(t.nbytes for t in tensors.values()))
+    out["mismatched"] = mismatched
+    out["pallas"] = _pallas_check(out["platform"] == "tpu")
+    ok = not mismatched and all(r["ok"] for r in out["pallas"].values())
+
+    if n_dev > 1:
+        out["data_parallel"] = _data_parallel_run(n_dev)
+        ok = ok and out["data_parallel"]["ok"]
+    return {"ok": ok, **out}
+
+
+def _pallas_check(compiled: bool) -> dict:
+    """neighbor_aggregate_pallas against the XLA path, forward and VJP, at
+    1,024x256 and at the largest shape supports_pallas admits (K=16). On the
+    chip the kernel is compiled by Mosaic (interpret=False); anywhere else
+    only the interpreter exists, and supports_pallas admits nothing, so the
+    second shape collapses to one tile."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dragonfly2_tpu.ops import neighbor_agg_pallas as pk
+    from dragonfly2_tpu.ops.neighbor_agg import masked_mean, neighbor_gather
+
+    n_budget = pk.TILE_N
+    while pk.supports_pallas(jax.ShapeDtypeStruct((n_budget + pk.TILE_N, 256), jnp.float32)):
+        n_budget += pk.TILE_N
+    # f32 states through the MXU at default precision are rounded to bf16
+    # (2^-9 relative) before the A@h product; means of |h| <~ 4.5 then differ
+    # from the XLA gather path by < 1e-2. A wrong gather or mask is O(1).
+    atol = 2e-2
+    out = {}
+    for n in (1024, n_budget):
+        rng = np.random.default_rng(n)
+        h = jnp.asarray(rng.standard_normal((n, 256)), jnp.float32)
+        nbr = jnp.asarray(rng.integers(0, n, (n, 16)), jnp.int32)
+        mask = jnp.asarray(rng.random((n, 16)) < 0.8, jnp.float32)
+
+        def pallas(a):
+            return pk.neighbor_aggregate_pallas(a, nbr, mask, interpret=not compiled)
+
+        def xla(a):
+            return masked_mean(neighbor_gather(a, nbr), mask)
+
+        fwd = float(jnp.max(jnp.abs(jax.jit(pallas)(h) - jax.jit(xla)(h))))
+        vjp = float(jnp.max(jnp.abs(
+            jax.jit(jax.grad(lambda a: jnp.sum(pallas(a) ** 2)))(h)
+            - jax.jit(jax.grad(lambda a: jnp.sum(xla(a) ** 2)))(h)
+        )))
+        out[f"{n}x256"] = {
+            "ok": fwd <= atol and vjp <= atol, "compiled": compiled,
+            "fwd_max_err": fwd, "vjp_max_err": vjp,
+        }
+    return out
+
+
+def _data_parallel_run(n_dev: int) -> dict:
+    """train_async under make_mesh(model_parallel=1) ({data: n}): node rows
+    and the pair batch must span the devices, a 1/n share each."""
+    import numpy as np
+
+    from dragonfly2_tpu.parallel import mesh as meshlib
+    from dragonfly2_tpu.trainer import synthetic, train_gnn
+    from dragonfly2_tpu.trainer.metrics import TrainRunTelemetry
+
+    cfg = train_gnn.GNNTrainConfig()
+    cluster = synthetic.make_cluster(num_nodes=1024, num_neighbors=16, num_pairs=65536, seed=0)
+    tel = TrainRunTelemetry("gnn", batch_size=cfg.batch_size)
+    _state, losses = asyncio.run(train_gnn.train_async(
+        cfg, cluster.graph, cluster.pairs, steps=20,
+        mesh=meshlib.make_mesh(model_parallel=1), telemetry=tel,
+    ))
+    p = tel.placement
+    graph, rows = p["graph"], p["batch_rows_per_device"]
+    ok = (
+        all(np.isfinite(losses))
+        and p["mesh"] == {"data": n_dev, "model": 1}
+        and len(graph["per_device_bytes"]) == n_dev
+        and all(b * n_dev == graph["bytes"] for b in graph["per_device_bytes"])
+        and rows * n_dev == cfg.batch_size
+    )
+    return {"ok": bool(ok), "placement": p, "steps": len(losses), "final_loss": losses[-1]}
+
+
+def _child_main(argv: list[str]) -> int:
+    kind, rest = argv[0], argv[1:]
+    try:
+        if kind == "scorer":
+            out = _child_scorer(rest[0])
+        elif kind == "device":
+            out = _child_device(rest[0], int(rest[1]), int(rest[2]))
+        else:
+            raise SystemExit(f"unknown child {kind!r}")
+    except Exception as e:  # the parent reports it; the traceback is in the child's log
+        import traceback
+
+        traceback.print_exc()
+        out = {"ok": False, "error": f"{type(e).__name__}: {e}"[:2000]}
+    print(RESULT_PREFIX + json.dumps(out), flush=True)
+    return 0 if out["ok"] else 1
+
+
+# ---- parent ----------------------------------------------------------------
+
+
+def main(argv: list[str] | None = None) -> int:
+    argv = sys.argv[1:] if argv is None else argv
+    if argv[:1] == ["--child"]:
+        return _child_main(argv[1:])
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--hosts", type=int, default=1024)
+    ap.add_argument("--downloads", type=int, default=65536)
+    ap.add_argument("--probes", type=int, default=40000)
+    ap.add_argument("--gnn-steps", type=int, default=30)
+    ap.add_argument("--mlp-steps", type=int, default=200)
+    ap.add_argument("--gnn-hidden", type=int, default=None,
+                    help="override the GNN width (default: TrainerConfig's 256)")
+    ap.add_argument("--stage-mib", type=int, default=64)
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+
+    try:
+        from dragonfly2_tpu.utils import jaxenv
+    except ImportError as e:
+        _log(f"the repository is not beside this script: {e}")
+        return 1
+    t0 = time.monotonic()
+    deadline = t0 + BUDGET_S
+    cache_dir = jaxenv.compile_cache_dir()
+    cache = {"dir": str(cache_dir), "entries_before": _cache_entries(cache_dir)}
+    _log(f"compile cache {cache_dir}: {cache['entries_before']} entries before")
+
+    phases: dict[str, dict] = {}
+    with tempfile.TemporaryDirectory(prefix="chip-smoke-") as td:
+        tmp = Path(td)
+        phases["trainer"] = trainer = _trainer_phase(args, tmp, deadline)
+        _log(f"trainer: {'ok' if trainer['ok'] else trainer}")
+        phases["artifacts"] = _artifacts_phase(trainer)
+        if phases["artifacts"]["ok"]:
+            phases["scorer"] = _run_child(
+                "scorer", ["--child", "scorer", trainer["gnn_artifact"]], tmp,
+                timeout=max(30.0, min(300.0, deadline - time.monotonic())),
+            )
+        else:
+            phases["scorer"] = {"ok": False, "error": "no artifact to score"}
+        _log(f"scorer: {phases['scorer']}")
+        phases["device"] = _run_child(
+            "device", ["--child", "device", str(tmp), str(args.stage_mib), str(args.seed)], tmp,
+            timeout=max(30.0, min(400.0, deadline - time.monotonic())),
+        )
+        _log(f"device: {phases['device']}")
+
+    device = trainer.get("device") or {}
+    phases["platform"] = {
+        "ok": device.get("platform") == "tpu",
+        "trainer_reported": device.get("platform"),
+    }
+    cache["entries_after"] = _cache_entries(cache_dir)
+    _log(f"compile cache {cache_dir}: {cache['entries_after']} entries after")
+    ok = all(p["ok"] for p in phases.values())
+    summary = {
+        "ok": ok,
+        "device": {
+            "platform": device.get("platform"),
+            "kind": device.get("device_kind"),
+            "count": device.get("device_count"),
+        },
+        "platform": device.get("platform"),
+        "device_kind": device.get("device_kind"),
+        "device_count": device.get("device_count"),
+        "mesh": trainer.get("mesh"),
+        "phases": {name: ("ok" if p["ok"] else "FAILED") for name, p in phases.items()},
+        "detail": phases,
+        "compile_cache": cache,
+        "wall_s": round(time.monotonic() - t0, 1),
+        "claim": None,
+    }
+    if ok:
+        print(json.dumps(summary), flush=True)
+        return 0
+    failed = [name for name, p in phases.items() if not p["ok"]]
+    _log(f"FAILED phases: {failed}")
+    print(json.dumps(summary), file=sys.stderr, flush=True)
+    return 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

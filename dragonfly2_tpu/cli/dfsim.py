@@ -196,6 +196,9 @@ def _print_human(out: dict) -> None:
 
 
 def main(argv: list[str] | None = None) -> int:
+    from dragonfly2_tpu.utils import jaxenv
+
+    jaxenv.pin_host_cpu()  # host-side process: never opens the accelerator
     ap = argparse.ArgumentParser(
         prog="dfsim", description="discrete-event swarm simulator (virtual clock)"
     )

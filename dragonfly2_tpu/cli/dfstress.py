@@ -134,8 +134,6 @@ async def run_scoring_stress(args: argparse.Namespace) -> dict:
     from pathlib import Path
 
     import jax
-
-    jax.config.update("jax_platforms", "cpu")  # artifact precompute only
     import jax.numpy as jnp
 
     from dragonfly2_tpu.models.graphsage import TopoGraph
@@ -676,6 +674,9 @@ async def run_swarm_stress(args: argparse.Namespace) -> dict:
 
 
 def main(argv: list[str] | None = None) -> int:
+    from dragonfly2_tpu.utils import jaxenv
+
+    jaxenv.pin_host_cpu()  # host-side process: never opens the accelerator
     ap = argparse.ArgumentParser(description="dragonfly2_tpu daemon load generator")
     ap.add_argument("url", nargs="?", default=None,
                     help="source URL to download repeatedly (download mode)")

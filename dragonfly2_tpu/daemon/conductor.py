@@ -113,7 +113,7 @@ class PieceReportBuffer:
         self._buf: list[tuple[int, float, str]] = []
         self._lock = asyncio.Lock()  # serializes flushes (ordering + no double-take)
         self._flusher: asyncio.Task | None = None
-        # events are created in __init__ (lazily loop-bound on 3.10), set by
+        # events are created in __init__ (asyncio binds them to a loop lazily), set by
         # add(): _wake = "buffer went non-empty", _full = "size trigger hit"
         self._wake = asyncio.Event()
         self._full = asyncio.Event()

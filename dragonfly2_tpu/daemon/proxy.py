@@ -302,8 +302,7 @@ class ProxyServer:
         await writer.drain()
         loop = asyncio.get_running_loop()
         try:
-            # Server-side TLS upgrade on the accepted stream. 3.10 has no
-            # StreamWriter.start_tls (3.11+) — replicate it with the loop
+            # Server-side TLS upgrade on the accepted stream through the loop
             # API + transport rewire, same idiom as SniProxy._handle_hijack.
             transport = await loop.start_tls(
                 writer.transport, writer.transport.get_protocol(), ctx,

@@ -231,8 +231,7 @@ class RawRangeClient:
         `on_chunk(filled)` fires on the event loop after each recv with the
         total bytes landed so far — the hash-on-receive hook. Raises IOError
         on any other status or a short body, and builtin TimeoutError past
-        `timeout` (on this image's 3.10, asyncio.TimeoutError is a separate
-        class — callers match the builtin, and as an OSError subclass it
+        `timeout` (callers match the builtin, and as an OSError subclass it
         also rides every IOError retry path)."""
         try:
             await asyncio.wait_for(

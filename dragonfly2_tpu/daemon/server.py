@@ -572,7 +572,10 @@ def main() -> None:
     import sys
 
     from dragonfly2_tpu.daemon.config import DaemonYaml
+    from dragonfly2_tpu.utils import jaxenv
     from dragonfly2_tpu.utils.config import ConfigError, load_config
+
+    jaxenv.pin_host_cpu()  # host-side process: never opens the accelerator
 
     # Two-stage parse (the reference's cobra/viper layering): --config loads
     # the validated YAML, whose values become the flag DEFAULTS.

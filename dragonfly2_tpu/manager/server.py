@@ -144,7 +144,10 @@ def main() -> None:
     import sys
 
     from dragonfly2_tpu.manager.config import ManagerYaml
+    from dragonfly2_tpu.utils import jaxenv
     from dragonfly2_tpu.utils.config import ConfigError, load_config
+
+    jaxenv.pin_host_cpu()  # host-side process: never opens the accelerator
 
     pre = argparse.ArgumentParser(add_help=False)
     pre.add_argument("--config", default=None, help="YAML config file (flags override)")

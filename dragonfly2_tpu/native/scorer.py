@@ -3,7 +3,8 @@
 Flow (north-star config 5):
   1. trainer finishes → `export_scorer_artifact(params, z, path)` flattens the
      TopoScorer head weights + cached embeddings into scorer.cc's binary format
-  2. `build_native_lib()` compiles scorer.cc once (g++ -O3, cached by mtime)
+  2. `build_native_lib()` compiles scorer.cc once (g++ -O3; the library's
+     file name carries a hash of the source and flags)
   3. `NativeScorer(artifact)` loads both and serves `score()` with the same
      batch signature as models.scorer.GNNScorer — drop-in for the scheduler's
      `ml` evaluator slot, no JAX runtime on the hot path.
@@ -12,6 +13,7 @@ Flow (north-star config 5):
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import struct
@@ -28,7 +30,24 @@ _VERSION = 1
 _SRC = Path(__file__).with_name("scorer.cc")
 
 
-def _default_lib_path() -> Path:
+# compile flags are part of the library's identity (hashed into its file
+# name below). Variants are tried best → portable: native SIMD + OpenMP, then
+# native SIMD, then plain.
+_CXX_FLAGS = ["-O3", "-shared", "-fPIC", "-std=c++17", "-ffast-math", "-funroll-loops"]
+_CXX_VARIANTS = (["-march=native", "-fopenmp"], ["-march=native"], [])
+
+
+def lib_file_name(source: bytes) -> str:
+    """The shared library's file name for one scorer.cc: it carries a hash of
+    the source bytes and the compile flags, so two checkouts on one machine
+    (a parent and a change under measurement) never load each other's build,
+    and an existing file with this name is by construction up to date."""
+    h = hashlib.sha256(source)
+    h.update(repr((_CXX_FLAGS, _CXX_VARIANTS)).encode())
+    return f"libdfscorer-{h.hexdigest()[:16]}.so"
+
+
+def _cache_dir() -> Path:
     # per-user cache dir: the .so is CDLL-loaded, so a predictable path in a
     # world-writable tmp dir would be a cross-user code-injection vector
     override = os.environ.get("DRAGONFLY_NATIVE_CACHE")
@@ -39,20 +58,17 @@ def _default_lib_path() -> Path:
         cache = Path(xdg) / "dragonfly2_tpu_native"
     cache.mkdir(parents=True, exist_ok=True)
     os.chmod(cache, 0o700)
-    return cache / "libdfscorer.so"
+    return cache
 
 
-def build_native_lib(*, force: bool = False, lib_path: Path | None = None) -> Path:
-    """Compile scorer.cc → shared library (cached; rebuilt when stale)."""
-    lib = lib_path or _default_lib_path()
-    if not force and lib.exists() and lib.stat().st_mtime >= _SRC.stat().st_mtime:
+def build_native_lib(*, force: bool = False) -> Path:
+    """Compile scorer.cc → shared library, once per (source, flags) hash."""
+    lib = _cache_dir() / lib_file_name(_SRC.read_bytes())
+    if not force and lib.exists():
         return lib
-    lib.parent.mkdir(parents=True, exist_ok=True)
     tmp = lib.with_name(lib.name + f".{os.getpid()}.tmp")
-    base = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-ffast-math",
-            "-funroll-loops", "-o", str(tmp), str(_SRC)]
-    # best → portable: native SIMD + OpenMP, then native SIMD, then plain
-    for extra in (["-march=native", "-fopenmp"], ["-march=native"], []):
+    base = ["g++", *_CXX_FLAGS, "-o", str(tmp), str(_SRC)]
+    for extra in _CXX_VARIANTS:
         try:
             subprocess.run(base + extra, check=True, capture_output=True, text=True)
             break
@@ -109,8 +125,8 @@ class NativeScorer:
 
     engine = "native"  # serving-mode metric label
 
-    def __init__(self, artifact_path: str | Path, *, lib_path: Path | None = None):
-        lib = build_native_lib(lib_path=lib_path)
+    def __init__(self, artifact_path: str | Path):
+        lib = build_native_lib()
         self._dll = ctypes.CDLL(str(lib))
         self._dll.df_scorer_load.restype = ctypes.c_void_p
         self._dll.df_scorer_load.argtypes = [ctypes.c_char_p]

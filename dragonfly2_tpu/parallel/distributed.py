@@ -74,8 +74,7 @@ def initialize(cfg: DistributedConfig | None = None) -> None:
     before any other JAX use; backend selection freezes at first device touch).
 
     CPU-simulation mode (local_device_count > 0) must set the XLA flag and
-    platform BEFORE the first backend initialization — same constraint as
-    __graft_entry__._force_virtual_cpu.
+    platform BEFORE the first backend initialization.
     """
     cfg = cfg or DistributedConfig.from_env()
     if cfg.local_device_count > 0:
@@ -92,12 +91,15 @@ def initialize(cfg: DistributedConfig | None = None) -> None:
 
 
 def _force_cpu_devices(count: int) -> None:
-    """Steer this process onto >= `count` virtual CPU devices.
+    """Give this process >= `count` virtual CPU devices.
 
     Must run before the first backend initialization (the flag is read once);
     an existing smaller count in XLA_FLAGS is raised in place so a process
-    that inherited the test conftest's 8 can still request 16+. Canonical
-    implementation — __graft_entry__._force_virtual_cpu delegates here.
+    that inherited the test conftest's 8 can still request 16+. Where no
+    platform is named the process is pinned to cpu (else a TPU host would
+    pick the chip and the flag would do nothing); a platform the caller
+    NAMED (jax_platforms / JAX_PLATFORMS) is never overridden — if it is not
+    cpu the device-count check at the call site fails and says so.
     """
     import re
 
@@ -113,8 +115,7 @@ def _force_cpu_devices(count: int) -> None:
         )
     import jax
 
-    platforms = (jax.config.jax_platforms or os.environ.get("JAX_PLATFORMS") or "").split(",")
-    if platforms and platforms[0] not in ("", "cpu"):
+    if not (jax.config.jax_platforms or os.environ.get("JAX_PLATFORMS")):
         jax.config.update("jax_platforms", "cpu")
 
 

@@ -90,5 +90,22 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
+def placement_report(tree: Any) -> dict:
+    """Where a placed pytree actually lives, read back from the arrays'
+    addressable shards: logical bytes, and the bytes each device holds. A
+    tree split four ways reads a quarter per device; four copies read the
+    whole size on each; unplaced reads one device."""
+    leaves = jax.tree.leaves(tree)
+    per_device: dict[int, int] = {}
+    for leaf in leaves:
+        for shard in leaf.addressable_shards:
+            per_device[shard.device.id] = per_device.get(shard.device.id, 0) + shard.data.nbytes
+    return {
+        "leaves": len(leaves),
+        "bytes": int(sum(leaf.nbytes for leaf in leaves)),
+        "per_device_bytes": [per_device[d] for d in sorted(per_device)],
+    }
+
+
 def pad_to_multiple(n: int, multiple: int) -> int:
     return int(math.ceil(n / multiple) * multiple)

@@ -27,6 +27,10 @@ def main() -> None:
     dist.initialize(cfg)
 
     import jax
+
+    from dragonfly2_tpu.utils import jaxenv
+
+    jaxenv.enable_compile_cache()
     import numpy as np
 
     from dragonfly2_tpu.parallel import mesh as meshlib
@@ -66,7 +70,8 @@ def main() -> None:
     jax.block_until_ready(state.params)
     if jax.process_index() == 0:
         print(
-            f"mp_train ok: procs={jax.process_count()} devices={len(jax.devices())} "
+            f"mp_train ok: platform={jax.devices()[0].platform} "
+            f"procs={jax.process_count()} devices={len(jax.devices())} "
             f"mesh={dict(mesh.shape)} steps={steps}",
             flush=True,
         )

@@ -381,7 +381,7 @@ class RpcClient:
         self._pending: dict[int, asyncio.Future] = {}
         self._next_id = 0
         self._recv_task: asyncio.Task | None = None
-        # Safe outside a running loop: since 3.10 asyncio.Lock binds lazily on
+        # Safe outside a running loop: asyncio.Lock binds to a loop lazily, on
         # first await, and each client is used from a single loop (DF021 audit).
         self._conn_lock = asyncio.Lock()
 

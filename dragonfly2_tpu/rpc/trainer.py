@@ -3,12 +3,18 @@ client-stream contract, server.go:41-90, unrolled as open/chunk/close)."""
 
 from __future__ import annotations
 
-from typing import Any
+from typing import TYPE_CHECKING, Any
 
 import numpy as np
 
 from dragonfly2_tpu.rpc.core import RpcClient, RpcServer
-from dragonfly2_tpu.trainer.service import TrainerService, pack_records
+from dragonfly2_tpu.telemetry.records import pack_records
+
+if TYPE_CHECKING:
+    # the client side (scheduler announcer, chip_smoke.py's parent) must not
+    # import the service: it pulls in JAX, and only the trainer process may
+    # open the accelerator
+    from dragonfly2_tpu.trainer.service import TrainerService
 
 TRAINER_METHODS = [
     "train_open", "train_chunk", "train_close", "status", "train_history",

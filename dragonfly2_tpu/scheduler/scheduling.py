@@ -1109,9 +1109,9 @@ class RoundDispatcher:
         # futures' done callbacks inline, which pops _inflight
         inflight = list(self._inflight.items())
         # cancel_futures: queued (never-started) batches are dropped by the
-        # executor (3.9+ kwarg; this image is 3.10) — their rounds' asyncio
-        # futures are cancelled below so no await strands; batches already
-        # RUNNING complete and resolve their rounds via the loop callback.
+        # executor — their rounds' asyncio futures are cancelled below so no
+        # await strands; batches already RUNNING complete and resolve their
+        # rounds via the loop callback.
         self._pool.shutdown(wait=False, cancel_futures=True)
         for cf, batch in inflight:
             if cf.cancelled():

@@ -220,7 +220,12 @@ def main() -> None:
     import sys
 
     from dragonfly2_tpu.scheduler.config import SchedulerYaml
+    from dragonfly2_tpu.utils import jaxenv
     from dragonfly2_tpu.utils.config import ConfigError, load_config
+
+    # host-side process: the JAX scorer fallback and load_gnn's model.init
+    # must never open the accelerator a trainer on this host holds
+    jaxenv.pin_host_cpu()
 
     # Two-stage parse (the reference's cobra/viper layering): --config loads
     # the validated YAML, whose values become the flag DEFAULTS — so explicit

@@ -42,7 +42,7 @@ PR 6 trust model is unchanged. Control-plane RPC keeps its defaults (1.3).
 kTLS: offloading the record layer to the kernel would restore sendfile on
 the upload path. ``probe_ktls()`` checks for BOTH prerequisites (a kernel
 with the ``tls`` ULP, a Python/OpenSSL with ``OP_ENABLE_KTLS``) at runtime
-and reports exactly what it found — on this 4.4-kernel / 3.10-Python image
+and reports exactly what it found — where the kernel has no ``tls`` ULP
 that is "unavailable", and the bench/README carry that as a null, never as a
 fabricated number (VERDICT #8).
 """

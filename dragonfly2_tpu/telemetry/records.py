@@ -9,6 +9,7 @@ training pair batches and edge lists without unflattening.
 
 from __future__ import annotations
 
+import io
 import time
 from pathlib import Path
 
@@ -46,6 +47,17 @@ PROBE_DTYPE = np.dtype(
         ("created_at", "f8"),
     ]
 )
+
+
+def pack_records(arr: np.ndarray) -> bytes:
+    """One structured-array chunk as the trainer RPC's wire bytes (.npy)."""
+    buf = io.BytesIO()
+    np.save(buf, arr, allow_pickle=False)
+    return buf.getvalue()
+
+
+def unpack_records(data: bytes) -> np.ndarray:
+    return np.load(io.BytesIO(data), allow_pickle=False)
 
 
 class ColumnarStore:

@@ -144,8 +144,8 @@ async def fetch_checkpoint(
             logger.info("%s: fetched %d bytes via p2p", entry.path, entry.size)
 
     # first failure cancels the remaining fetches instead of leaving multi-GB
-    # downloads running detached after the error returns (TaskGroup semantics;
-    # utils.aio provides them on this image's 3.10)
+    # downloads running detached after the error returns (utils.aio: first
+    # error propagates bare, not wrapped in an ExceptionGroup)
     from dragonfly2_tpu.utils.aio import gather_all_cancel_on_error
 
     await gather_all_cancel_on_error(fetch_one(e) for e in manifest.files)

@@ -96,6 +96,9 @@ class TrainRunTelemetry:
         self.last_grad_norm: float | None = None
         self._curve: list[tuple[int, float]] = []
         self._curve_stride = 1
+        # mesh + per-device bytes of the placed run (train_gnn._placement);
+        # None for a trainer that places nothing (the MLP)
+        self.placement: dict | None = None
         # steps/s anchors at the FIRST report, not construction: the gap
         # between them is XLA setup + first-call compile (5-30 s on CPU),
         # which would understate a short run's throughput 10x+. The first
@@ -139,6 +142,11 @@ class TrainRunTelemetry:
         if grad_norm is not None:
             TRAIN_GRAD_NORM.set(float(grad_norm), model=self.model)
 
+    def on_placed(self, placement: dict) -> None:
+        """Record where the run's arrays were placed (once, after set-up)."""
+        with self._lock:
+            self.placement = placement
+
     def steps_per_sec(self) -> float | None:
         with self._lock:
             return self._steps_per_sec_locked()
@@ -172,4 +180,5 @@ class TrainRunTelemetry:
                 ),
                 "steps_per_sec": sps,
                 "curve": [(s, round(v, 6)) for s, v in self._curve],
+                "placement": self.placement,
             }

@@ -2,6 +2,11 @@
 
 `python -m dragonfly2_tpu.trainer.server --port 9300 --manager 127.0.0.1:9200
 --model-dir /var/lib/df/models`
+
+This is the one process on a TPU-VM host that opens the accelerator (every
+other service pins the host CPU, utils/jaxenv.py). It does so before
+TRAINER_READY — a chip that cannot be opened fails the start, not the first
+training run — and `status` names the platform it got.
 """
 
 from __future__ import annotations
@@ -13,6 +18,7 @@ import logging
 from dragonfly2_tpu.rpc.core import RpcServer
 from dragonfly2_tpu.rpc.trainer import register_trainer
 from dragonfly2_tpu.trainer.service import TrainerConfig, TrainerService
+from dragonfly2_tpu.utils import jaxenv
 from dragonfly2_tpu.utils.proc import run_until_signalled
 
 logger = logging.getLogger("trainer")
@@ -57,7 +63,7 @@ async def run_trainer(
     server = RpcServer(host=host, port=port)
     register_trainer(server, service)
     await server.start()
-    logger.info("trainer listening on %s", server.address)
+    logger.info("trainer listening on %s, device %s", server.address, service.device)
     # cluster metrics plane (ISSUE 12): the trainer is a member of the
     # cluster view too — its frame (loop lag + whatever trainer families
     # exist) rides a keepalive tick like every other service
@@ -119,6 +125,7 @@ def main() -> None:
         level=logging.DEBUG if args.verbose else logging.INFO,
         format="%(asctime)s %(name)s %(levelname)s %(message)s",
     )
+    logger.info("compile cache at %s", jaxenv.enable_compile_cache())
     asyncio.run(
         run_trainer(
             host=args.host, port=args.port, model_dir=args.model_dir,

@@ -38,7 +38,6 @@ from __future__ import annotations
 
 import asyncio
 import collections
-import io
 import logging
 import time
 from dataclasses import dataclass, field
@@ -47,6 +46,7 @@ from typing import Any
 
 import numpy as np
 
+from dragonfly2_tpu.telemetry.records import unpack_records
 from dragonfly2_tpu.trainer import (
     artifacts,
     dataset as datasetlib,
@@ -54,21 +54,12 @@ from dragonfly2_tpu.trainer import (
     train_gnn,
     train_mlp,
 )
+from dragonfly2_tpu.utils import jaxenv
 
 logger = logging.getLogger(__name__)
 
 # run manifests kept for `train_history` (one per training run, bounded)
 RUN_HISTORY_CAP = 64
-
-
-def pack_records(arr: np.ndarray) -> bytes:
-    buf = io.BytesIO()
-    np.save(buf, arr, allow_pickle=False)
-    return buf.getvalue()
-
-
-def unpack_records(data: bytes) -> np.ndarray:
-    return np.load(io.BytesIO(data), allow_pickle=False)
 
 
 @dataclass
@@ -126,6 +117,11 @@ class TrainerService:
         """manager: RemoteManagerClient (or None to skip registry)."""
         self.cfg = config or TrainerConfig()
         self.manager = manager
+        # platform / device_kind / device_count, read ONCE here: this is the
+        # process that holds the accelerator, and every status reply and run
+        # manifest carries it so a JAX-free caller can tell a TPU run from a
+        # CPU one
+        self.device = jaxenv.device_report()
         self._acc = datasetlib.DatasetAccumulator(max_pair_rows=self.cfg.pool_rows)
         # schedulers that have committed into the CURRENT pool epoch —
         # cleared on rotation with the pool it describes
@@ -210,6 +206,7 @@ class TrainerService:
     async def status(self, p: Any = None) -> dict:
         running = self._drainer is not None and not self._drainer.done()
         return {
+            **self.device,
             "training": running,
             "queue_depth": len(self._queue),
             "open_sessions": len(self._sessions),
@@ -327,13 +324,16 @@ class TrainerService:
                     with default_tracer().span("trainer.publish"):
                         await self._register_models(sess, result)
             self._note_run(sess, result, started_at, time.perf_counter() - t_run)
-        except Exception:
+        except Exception as e:
+            # the service stays up, but the failure is the run's result: what
+            # raised goes into last_result and the manifest, not only the log
             logger.exception("training run failed")
-            self.last_result = {"error": "training failed"}
+            error = f"{type(e).__name__}: {e}"[:500]
+            self.last_result = {"error": error}
             # same manifest shape as success/skip — ONE append path, so the
             # schema can never drift between outcomes
             self._note_run(
-                sess, {"version": f"run-{self.trains_started}"},
+                sess, {"version": f"run-{self.trains_started}", "error": error},
                 started_at, time.perf_counter() - t_run, status="error",
             )
 
@@ -359,6 +359,7 @@ class TrainerService:
                     if k != "contributors"
                 },
                 **(info.get("telemetry") or {}),
+                "native_export_error": info.get("native_export_error"),
             }
             for m in ("mlp", "gnn")
             if (info := result.get(m))
@@ -378,6 +379,9 @@ class TrainerService:
             "started_at": round(started_at, 3),
             "wall_s": round(wall, 3),
             "status": status,
+            "error": result.get("error"),
+            **self.device,
+            "device_peak_bytes": result.get("device_peak_bytes"),
             "scheduler": sess.scheduler_hostname,
             "dataset": {
                 "pairs": result.get("num_pairs", 0),
@@ -462,7 +466,7 @@ class TrainerService:
                 "steps_per_sec": round(len(losses) / max(1e-9, train_seconds), 2),
             }
 
-            def _save_gnn() -> tuple[Path, str]:
+            def _save_gnn() -> tuple[Path, str, str | None]:
                 path = artifacts.save_artifact(
                     Path(self.cfg.model_dir) / f"gnn-{version}",
                     model_type="gnn", version=version, params=state.params,
@@ -477,19 +481,24 @@ class TrainerService:
                     # the serving scheduler compares live scoring features
                     # against THIS distribution (feature drift)
                     artifacts.save_sketch(path, ds.feature_sketch)
+                native_error = None
                 try:
                     artifacts.save_native(path, train_gnn.make_model(cfg), state.params, ds.graph)
-                except Exception:
-                    # native serving is an optimization; the flax artifact always works
+                except Exception as e:
+                    # the flax artifact still serves, so the run is not failed
+                    # — but the missing scorer.dfsc is named in the result
                     logger.exception("native scorer export failed; flax artifact only")
+                    native_error = f"{type(e).__name__}: {e}"[:500]
                 # digest LAST: it must cover every file the loader will read
-                return path, artifacts.artifact_digest(path)
+                return path, artifacts.artifact_digest(path), native_error
 
-            path, digest = await asyncio.to_thread(_save_gnn)
+            path, digest, native_error = await asyncio.to_thread(_save_gnn)
             out["gnn"] = {
                 "artifact": str(path), "digest": digest,
                 "evaluation": evaluation, "telemetry": gnn_tel.summary(),
+                "native_export_error": native_error,
             }
+        out["device_peak_bytes"] = jaxenv.peak_device_bytes()
         return out
 
     async def _register_models(self, sess: TrainSession, result: dict) -> None:

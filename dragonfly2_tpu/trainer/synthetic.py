@@ -16,12 +16,16 @@ the history-less pairs and beat the linear baseline.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import TYPE_CHECKING, NamedTuple
 
 import numpy as np
 
 from dragonfly2_tpu.models.features import FEATURE_DIM, NODE_FEATURE_DIM
-from dragonfly2_tpu.models.graphsage import TopoGraph
+
+if TYPE_CHECKING:
+    # imported inside make_cluster: graphsage pulls in flax/jax, and the
+    # telemetry generator below is also used by JAX-free feeders
+    from dragonfly2_tpu.models.graphsage import TopoGraph
 
 EDGE_FEATURE_DIM = 4  # rtt_mean, rtt_std, rtt_min, probe_count (normalized)
 
@@ -47,6 +51,8 @@ def make_cluster(
     num_idcs: int = 8,
     seed: int = 0,
 ) -> SyntheticCluster:
+    from dragonfly2_tpu.models.graphsage import TopoGraph
+
     rng = np.random.default_rng(seed)
     n, k = num_nodes, num_neighbors
 

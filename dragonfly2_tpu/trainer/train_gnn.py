@@ -247,6 +247,24 @@ def make_scan_step(
     )
 
 
+def _placement(mesh: Mesh, state: Any, g: TopoGraph, batch_size: int) -> dict:
+    """What the placed run occupies, for the run manifest: the Dense kernels
+    the tensor-parallel rule shards and the graph's node rows, both read
+    back from the placed arrays, plus the rows of one pair batch each device
+    is constrained to inside the step."""
+    kernels = [
+        leaf for leaf in jax.tree.leaves(state.params)
+        if leaf.ndim == 2 and meshlib.MODEL_AXIS in leaf.sharding.spec
+    ]
+    batch_size = meshlib.pad_to_multiple(batch_size, mesh.shape[meshlib.DATA_AXIS])
+    return {
+        "mesh": {k: int(v) for k, v in mesh.shape.items()},
+        "kernels": meshlib.placement_report(kernels),
+        "graph": meshlib.placement_report(g),
+        "batch_rows_per_device": meshlib.batch_sharding(mesh).shard_shape((batch_size,))[0],
+    }
+
+
 async def train_async(
     cfg: GNNTrainConfig,
     graph: TopoGraph,
@@ -289,6 +307,8 @@ async def train_async(
         )
 
     state, g, pool, multi_step = await asyncio.to_thread(_setup)
+    if telemetry is not None:
+        telemetry.on_placed(_placement(mesh, state, g, cfg.batch_size))
     key = jax.random.PRNGKey(seed)
 
     def _one_call(st, k):

@@ -1,12 +1,13 @@
-"""Small asyncio compatibility helpers.
+"""Small asyncio helpers.
 
-This image runs Python 3.10, where asyncio.TaskGroup (3.11+) does not exist —
-the two fan-out sites that wanted its semantics (ranged back-to-source piece
-fetches, checkpoint multi-file fetch) raised AttributeError at runtime the
-moment they were reached. `gather_all_cancel_on_error` provides the one
-TaskGroup behavior those sites rely on: run everything, and on the first
-failure cancel the stragglers before re-raising (so multi-GB sibling
-downloads don't keep running detached after the caller has already failed).
+`gather_all_cancel_on_error` is what the two fan-out sites (ranged
+back-to-source piece fetches, checkpoint multi-file fetch) rely on: run
+everything, and on the first failure cancel the stragglers before re-raising
+(so multi-GB sibling downloads don't keep running detached after the caller
+has already failed). It is asyncio.TaskGroup's cancellation behaviour with
+one difference the callers depend on: the first exception propagates BARE,
+not wrapped in an ExceptionGroup, so `except IOError` / `except TimeoutError`
+at the call sites keep matching.
 """
 
 from __future__ import annotations
@@ -22,7 +23,7 @@ async def gather_all_cancel_on_error(coros: Iterable[Awaitable]) -> None:
 
     Unlike bare asyncio.gather (which returns control on the first error but
     leaves the remaining tasks running detached), every task is finished or
-    cancelled by the time this returns — TaskGroup semantics on 3.10. The
+    cancelled by the time this returns, as with asyncio.TaskGroup. The
     first exception (in completion order) propagates; later ones are eaten,
     as with TaskGroup's primary-error behavior for non-ExceptionGroup users.
     """

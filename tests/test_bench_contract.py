@@ -8,6 +8,11 @@ payload schema the driver parses.
 """
 
 import json
+import os
+import subprocess
+import sys
+
+import pytest
 
 import bench
 
@@ -367,10 +372,30 @@ def test_piece_pipeline_contract():
 
 
 def test_payload_schema():
-    line = bench._payload(1234.5, {"backend": "cpu"})
+    line = bench._payload(1234.5, {"platform": "cpu"})
     d = json.loads(line)
     assert set(d) == {"metric", "value", "unit", "vs_baseline", "extra"}
     assert d["metric"] == "scheduler_scoring_calls_per_sec"
     assert d["value"] == 1234.5
     assert d["vs_baseline"] == round(1234.5 / 10_000, 3)
-    assert d["extra"]["backend"] == "cpu"
+    assert d["extra"]["platform"] == "cpu"
+
+
+def test_unknown_device_kind_is_an_error_not_v5e_peaks():
+    peaks = bench.chip_peaks("TPU v5 lite")
+    assert (peaks["bf16_tflops"], peaks["hbm_gbps"]) == (197.0, 819.0) and peaks["source"]
+    with pytest.raises(KeyError, match="no published peaks"):
+        bench.chip_peaks("TPU v9 imaginary")
+
+
+def test_supervisor_refuses_cpu_unless_the_caller_forced_it():
+    """JAX carries on on the CPU when it finds no accelerator; bench.py must
+    not: one JSON line naming the platform, and a non-zero exit."""
+    env = {k: v for k, v in os.environ.items() if k != "DF_BENCH_FORCE_CPU"}
+    out = subprocess.run(
+        [sys.executable, bench.__file__], env={**env, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=300,
+    )
+    assert out.returncode == 1
+    d = json.loads(out.stdout.strip().splitlines()[-1])
+    assert d["extra"]["platform"] == "cpu" and "no accelerator" in d["extra"]["errors"]["init"]

@@ -1,0 +1,103 @@
+"""chip_smoke.py and the rules it rests on, on CPU: the smoke refuses a CPU
+run for the right reason, the compile cache can be placed from outside,
+host-side processes pin the CPU, and the native library is keyed by content.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
+
+from dragonfly2_tpu.native import scorer as native_scorer
+from dragonfly2_tpu.utils import jaxenv
+
+REPO = Path(__file__).resolve().parents[1]
+
+
+def _env(**extra: str) -> dict:
+    # conftest's 8 virtual devices must not leak into children: the smoke's
+    # data-parallel leg would run the full-width GNN over them
+    env = {k: v for k, v in os.environ.items() if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    return {**env, **extra}
+
+
+def test_chip_smoke_on_cpu_fails_only_on_platform(tmp_path):
+    """At tiny sizes under JAX_PLATFORMS=cpu every phase passes and the run
+    still exits non-zero BECAUSE the trainer process reports cpu — with no
+    result line on stdout."""
+    cache = tmp_path / "cache"
+    out = subprocess.run(
+        [
+            sys.executable, str(REPO / "chip_smoke.py"),
+            "--hosts", "64", "--downloads", "2048", "--probes", "1500",
+            "--gnn-steps", "20", "--mlp-steps", "20", "--gnn-hidden", "32",
+            "--stage-mib", "1",
+        ],
+        env=_env(JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(cache)),
+        capture_output=True, text=True, timeout=600,
+    )
+    assert out.returncode != 0
+    assert out.stdout.strip() == "", out.stdout
+    summary = json.loads(out.stderr.strip().splitlines()[-1])
+    assert summary["ok"] is False and summary["claim"] is None
+    assert summary["platform"] == "cpu" and summary["device_count"] == 1
+    assert summary["phases"] == {
+        "trainer": "ok", "artifacts": "ok", "scorer": "ok", "device": "ok",
+        "platform": "FAILED",
+    }, summary["detail"]
+    assert summary["mesh"] == {"data": 1, "model": 1}
+    # the scorer child was pinned to the host CPU; the cache went where the
+    # environment said, not into the checkout
+    assert summary["detail"]["scorer"]["platform"] == "cpu"
+    assert summary["compile_cache"]["dir"] == str(cache)
+    assert summary["detail"]["device"]["cache_dir"] == str(cache)
+
+
+def test_compile_cache_honours_env_else_fixed_checkout_path(tmp_path, monkeypatch):
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(jaxenv.CACHE_ENV, str(tmp_path))
+    assert jaxenv.enable_compile_cache() == tmp_path
+    assert jax.config.jax_compilation_cache_dir == before  # nothing set in code
+    monkeypatch.delenv(jaxenv.CACHE_ENV)
+    assert jaxenv.compile_cache_dir() == REPO / ".jax_cache"
+    # unset, the helper does configure the fixed path (fresh process: this
+    # one's config must stay untouched for the rest of the suite)
+    env = _env(JAX_PLATFORMS="cpu")
+    env.pop(jaxenv.CACHE_ENV, None)
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; from dragonfly2_tpu.utils import jaxenv; "
+         "print(jaxenv.enable_compile_cache()); print(jax.config.jax_compilation_cache_dir)"],
+        env=env, cwd=REPO, capture_output=True, text=True, timeout=120, check=True,
+    )
+    assert out.stdout.split() == [str(REPO / ".jax_cache")] * 2
+
+
+def test_scheduler_main_pins_host_cpu():
+    """A host-side main() leaves jax_platforms == "cpu" even when nothing in
+    the environment named a platform and jax was imported first."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, jax\n"
+         "from dragonfly2_tpu.scheduler import server\n"
+         "assert not jax.config.jax_platforms, jax.config.jax_platforms\n"
+         "sys.argv = ['scheduler', '--help']\n"
+         "try:\n    server.main()\nexcept SystemExit:\n    pass\n"
+         "import os; print('PINNED', jax.config.jax_platforms, os.environ['JAX_PLATFORMS'])\n"],
+        env=_env(), cwd=REPO, capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-800:]
+    assert out.stdout.strip().splitlines()[-1] == "PINNED cpu cpu"
+
+
+def test_native_lib_name_follows_source_bytes():
+    src = native_scorer._SRC.read_bytes()
+    name = native_scorer.lib_file_name(src)
+    assert name == native_scorer.lib_file_name(src)
+    assert name != native_scorer.lib_file_name(src + b"\n")
+    assert name.startswith("libdfscorer-") and name.endswith(".so")

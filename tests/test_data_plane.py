@@ -93,8 +93,8 @@ class TestCipherPolicy:
         out = tport.probe_ktls()
         assert set(out) == {"available", "reason"}
         assert isinstance(out["available"], bool) and out["reason"]
-        # this image: 4.4 kernel + Python 3.10 — kTLS CANNOT be available,
-        # and a True here would mean the probe fabricated support
+        # this image's kernel has no tls ULP — kTLS CANNOT be available, and
+        # a True here would mean the probe fabricated support
         assert out["available"] is False
 
     def test_cipher_microbench_measures_both(self, certs):

@@ -14,9 +14,9 @@ from dragonfly2_tpu.rpc.core import RpcServer
 from dragonfly2_tpu.rpc.trainer import RemoteTrainerClient, register_trainer
 from dragonfly2_tpu.scheduler.announcer import TrainerAnnouncer
 from dragonfly2_tpu.telemetry import TelemetryStorage
-from dragonfly2_tpu.telemetry.records import DOWNLOAD_DTYPE, PROBE_DTYPE
+from dragonfly2_tpu.telemetry.records import DOWNLOAD_DTYPE, PROBE_DTYPE, pack_records
 from dragonfly2_tpu.trainer import dataset as datasetlib, train_gnn, train_mlp
-from dragonfly2_tpu.trainer.service import TrainerConfig, TrainerService, pack_records
+from dragonfly2_tpu.trainer.service import TrainerConfig, TrainerService
 from dragonfly2_tpu.trainer.synthetic import synth_telemetry_records
 
 

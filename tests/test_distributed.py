@@ -104,7 +104,7 @@ def test_dryrun_16_devices_subprocess():
     it on capable hardware."""
     env = {k: v for k, v in os.environ.items() if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
     out = subprocess.run(
-        [sys.executable, "-c", "import __graft_entry__; __graft_entry__.dryrun_multichip(16, steps=10)"],
+        [sys.executable, "-c", "import __graft_entry__; __graft_entry__.dryrun_multichip(16, steps=10, virtual_cpu=True)"],
         env=env,
         cwd=REPO,
         capture_output=True,
@@ -114,8 +114,8 @@ def test_dryrun_16_devices_subprocess():
     assert out.returncode == 0, out.stderr[-1000:]
     lines = [l for l in out.stdout.splitlines() if l.startswith("dryrun_multichip ok")]
     assert len(lines) == 2  # tp mesh + pure-dp mesh
-    assert "mesh={'data': 4, 'model': 4} devices=16" in lines[0]
-    assert "mesh={'data': 16, 'model': 1} devices=16" in lines[1]
+    assert "platform=cpu mesh={'data': 4, 'model': 4} devices=16" in lines[0]
+    assert "platform=cpu mesh={'data': 16, 'model': 1} devices=16" in lines[1]
 
 
 @pytest.mark.slow

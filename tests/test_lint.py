@@ -15,7 +15,9 @@ import pytest
 REPO = Path(__file__).resolve().parent.parent
 # dflint enforces the whole tree, tests included; ruff's scope is narrower
 # (tests are excluded in pyproject.toml).
-DFLINT_TARGETS = ["dragonfly2_tpu", "tools", "tests", "bench.py", "__graft_entry__.py"]
+DFLINT_TARGETS = [
+    "dragonfly2_tpu", "tools", "tests", "bench.py", "__graft_entry__.py", "chip_smoke.py",
+]
 LINT_TARGETS = ["dragonfly2_tpu", "tools", "bench.py"]
 
 

@@ -33,10 +33,12 @@ def trained():
     return cluster, state.params, z, jax_scorer
 
 
-def test_build_lib_is_cached(tmp_path):
-    lib = build_native_lib(lib_path=tmp_path / "lib.so")
+def test_build_lib_is_cached(tmp_path, monkeypatch):
+    monkeypatch.setenv("DRAGONFLY_NATIVE_CACHE", str(tmp_path))
+    lib = build_native_lib()
+    assert lib.parent == tmp_path
     mtime = lib.stat().st_mtime
-    lib2 = build_native_lib(lib_path=tmp_path / "lib.so")
+    lib2 = build_native_lib()
     assert lib2 == lib and lib.stat().st_mtime == mtime  # no rebuild
 
 

@@ -527,8 +527,8 @@ class TestHttpsInterception:
                     assert b"200" in await reader.readline()
                     while (await reader.readline()) not in (b"\r\n", b"\n", b""):
                         pass
-                    # client-side TLS upgrade; 3.10 has no StreamWriter
-                    # .start_tls (3.11+) — use the loop API + transport rewire
+                    # client-side TLS upgrade through the loop API +
+                    # transport rewire (the idiom the proxy itself uses)
                     loop = asyncio.get_running_loop()
                     transport = await loop.start_tls(
                         writer.transport, writer.transport.get_protocol(),

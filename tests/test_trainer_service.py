@@ -14,8 +14,9 @@ from dragonfly2_tpu.rpc.manager import RemoteManagerClient
 from dragonfly2_tpu.rpc.trainer import RemoteTrainerClient, register_trainer
 from dragonfly2_tpu.scheduler.announcer import TrainerAnnouncer
 from dragonfly2_tpu.telemetry import TelemetryStorage
+from dragonfly2_tpu.telemetry.records import pack_records, unpack_records
 from dragonfly2_tpu.trainer import artifacts, dataset as datasetlib, train_gnn, train_mlp
-from dragonfly2_tpu.trainer.service import TrainerConfig, TrainerService, pack_records, unpack_records
+from dragonfly2_tpu.trainer.service import TrainerConfig, TrainerService
 from dragonfly2_tpu.trainer.synthetic import PairBatch
 
 
@@ -193,5 +194,29 @@ def test_trainer_skips_on_thin_data(run, tmp_path):
 
         with pytest.raises(KeyError):
             await svc.train_chunk({"token": "bogus", "kind": "downloads", "data": b""})
+
+    run(body())
+
+
+def test_failed_run_and_device_are_visible_to_a_remote_caller(run, tmp_path):
+    """The service survives a training exception, but the caller can see it:
+    what raised is in last_result and the manifest, and status names the
+    platform/device the trainer process holds (PR 21)."""
+    from dragonfly2_tpu.trainer.service import TrainSession
+
+    async def body():
+        svc = TrainerService(TrainerConfig(model_dir=str(tmp_path)))
+
+        async def boom(sess):
+            raise ValueError("bad graph")
+
+        svc._run_training = boom
+        await svc._train(TrainSession("tok"))
+        status = await svc.status()
+        assert status["last_result"] == {"error": "ValueError: bad graph"}
+        assert (status["platform"], status["device_count"]) == ("cpu", 8) and status["device_kind"]
+        manifest = (await svc.train_history({"limit": 1}))["runs"][0]
+        assert manifest["status"] == "error" and manifest["error"] == "ValueError: bad graph"
+        assert manifest["platform"] == "cpu"
 
     run(body())

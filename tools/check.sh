@@ -51,7 +51,7 @@ skip_stage() {
     echo "━━ $1: skipped ($2)"
 }
 
-run_stage "dflint" python tools/dflint.py dragonfly2_tpu/ tools/ tests/ bench.py __graft_entry__.py
+run_stage "dflint" python tools/dflint.py dragonfly2_tpu/ tools/ tests/ bench.py __graft_entry__.py chip_smoke.py
 
 if command -v ruff >/dev/null 2>&1; then
     run_stage "ruff" ruff check dragonfly2_tpu tools bench.py

@@ -150,11 +150,8 @@ async def main() -> int:
     from dragonfly2_tpu.scheduler.evaluator import new_evaluator
     from dragonfly2_tpu.scheduler.manager_link import ManagerLink
     from dragonfly2_tpu.scheduler.service import SchedulerService
-    from dragonfly2_tpu.trainer.service import (
-        TrainerConfig,
-        TrainerService,
-        pack_records,
-    )
+    from dragonfly2_tpu.telemetry.records import pack_records
+    from dragonfly2_tpu.trainer.service import TrainerConfig, TrainerService
 
     tmp = Path(tempfile.mkdtemp(prefix="df-mlobs-smoke-"))
     manager = ManagerServer(db_path=str(tmp / "m.db"))
