@@ -578,11 +578,7 @@ def bench_federation(
     )
 
     # ---- two real schedulers, chained federation, short gossip tick ----
-    env = dict(
-        os.environ,
-        PYTHONPATH=os.path.dirname(os.path.abspath(__file__)),
-        JAX_PLATFORMS="cpu",
-    )
+    env = dict(os.environ, PYTHONPATH=os.path.dirname(os.path.abspath(__file__)))
     procs: list[subprocess.Popen] = []
 
     def boot(extra: list[str]) -> str:
@@ -894,7 +890,6 @@ async def _spawn_upload_parent(
         ],
         stdout=subprocess.PIPE,
         text=True,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     loop = asyncio.get_running_loop()
     try:

@@ -22,7 +22,10 @@ the entry points a user would call, at the widths the repo ships as default:
             local devices, pulled back and compared bit for bit. On more than
             one device it also drives train_async under {data: n} so node
             rows and the pair batch are seen to span them.
-  platform  the platform the TRAINER PROCESS reported must be "tpu".
+  platform  the trainer process AND the device child must both report
+            platform "tpu" with the same device kind and count, and every
+            Pallas shape must have been compiled (interpret=False). A child
+            that fell back to the CPU fails the smoke here.
 
 This parent never imports jax: a parent that has touched JAX holds the chip
 and a child that needs it then fails or hangs. On success the last line of
@@ -51,6 +54,8 @@ HERE = Path(__file__).resolve().parent
 RESULT_PREFIX = "CHIP_SMOKE_CHILD "
 # one wall-clock budget for the whole smoke (the contract allows 1200 s)
 BUDGET_S = 1100.0
+# the telemetry and the staged tensors are made from this seed
+SEED = 0
 
 
 def _log(msg: str) -> None:
@@ -63,6 +68,8 @@ def _cache_entries(cache_dir: Path) -> int:
 
 def _spawn(args: list[str], log_path: Path) -> subprocess.Popen:
     """Start a child in its own process group, output to a log file."""
+    # a parent that has touched JAX holds the chip its children need
+    assert "jax" not in sys.modules, "chip_smoke.py's parent imported jax"
     with open(log_path, "wb") as log:
         return subprocess.Popen(
             [sys.executable, *args],
@@ -121,7 +128,7 @@ async def _drive_trainer(addr: str, args: argparse.Namespace, deadline: float) -
     client = RemoteTrainerClient(addr)
     try:
         downloads, probes = synth_telemetry_records(
-            args.downloads, args.probes, args.hosts, seed=args.seed
+            args.downloads, args.probes, args.hosts, seed=SEED
         )
         token = await client.train_open("chip-smoke", 0)
         for kind, arr in (("downloads", downloads), ("probes", probes)):
@@ -230,6 +237,29 @@ def _artifacts_phase(trainer: dict) -> dict:
     return {"ok": not missing, "missing": missing}
 
 
+def _platform_phase(trainer_device: dict, device_child: dict) -> dict:
+    """No phase may have run on a CPU that JAX fell back to: the trainer and
+    the device child both say "tpu", name the same chips, and Mosaic (not the
+    interpreter) compiled every Pallas shape."""
+    child_device = {k: device_child.get(k) for k in ("platform", "device_kind", "device_count")}
+    pallas = device_child.get("pallas") or {}
+    problems = []
+    if trainer_device.get("platform") != "tpu":
+        problems.append(f"trainer ran on {trainer_device.get('platform')!r}")
+    if child_device["platform"] != "tpu":
+        problems.append(f"device child ran on {child_device['platform']!r}")
+    if child_device != trainer_device:
+        problems.append(f"device child saw {child_device}, trainer saw {trainer_device}")
+    interpreted = [shape for shape, r in pallas.items() if not r.get("compiled")]
+    if interpreted or not pallas:
+        problems.append(f"Pallas kernel not compiled at {interpreted or 'any shape'}")
+    return {
+        "ok": not problems, "problems": problems,
+        "trainer_reported": trainer_device.get("platform"),
+        "device_child_reported": child_device["platform"],
+    }
+
+
 # ---- children (these import jax) -------------------------------------------
 
 
@@ -249,7 +279,7 @@ def _child_scorer(artifact: str) -> dict:
     jax_scorer = GNNScorer(model, params)
     jax_scorer.refresh(graph)
     native = artifacts.load_native(artifact)  # builds with g++; missing = failure
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(SEED)
     n = jax_scorer.num_nodes
     child = np.full(40, rng.integers(0, n), np.int32)
     parent = rng.integers(0, n, size=40).astype(np.int32)
@@ -259,10 +289,13 @@ def _child_scorer(artifact: str) -> dict:
     err = float(np.max(np.abs(a - b)))
     # bf16 JAX head vs f32 C++ head (the tolerance tests/test_native.py pins)
     ok = bool(a.shape == (40,) and np.all(np.isfinite(a)) and np.all(np.isfinite(b)) and err <= 3e-2)
-    return {"ok": ok, "max_abs_diff": err, "nodes": n, **jaxenv.device_report()}
+    report = jaxenv.device_report()
+    # this child scores on the host: it must never have opened the chip
+    ok = ok and report["platform"] == "cpu"
+    return {"ok": ok, "max_abs_diff": err, "nodes": n, **report}
 
 
-def _child_device(tmp: str, stage_mib: int, seed: int) -> dict:
+def _child_device(tmp: str, stage_mib: int) -> dict:
     """Opens the accelerator: staging round trip, and on several devices the
     data-parallel GNN run."""
     from dragonfly2_tpu.utils import jaxenv
@@ -277,7 +310,7 @@ def _child_device(tmp: str, stage_mib: int, seed: int) -> dict:
 
     out: dict = {**jaxenv.device_report(), "cache_dir": str(cache)}
     n_dev = out["device_count"]
-    rng = np.random.default_rng(seed)
+    rng = np.random.default_rng(SEED)
     rows = max(n_dev * 8, stage_mib * (1 << 20) // (4096 * 2))
     rows -= rows % (n_dev * 8)
     # finite bf16 bit patterns (exponent never all-ones): bits must survive
@@ -376,7 +409,7 @@ def _data_parallel_run(n_dev: int) -> dict:
     from dragonfly2_tpu.trainer.metrics import TrainRunTelemetry
 
     cfg = train_gnn.GNNTrainConfig()
-    cluster = synthetic.make_cluster(num_nodes=1024, num_neighbors=16, num_pairs=65536, seed=0)
+    cluster = synthetic.make_cluster(num_nodes=1024, num_neighbors=16, num_pairs=65536, seed=SEED)
     tel = TrainRunTelemetry("gnn", batch_size=cfg.batch_size)
     _state, losses = asyncio.run(train_gnn.train_async(
         cfg, cluster.graph, cluster.pairs, steps=20,
@@ -400,7 +433,7 @@ def _child_main(argv: list[str]) -> int:
         if kind == "scorer":
             out = _child_scorer(rest[0])
         elif kind == "device":
-            out = _child_device(rest[0], int(rest[1]), int(rest[2]))
+            out = _child_device(rest[0], int(rest[1]))
         else:
             raise SystemExit(f"unknown child {kind!r}")
     except Exception as e:  # the parent reports it; the traceback is in the child's log
@@ -428,7 +461,6 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--gnn-hidden", type=int, default=None,
                     help="override the GNN width (default: TrainerConfig's 256)")
     ap.add_argument("--stage-mib", type=int, default=64)
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
     try:
@@ -457,16 +489,13 @@ def main(argv: list[str] | None = None) -> int:
             phases["scorer"] = {"ok": False, "error": "no artifact to score"}
         _log(f"scorer: {phases['scorer']}")
         phases["device"] = _run_child(
-            "device", ["--child", "device", str(tmp), str(args.stage_mib), str(args.seed)], tmp,
+            "device", ["--child", "device", str(tmp), str(args.stage_mib)], tmp,
             timeout=max(30.0, min(400.0, deadline - time.monotonic())),
         )
         _log(f"device: {phases['device']}")
 
     device = trainer.get("device") or {}
-    phases["platform"] = {
-        "ok": device.get("platform") == "tpu",
-        "trainer_reported": device.get("platform"),
-    }
+    phases["platform"] = _platform_phase(device, phases["device"])
     cache["entries_after"] = _cache_entries(cache_dir)
     _log(f"compile cache {cache_dir}: {cache['entries_after']} entries after")
     ok = all(p["ok"] for p in phases.values())

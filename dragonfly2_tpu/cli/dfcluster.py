@@ -59,7 +59,7 @@ class Cluster:
 
     def _env(self, name: str) -> dict:
         env = dict(os.environ, PYTHONPATH=os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__)))), JAX_PLATFORMS="cpu")
+            os.path.dirname(os.path.abspath(__file__)))))
         if self.trace:
             os.makedirs(self.trace_dir, exist_ok=True)
             env["DRAGONFLY_TRACE_FILE"] = os.path.join(self.trace_dir, f"{name}.jsonl")

@@ -13,6 +13,7 @@ from pathlib import Path
 
 import jax
 
+import chip_smoke
 from dragonfly2_tpu.native import scorer as native_scorer
 from dragonfly2_tpu.utils import jaxenv
 
@@ -51,11 +52,36 @@ def test_chip_smoke_on_cpu_fails_only_on_platform(tmp_path):
         "platform": "FAILED",
     }, summary["detail"]
     assert summary["mesh"] == {"data": 1, "model": 1}
+    platform = summary["detail"]["platform"]
+    assert platform["trainer_reported"] == platform["device_child_reported"] == "cpu"
+    assert any("trainer ran on 'cpu'" in p for p in platform["problems"])
+    # off-chip the kernel only interprets, and the gate says so
+    assert not any(r["compiled"] for r in summary["detail"]["device"]["pallas"].values())
+    assert any("Pallas kernel not compiled" in p for p in platform["problems"])
     # the scorer child was pinned to the host CPU; the cache went where the
     # environment said, not into the checkout
     assert summary["detail"]["scorer"]["platform"] == "cpu"
     assert summary["compile_cache"]["dir"] == str(cache)
     assert summary["detail"]["device"]["cache_dir"] == str(cache)
+
+
+def test_platform_phase_refuses_a_device_child_that_fell_back():
+    """A trainer on the chip does not excuse a device child (staging, Pallas,
+    {data: n}) that JAX quietly ran on the CPU, or a kernel that was only
+    interpreted."""
+    tpu = {"platform": "tpu", "device_kind": "TPU v5 lite", "device_count": 1}
+    cpu = {"platform": "cpu", "device_kind": "cpu", "device_count": 1}
+    compiled = {"1024x256": {"ok": True, "compiled": True}}
+    interpreted = {"1024x256": {"ok": True, "compiled": False}}
+    assert chip_smoke._platform_phase(tpu, {"ok": True, **tpu, "pallas": compiled})["ok"]
+    assert not chip_smoke._platform_phase(tpu, {"ok": True, **cpu, "pallas": interpreted})["ok"]
+    assert not chip_smoke._platform_phase(tpu, {"ok": True, **tpu, "pallas": interpreted})["ok"]
+    assert not chip_smoke._platform_phase(tpu, {"ok": True, **tpu, "pallas": {}})["ok"]
+    assert not chip_smoke._platform_phase(cpu, {"ok": True, **tpu, "pallas": compiled})["ok"]
+    # a device child that died reports no platform at all
+    assert not chip_smoke._platform_phase(tpu, {"ok": False, "error": "boom"})["ok"]
+    assert not chip_smoke._platform_phase(
+        tpu, {"ok": True, **tpu, "device_count": 4, "pallas": compiled})["ok"]
 
 
 def test_compile_cache_honours_env_else_fixed_checkout_path(tmp_path, monkeypatch):

@@ -41,7 +41,7 @@ def dftop_once(manager_addr: str) -> tuple[int, dict]:
         [sys.executable, "-m", "dragonfly2_tpu.cli.dftop",
          "--manager", manager_addr, "--once", "--json"],
         capture_output=True, text=True, timeout=30,
-        env=dict(os.environ, PYTHONPATH=str(REPO), JAX_PLATFORMS="cpu"),
+        env=dict(os.environ, PYTHONPATH=str(REPO)),
     )
     doc = json.loads(r.stdout) if r.stdout.strip() else {}
     return r.returncode, doc
@@ -95,7 +95,7 @@ def main() -> int:
              "--schedulers", ",".join(cluster.scheduler_addrs),
              "--peers", "30", "--duration", "4"],
             capture_output=True, text=True, timeout=120,
-            env=dict(os.environ, PYTHONPATH=str(REPO), JAX_PLATFORMS="cpu"),
+            env=dict(os.environ, PYTHONPATH=str(REPO)),
         )
         if r.returncode != 0:
             raise ClusterError(f"swarm phase failed: {r.stderr or r.stdout}")

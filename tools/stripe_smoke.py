@@ -116,5 +116,4 @@ async def main() -> int:
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     raise SystemExit(asyncio.run(main()))
