@@ -28,12 +28,14 @@ the entry points a user would call, at the widths the repo ships as default:
             that fell back to the CPU fails the smoke here.
 
 This parent never imports jax: a parent that has touched JAX holds the chip
-and a child that needs it then fails or hangs. On success the last line of
-stdout is one JSON object, `{"ok": true, "device": {...}, ...}`, and the exit
-code is 0. On any failure nothing is written to stdout's last line as a
-result: the same summary goes to stderr with "ok": false and the exit code is
-non-zero. The size flags exist for the CPU test (tests/test_chip_smoke.py)
-and for the second shape (`--hosts 16384 --gnn-hidden 512`).
+and a child that needs it then fails or hangs. The summary (mesh, each
+phase's outcome and detail, the compile cache's entry counts) is one JSON line
+on stderr, always. On success stdout carries exactly one line, the result
+`{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}` with
+the device as the trainer process's JAX reported it, and the exit code is 0.
+On any failure stdout stays empty and the exit code is non-zero. The size
+flags exist for the CPU test (tests/test_chip_smoke.py) and for the second
+shape (`--hosts 16384 --gnn-hidden 512`).
 """
 
 from __future__ import annotations
@@ -499,16 +501,17 @@ def main(argv: list[str] | None = None) -> int:
     cache["entries_after"] = _cache_entries(cache_dir)
     _log(f"compile cache {cache_dir}: {cache['entries_after']} entries after")
     ok = all(p["ok"] for p in phases.values())
-    summary = {
+    # the result line: exactly these keys, the device as the trainer's JAX saw it
+    result = {
         "ok": ok,
         "device": {
             "platform": device.get("platform"),
             "kind": device.get("device_kind"),
             "count": device.get("device_count"),
         },
-        "platform": device.get("platform"),
-        "device_kind": device.get("device_kind"),
-        "device_count": device.get("device_count"),
+    }
+    summary = {
+        **result,
         "mesh": trainer.get("mesh"),
         "phases": {name: ("ok" if p["ok"] else "FAILED") for name, p in phases.items()},
         "detail": phases,
@@ -516,13 +519,12 @@ def main(argv: list[str] | None = None) -> int:
         "wall_s": round(time.monotonic() - t0, 1),
         "claim": None,
     }
-    if ok:
-        print(json.dumps(summary), flush=True)
-        return 0
-    failed = [name for name, p in phases.items() if not p["ok"]]
-    _log(f"FAILED phases: {failed}")
+    if not ok:
+        _log(f"FAILED phases: {[name for name, p in phases.items() if not p['ok']]}")
     print(json.dumps(summary), file=sys.stderr, flush=True)
-    return 1
+    if ok:
+        print(json.dumps(result), flush=True)
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":

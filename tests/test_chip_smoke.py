@@ -46,7 +46,7 @@ def test_chip_smoke_on_cpu_fails_only_on_platform(tmp_path):
     assert out.stdout.strip() == "", out.stdout
     summary = json.loads(out.stderr.strip().splitlines()[-1])
     assert summary["ok"] is False and summary["claim"] is None
-    assert summary["platform"] == "cpu" and summary["device_count"] == 1
+    assert summary["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
     assert summary["phases"] == {
         "trainer": "ok", "artifacts": "ok", "scorer": "ok", "device": "ok",
         "platform": "FAILED",
@@ -63,6 +63,25 @@ def test_chip_smoke_on_cpu_fails_only_on_platform(tmp_path):
     assert summary["detail"]["scorer"]["platform"] == "cpu"
     assert summary["compile_cache"]["dir"] == str(cache)
     assert summary["detail"]["device"]["cache_dir"] == str(cache)
+
+
+def test_result_line_has_exactly_the_contract_keys(monkeypatch, capsys):
+    """On success stdout's one line is {"ok", "device": {"platform", "kind",
+    "count"}} and nothing else; the detail is the stderr summary."""
+    tpu = {"platform": "tpu", "device_kind": "TPU v5 lite", "device_count": 1}
+    trainer = {"ok": True, "device": tpu, "mesh": {"data": 1, "model": 1}, "gnn_artifact": "g"}
+    child = {"ok": True, **tpu, "pallas": {"1024x256": {"ok": True, "compiled": True}}}
+    monkeypatch.setattr(chip_smoke, "_trainer_phase", lambda *a: trainer)
+    monkeypatch.setattr(chip_smoke, "_artifacts_phase", lambda t: {"ok": True, "missing": []})
+    monkeypatch.setattr(chip_smoke, "_run_child", lambda name, *a, **k: child)
+    assert chip_smoke.main([]) == 0
+    out, err = capsys.readouterr()
+    assert out.count("\n") == 1
+    assert json.loads(out) == {
+        "ok": True, "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+    }
+    summary = json.loads(err.strip().splitlines()[-1])
+    assert summary["claim"] is None and set(summary["phases"].values()) == {"ok"}
 
 
 def test_platform_phase_refuses_a_device_child_that_fell_back():
