@@ -236,6 +236,13 @@ async def _train(args: argparse.Namespace) -> int:
                 f"grad_norm={info.get('grad_norm')} "
                 f"steps/s={info.get('steps_per_sec')}"
             )
+            calls = info.get("calls")
+            if calls:
+                # the scan loop's pacing: what the host held the chip back
+                line += (
+                    f" calls={calls.get('count')} period_p50={calls.get('period_ms_p50')}ms"
+                    f" turn_max={calls.get('turn_ms_max')}ms stall={calls.get('stall_ms')}ms"
+                )
             print(line)
             curve = info.get("curve") or []
             if curve and not args.no_curves:

@@ -16,6 +16,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from dragonfly2_tpu.ops.neighbor_agg import masked_mean, neighbor_gather
@@ -36,6 +37,27 @@ class TopoGraph(NamedTuple):
     edge_feats: jnp.ndarray
 
 
+# The scope vocabulary of the training step: every device op of the step
+# carries exactly one of these as a component of its `op_name` (set with
+# `jax.named_scope`, metadata only: the compiled program does not change),
+# and the benchmark's per-layer metrics find a part's device time by it
+# (benchmarks/scopes.json is their copy). JAX itself adds `transpose(jvp(..))`
+# for the backward pass and flax the module's name (`SAGELayer_1`), so a name
+# says what an op is FOR, not how it is done: a kernel or `custom_vjp` that
+# replaces a part keeps its forward and its backward under that part's name.
+# The scopes do not nest. Never rename a flax module for the trace's sake:
+# module names are parameter-tree keys and seed the initial weights.
+GATHER = "gather"        # neighbor_gather in SAGELayer; its VJP is the scatter-add
+MESSAGE = "message"      # edge projection, the sum of the three terms, gelu
+REDUCE = "reduce"        # masked_mean over the K neighbor slots
+DENSE = "dense"          # every other Dense / LayerNorm of the encoder, the L2 norm
+HEAD = "head"            # the pair rows' take and the pairwise head
+LOSS = "loss"            # trainer.train_gnn.loss_fn's mean square
+OPTIMIZER = "optimizer"  # global_norm, apply_gradients
+SAMPLE = "sample"        # the scan's key split, randint and pool gathers
+STEP_SCOPES = (GATHER, MESSAGE, REDUCE, DENSE, HEAD, LOSS, OPTIMIZER, SAMPLE)
+
+
 class SAGELayer(nn.Module):
     features: int
     dtype: jnp.dtype = jnp.bfloat16
@@ -49,23 +71,30 @@ class SAGELayer(nn.Module):
         # natural rank instead: node projections are [N, H]·[H, F] (no K),
         # only the tiny edge term stays per-edge. ~(2H+E)/(2H/K+E) ≈ 7x fewer
         # MACs at K=16, and every matmul is a clean MXU shape.
-        h = h.astype(self.dtype)
-        u = nn.Dense(
-            self.features, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32,
-            name="msg_nbr",
-        )(h)
-        s = nn.Dense(
-            self.features, dtype=self.dtype, param_dtype=jnp.float32, name="msg_self"
-        )(h)
-        v = nn.Dense(
-            self.features, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32,
-            name="msg_edge",
-        )(g.edge_feats.astype(self.dtype))
-        msg = nn.gelu(neighbor_gather(u, g.neighbors) + s[:, None, :] + v)  # [N, K, F]
-        agg = masked_mean(msg, g.mask.astype(self.dtype))  # [N, features]
-        self_h = nn.Dense(self.features, dtype=self.dtype, param_dtype=jnp.float32)(h)
-        out = nn.gelu(self_h + agg)
-        return nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32)(out)
+        with jax.named_scope(DENSE):
+            h = h.astype(self.dtype)
+            u = nn.Dense(
+                self.features, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32,
+                name="msg_nbr",
+            )(h)
+            s = nn.Dense(
+                self.features, dtype=self.dtype, param_dtype=jnp.float32, name="msg_self"
+            )(h)
+        with jax.named_scope(MESSAGE):
+            v = nn.Dense(
+                self.features, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32,
+                name="msg_edge",
+            )(g.edge_feats.astype(self.dtype))
+        with jax.named_scope(GATHER):
+            nbr = neighbor_gather(u, g.neighbors)  # [N, K, F]
+        with jax.named_scope(MESSAGE):
+            msg = nn.gelu(nbr + s[:, None, :] + v)  # [N, K, F]
+        with jax.named_scope(REDUCE):
+            agg = masked_mean(msg, g.mask.astype(self.dtype))  # [N, features]
+        with jax.named_scope(DENSE):
+            self_h = nn.Dense(self.features, dtype=self.dtype, param_dtype=jnp.float32)(h)
+            out = nn.gelu(self_h + agg)
+            return nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32)(out)
 
 
 class GraphSAGE(nn.Module):
@@ -78,16 +107,18 @@ class GraphSAGE(nn.Module):
 
     @nn.compact
     def __call__(self, g: TopoGraph) -> jnp.ndarray:
-        h = nn.Dense(self.hidden, dtype=self.dtype, param_dtype=jnp.float32)(
-            g.node_feats.astype(self.dtype)
-        )
+        with jax.named_scope(DENSE):
+            h = nn.Dense(self.hidden, dtype=self.dtype, param_dtype=jnp.float32)(
+                g.node_feats.astype(self.dtype)
+            )
         for _ in range(self.num_layers):
             h = SAGELayer(self.hidden, dtype=self.dtype)(h, g)
-        z = nn.Dense(self.embed_dim, dtype=self.dtype, param_dtype=jnp.float32)(h)
-        # L2-normalized embeddings (standard GraphSAGE) keep the pairwise head
-        # scale-stable across training rounds.
-        z = z.astype(jnp.float32)
-        return z / (jnp.linalg.norm(z, axis=-1, keepdims=True) + 1e-6)
+        with jax.named_scope(DENSE):
+            z = nn.Dense(self.embed_dim, dtype=self.dtype, param_dtype=jnp.float32)(h)
+            # L2-normalized embeddings (standard GraphSAGE) keep the pairwise head
+            # scale-stable across training rounds.
+            z = z.astype(jnp.float32)
+            return z / (jnp.linalg.norm(z, axis=-1, keepdims=True) + 1e-6)
 
 
 class TopoScorer(nn.Module):
@@ -124,13 +155,14 @@ class TopoScorer(nn.Module):
         pair_feats: jnp.ndarray,
     ) -> jnp.ndarray:
         z = self.encoder(g)  # [N, D] float32
-        zc = jnp.take(z, child_idx, axis=0)
-        zp = jnp.take(z, parent_idx, axis=0)
-        x = jnp.concatenate(
-            [zc, zp, zc * zp, pair_feats.astype(jnp.float32)], axis=-1
-        ).astype(self.dtype)
-        out = self.head(x).astype(jnp.float32).squeeze(-1)
-        return nn.sigmoid(out)
+        with jax.named_scope(HEAD):
+            zc = jnp.take(z, child_idx, axis=0)
+            zp = jnp.take(z, parent_idx, axis=0)
+            x = jnp.concatenate(
+                [zc, zp, zc * zp, pair_feats.astype(jnp.float32)], axis=-1
+            ).astype(self.dtype)
+            out = self.head(x).astype(jnp.float32).squeeze(-1)
+            return nn.sigmoid(out)
 
     def embed(self, g: TopoGraph) -> jnp.ndarray:
         return self.encoder(g)

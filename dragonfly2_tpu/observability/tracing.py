@@ -20,6 +20,10 @@ client/config/constants_otel.go). Dependency-free design:
   and/or JSON-lines file (the jaeger-exporter stand-in — one dict per span
   with trace_id, span_id, parent_id, name, start, duration_ms, attrs,
   status), and/or OTLP/JSON batches (file or collector endpoint).
+- `Tracer.annotate` is the one bridge to another clock: a process that owns
+  a profiler sets it (the trainer: `jax.profiler.TraceAnnotation`) and every
+  sampled span is then also an event of that profiler, under the span's
+  name. This module itself stays free of jax.
 """
 
 from __future__ import annotations
@@ -35,7 +39,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Mapping, Optional
+from typing import Any, Callable, ContextManager, Mapping, Optional
 
 _current_span: contextvars.ContextVar[Optional["Span"]] = contextvars.ContextVar(
     "dragonfly_current_span", default=None
@@ -102,7 +106,8 @@ class SpanContext:
 class Span:
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id", "start", "end",
-        "attrs", "status", "error", "sampled", "_tracer", "_token",
+        "attrs", "status", "error", "sampled", "duration_ms",
+        "_tracer", "_token", "_t0", "_annotation",
     )
 
     def __init__(
@@ -120,7 +125,11 @@ class Span:
         self.sampled = sampled
         if sampled:
             self.span_id = _gen_span_id()
+            # `start` is the unix time the exports carry; the duration comes
+            # from the monotonic clock, which a step of the wall clock (NTP,
+            # a VM resume) cannot corrupt
             self.start = time.time()
+            self._t0 = time.perf_counter()
         else:
             # unsampled spans still hold the trace lineage for propagation
             # (children and remote continuations inherit the decision) but
@@ -128,7 +137,10 @@ class Span:
             # unsampled hot path cost an object + contextvar churn only
             self.span_id = ""
             self.start = 0.0
+            self._t0 = 0.0
         self.end = 0.0
+        self.duration_ms = 0.0
+        self._annotation: Optional[ContextManager] = None
         self.attrs = attrs
         self.status = "ok"
         self.error = ""
@@ -144,6 +156,10 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current_span.set(self)
+        annotate = self._tracer.annotate
+        if annotate is not None and self.sampled:
+            self._annotation = annotate(self.name)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
@@ -151,10 +167,15 @@ class Span:
             _current_span.reset(self._token)
         if not self.sampled:
             return
+        elapsed = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if exc is not None:
             self.status = "error"
             self.error = f"{exc_type.__name__}: {exc}"
-        self.end = time.time()
+        self.duration_ms = round(elapsed * 1000, 3)
+        self.end = self.start + elapsed
         self._tracer._export(self)
 
     def to_dict(self) -> dict:
@@ -164,7 +185,7 @@ class Span:
             "parent_id": self.parent_id,
             "name": self.name,
             "start": self.start,
-            "duration_ms": round((self.end - self.start) * 1000, 3),
+            "duration_ms": self.duration_ms,
             "attrs": self.attrs,
             "status": self.status,
             "error": self.error,
@@ -244,7 +265,15 @@ class Tracer:
     descendants (local and remote) inherit the decision. 1.0 records
     everything (library/test default), 0.0 records nothing while keeping
     propagation wired; service boots default to
-    DEFAULT_SERVICE_SAMPLE_RATE via configure_default_tracer."""
+    DEFAULT_SERVICE_SAMPLE_RATE via configure_default_tracer.
+
+    `annotate`, when set, is called with the name of every SAMPLED span as
+    it is entered and must return a context manager, which the span enters
+    and leaves with itself, on the same thread. The trainer's server sets
+    `jax.profiler.TraceAnnotation`: with no profiler session that is an
+    atomic load, with one the span is an event on the profiler's host
+    plane, on the device trace's clock. Unset (every other service) it
+    costs one `is None`."""
 
     service: str = "dragonfly"
     path: str = ""
@@ -255,6 +284,7 @@ class Tracer:
     ring_size: int = 2048
     sample_rate: float = 1.0
     rng: Any = None  # random.random-compatible draw source (tests seed it)
+    annotate: Optional[Callable[[str], ContextManager]] = None
     _ring: deque = field(default_factory=lambda: deque(maxlen=2048), repr=False)
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
     _fh: Any = field(default=None, repr=False)

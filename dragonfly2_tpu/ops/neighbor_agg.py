@@ -9,6 +9,11 @@ mean + matmul, all of which XLA tiles onto the MXU with no dynamic shapes.
 The XLA path below is the default; ops.neighbor_agg_pallas holds the fused
 MXU kernel for the same contract, auto-selected by `neighbor_aggregate`
 on TPU for VMEM-sized graphs.
+
+In the training step the caller names these ops for the device trace
+(models/graphsage.py: `neighbor_gather` under the `gather` scope, with its
+scatter-add VJP; `masked_mean` under `reduce`). A kernel that replaces one
+of them keeps its forward and its backward under the same scope.
 """
 
 from __future__ import annotations

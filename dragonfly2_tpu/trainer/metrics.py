@@ -20,6 +20,7 @@ serves — dfml prints these curves without ever shipping full step logs.
 from __future__ import annotations
 
 import math
+import statistics
 import threading
 
 from dragonfly2_tpu.observability.metrics import default_registry
@@ -99,6 +100,9 @@ class TrainRunTelemetry:
         # mesh + per-device bytes of the placed run (train_gnn._placement);
         # None for a trainer that places nothing (the MLP)
         self.placement: dict | None = None
+        # the scan loop's call periods and turns (on_calls); None for a
+        # trainer whose loop makes no such calls (the MLP)
+        self.calls: dict | None = None
         # steps/s anchors at the FIRST report, not construction: the gap
         # between them is XLA setup + first-call compile (5-30 s on CPU),
         # which would understate a short run's throughput 10x+. The first
@@ -147,6 +151,30 @@ class TrainRunTelemetry:
         with self._lock:
             self.placement = placement
 
+    def on_calls(self, calls: list[tuple[float, float]]) -> None:
+        """Record, once at the run's end, how the host paced the run's scan
+        calls: `calls` holds each call's (start, end) on one monotonic clock.
+        A period runs from one call's start to the next one's, a turn from
+        one call's end to the next one's start (reports, the log line, the
+        thread hand-off). The first call compiles or loads the program, so
+        periods count from the second; `stall_ms` is the time by which
+        periods exceeded 1.5 x their median: what the host held the chip
+        back, in a run nobody traced."""
+        starts = [a for a, _ in calls]
+        periods = [(b - a) * 1e3 for a, b in zip(starts[1:], starts[2:])]
+        turns = [(nxt - end) * 1e3 for (_, end), nxt in zip(calls, starts[1:])]
+        p50 = statistics.median(periods) if periods else None
+        summary = {
+            "count": len(calls),
+            "period_ms_p50": _round3(p50),
+            "period_ms_max": _round3(max(periods, default=None)),
+            "turn_ms_p50": _round3(statistics.median(turns) if turns else None),
+            "turn_ms_max": _round3(max(turns, default=None)),
+            "stall_ms": _round3(sum(max(0.0, p - 1.5 * p50) for p in periods)),
+        }
+        with self._lock:
+            self.calls = summary
+
     def steps_per_sec(self) -> float | None:
         with self._lock:
             return self._steps_per_sec_locked()
@@ -181,4 +209,9 @@ class TrainRunTelemetry:
                 "steps_per_sec": sps,
                 "curve": [(s, round(v, 6)) for s, v in self._curve],
                 "placement": self.placement,
+                "calls": self.calls,
             }
+
+
+def _round3(value: float | None) -> float | None:
+    return None if value is None else round(value, 3)

@@ -126,6 +126,14 @@ def main() -> None:
         format="%(asctime)s %(name)s %(levelname)s %(message)s",
     )
     logger.info("compile cache at %s", jaxenv.enable_compile_cache())
+    # every sampled span of this process is also a TraceMe event of the
+    # profiler that can trace the chip, under its own name and on the device
+    # trace's clock (an atomic load while no profiler session runs)
+    import jax.profiler
+
+    from dragonfly2_tpu.observability.tracing import configure_default_tracer
+
+    configure_default_tracer(service="trainer").annotate = jax.profiler.TraceAnnotation
     asyncio.run(
         run_trainer(
             host=args.host, port=args.port, model_dir=args.model_dir,

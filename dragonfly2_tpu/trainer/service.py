@@ -42,7 +42,7 @@ import logging
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Any
+from typing import Any, Callable
 
 import numpy as np
 
@@ -110,6 +110,21 @@ class TrainerConfig:
     # Sessions opened but never closed are dropped after this many seconds
     # (checked at every open/close); 0 disables eviction.
     session_ttl: float = 3600.0
+
+
+async def _export(model: str, save: Callable[[], Any]) -> tuple[Any, float]:
+    """Run one model's artifact save in a worker thread under the
+    `trainer.export` span; returns (what `save` returned, its seconds) —
+    the manifest's `evaluation.export_seconds`, read beside the span."""
+    from dragonfly2_tpu.observability.tracing import default_tracer
+
+    def run() -> tuple[Any, float]:
+        t0 = time.perf_counter()
+        with default_tracer().span("trainer.export", model=model):
+            out = save()
+        return out, round(time.perf_counter() - t0, 3)
+
+    return await asyncio.to_thread(run)
 
 
 class TrainerService:
@@ -438,7 +453,7 @@ class TrainerService:
                     artifacts.save_sketch(path, ds.feature_sketch)
                 return path, artifacts.artifact_digest(path)
 
-            path, digest = await asyncio.to_thread(_save_mlp)
+            (path, digest), evaluation["export_seconds"] = await _export("mlp", _save_mlp)
             out["mlp"] = {
                 "artifact": str(path), "digest": digest,
                 "evaluation": evaluation, "telemetry": mlp_tel.summary(),
@@ -483,7 +498,10 @@ class TrainerService:
                     artifacts.save_sketch(path, ds.feature_sketch)
                 native_error = None
                 try:
-                    artifacts.save_native(path, train_gnn.make_model(cfg), state.params, ds.graph)
+                    with default_tracer().span("trainer.export.native"):
+                        artifacts.save_native(
+                            path, train_gnn.make_model(cfg), state.params, ds.graph
+                        )
                 except Exception as e:
                     # the flax artifact still serves, so the run is not failed
                     # — but the missing scorer.dfsc is named in the result
@@ -492,7 +510,9 @@ class TrainerService:
                 # digest LAST: it must cover every file the loader will read
                 return path, artifacts.artifact_digest(path), native_error
 
-            path, digest, native_error = await asyncio.to_thread(_save_gnn)
+            (path, digest, native_error), evaluation["export_seconds"] = await _export(
+                "gnn", _save_gnn
+            )
             out["gnn"] = {
                 "artifact": str(path), "digest": digest,
                 "evaluation": evaluation, "telemetry": gnn_tel.summary(),

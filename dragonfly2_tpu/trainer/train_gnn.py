@@ -14,6 +14,7 @@ received CSV chunks and dropped them on the floor).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import time
 from dataclasses import dataclass
 from functools import partial
@@ -26,9 +27,11 @@ import optax
 from flax.training import train_state
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dragonfly2_tpu.models.graphsage import TopoGraph, TopoScorer
+from dragonfly2_tpu.models.graphsage import LOSS, OPTIMIZER, SAMPLE, TopoGraph, TopoScorer
+from dragonfly2_tpu.observability.tracing import default_tracer
 from dragonfly2_tpu.parallel import mesh as meshlib
 from dragonfly2_tpu.trainer.synthetic import PairBatch, sample_batch
+from dragonfly2_tpu.utils import jaxenv
 
 
 @dataclass
@@ -76,7 +79,8 @@ def _as_jnp_graph(g: TopoGraph) -> TopoGraph:
 
 def loss_fn(apply_fn: Callable, params: Any, g: TopoGraph, batch: PairBatch) -> jnp.ndarray:
     pred = apply_fn(params, g, batch.child, batch.parent, batch.feats)
-    return jnp.mean((pred - batch.label) ** 2)
+    with jax.named_scope(LOSS):
+        return jnp.mean((pred - batch.label) ** 2)
 
 
 def make_train_step(remat: bool = False, *, with_metrics: bool = False) -> Callable:
@@ -99,10 +103,11 @@ def make_train_step(remat: bool = False, *, with_metrics: bool = False) -> Calla
     ):
         apply_fn = jax.checkpoint(state.apply_fn) if remat else state.apply_fn
         loss, grads = jax.value_and_grad(partial(loss_fn, apply_fn))(state.params, g, batch)
-        if with_metrics:
-            gnorm = optax.global_norm(grads)
-            return state.apply_gradients(grads=grads), (loss, gnorm)
-        return state.apply_gradients(grads=grads), loss
+        with jax.named_scope(OPTIMIZER):
+            if with_metrics:
+                gnorm = optax.global_norm(grads)
+                return state.apply_gradients(grads=grads), (loss, gnorm)
+            return state.apply_gradients(grads=grads), loss
 
     return step
 
@@ -230,13 +235,15 @@ def make_scan_step(
         n_pool = pool.child.shape[0]
 
         def one(carry, k):
-            idx = jax.random.randint(k, (batch_size,), 0, n_pool)
-            batch = PairBatch(
-                *(jax.lax.with_sharding_constraint(a[idx], batch_sh) for a in pool)
-            )
+            with jax.named_scope(SAMPLE):
+                idx = jax.random.randint(k, (batch_size,), 0, n_pool)
+                batch = PairBatch(
+                    *(jax.lax.with_sharding_constraint(a[idx], batch_sh) for a in pool)
+                )
             return step(carry, gg, batch)
 
-        keys = jax.random.split(key, steps_per_call)
+        with jax.named_scope(SAMPLE):
+            keys = jax.random.split(key, steps_per_call)
         return jax.lax.scan(one, st, keys)
 
     return jax.jit(
@@ -292,39 +299,62 @@ async def train_async(
     grad-norm land in the dragonfly_train_* families after every call. The
     grad norms ride the scan's ys (with_metrics), so the telemetry costs no
     extra D2H sync: the per-call np.asarray pull already materializes them.
+    It also gets every call's start and end once, at the run's end.
+
+    Spans (children of the caller's current span; to_thread copies the
+    context): `trainer.gnn.setup` around init + placement + building the
+    jit, `trainer.gnn.call` around each call in the worker, and inside it
+    `trainer.gnn.dispatch` (key split + enqueue) and `trainer.gnn.pull` (the
+    D2H pulls). The loop's turn between two calls is the gap between two
+    `trainer.gnn.call` spans.
     """
     mesh = mesh or meshlib.make_mesh()
     steps_per_call = max(1, min(steps_per_call, steps))
     calls = -(-steps // steps_per_call)
     with_metrics = telemetry is not None
 
+    tracer = default_tracer()
+
     def _setup():
-        state = init_state(cfg, graph, seed)
-        return shard_for_training_scan(
-            state, graph, pairs, mesh,
-            batch_size=cfg.batch_size, steps_per_call=steps_per_call,
-            remat=cfg.remat, with_metrics=with_metrics,
-        )
+        with tracer.span("trainer.gnn.setup"):
+            state = init_state(cfg, graph, seed)
+            return shard_for_training_scan(
+                state, graph, pairs, mesh,
+                batch_size=cfg.batch_size, steps_per_call=steps_per_call,
+                remat=cfg.remat, with_metrics=with_metrics,
+            )
 
     state, g, pool, multi_step = await asyncio.to_thread(_setup)
     if telemetry is not None:
         telemetry.on_placed(_placement(mesh, state, g, cfg.batch_size))
     key = jax.random.PRNGKey(seed)
 
-    def _one_call(st, k):
-        k, sub = jax.random.split(k)
-        st, ys = multi_step(st, g, pool, sub)
-        # D2H pull materializes the whole call's chain before returning to
-        # the loop — the same sync discipline the bench windows use
-        if with_metrics:
-            ls, gn = ys
-            return st, k, np.asarray(ls), np.asarray(gn)
-        return st, k, np.asarray(ys), None
+    # each call's (start, end) in the worker, always on: two clock reads a
+    # call, summarized once at the run's end (telemetry.on_calls)
+    call_times: list[tuple[float, float]] = []
+
+    def _one_call(st, k, index):
+        t_start = time.perf_counter()
+        with tracer.span("trainer.gnn.call", index=index, steps=steps_per_call):
+            with tracer.span("trainer.gnn.dispatch"):
+                k, sub = jax.random.split(k)
+                # the first call compiles the step or loads it from the
+                # persistent cache: under a key that holds its scope names
+                with jaxenv.op_names_in_cache_key() if index == 0 else contextlib.nullcontext():
+                    st, ys = multi_step(st, g, pool, sub)
+            # D2H pull materializes the whole call's chain before returning to
+            # the loop — the same sync discipline the bench windows use
+            with tracer.span("trainer.gnn.pull"):
+                ls, gn = ys if with_metrics else (ys, None)
+                ls = np.asarray(ls)
+                gn = None if gn is None else np.asarray(gn)
+        call_times.append((t_start, time.perf_counter()))
+        return st, k, ls, gn
 
     losses: list[float] = []
     t0 = time.perf_counter()
     for i in range(calls):
-        state, key, ls, gn = await asyncio.to_thread(_one_call, state, key)
+        state, key, ls, gn = await asyncio.to_thread(_one_call, state, key, i)
         if telemetry is not None and gn is not None:
             for lv, gv in zip(ls, gn):
                 telemetry.on_step(
@@ -337,6 +367,8 @@ async def train_async(
                 f"step {done}/{calls * steps_per_call} loss={losses[-1]:.5f} "
                 f"({done / (time.perf_counter() - t0):.2f} steps/s)"
             )
+    if telemetry is not None:
+        telemetry.on_calls(call_times)
     return state, losses
 
 

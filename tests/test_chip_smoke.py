@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -105,9 +106,23 @@ def test_platform_phase_refuses_a_device_child_that_fell_back():
 
 def test_compile_cache_honours_env_else_fixed_checkout_path(tmp_path, monkeypatch):
     before = jax.config.jax_compilation_cache_dir
+    regex = "jax_hlo_source_file_canonicalization_regex"
+    relative = getattr(jax.config, regex)
     monkeypatch.setenv(jaxenv.CACHE_ENV, str(tmp_path))
-    assert jaxenv.enable_compile_cache() == tmp_path
-    assert jax.config.jax_compilation_cache_dir == before  # nothing set in code
+    try:
+        assert jaxenv.enable_compile_cache() == tmp_path
+        assert jax.config.jax_compilation_cache_dir == before  # no directory set in code
+        # source files relative to the checkout, so that a key that holds them
+        # (op_names_in_cache_key) is the same from a checkout in another place
+        assert getattr(jax.config, regex) == re.escape(f"{REPO}/")
+    finally:
+        jax.config.update(regex, relative)
+    # the step's first call is keyed by its op names too (a cached program
+    # keeps the names it was compiled with); nothing else is
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is False
+    with jaxenv.op_names_in_cache_key():
+        assert jax.config.jax_compilation_cache_include_metadata_in_key is True
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is False
     monkeypatch.delenv(jaxenv.CACHE_ENV)
     assert jaxenv.compile_cache_dir() == REPO / ".jax_cache"
     # unset, the helper does configure the fixed path (fresh process: this
