@@ -246,7 +246,6 @@ def bench_native_scoring(
     import jax
     import jax.numpy as jnp
 
-    from dragonfly2_tpu.models.graphsage import TopoGraph
     from dragonfly2_tpu.native import NativeScorer, export_scorer_artifact
     from dragonfly2_tpu.trainer import synthetic, train_gnn
 
@@ -254,7 +253,7 @@ def bench_native_scoring(
     cfg = train_gnn.GNNTrainConfig()
     model = train_gnn.make_model(cfg)
     state = train_gnn.init_state(cfg, cluster.graph, rng_seed=7)
-    g = TopoGraph(*(jnp.asarray(a) for a in cluster.graph))
+    g = jax.tree.map(jnp.asarray, cluster.graph)
     z = np.asarray(
         jax.jit(lambda p, gg: model.apply(p, gg, method=model.embed))(state.params, g)
     )

@@ -136,7 +136,6 @@ async def run_scoring_stress(args: argparse.Namespace) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from dragonfly2_tpu.models.graphsage import TopoGraph
     from dragonfly2_tpu.native import (
         MicroBatchScorer,
         NativeScorer,
@@ -156,7 +155,7 @@ async def run_scoring_stress(args: argparse.Namespace) -> dict:
     cfg = train_gnn.GNNTrainConfig()
     model = train_gnn.make_model(cfg)
     state = train_gnn.init_state(cfg, cluster.graph, rng_seed=7)
-    g = TopoGraph(*(jnp.asarray(a) for a in cluster.graph))
+    g = jax.tree.map(jnp.asarray, cluster.graph)
     z = np.asarray(
         jax.jit(lambda p, gg: model.apply(p, gg, method=model.embed))(state.params, g)
     )

@@ -13,13 +13,16 @@ All shapes static; compute in bfloat16 on the MXU; params float32.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import TYPE_CHECKING, NamedTuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from dragonfly2_tpu.ops.neighbor_agg import masked_mean, neighbor_gather
+
+if TYPE_CHECKING:
+    from dragonfly2_tpu.ops.neighbor_agg_pallas import EdgesByDst
 
 
 class TopoGraph(NamedTuple):
@@ -29,12 +32,17 @@ class TopoGraph(NamedTuple):
     neighbors:  [N, K] int32 neighbor indices (padded slots point at 0)
     mask:       [N, K] float32 1.0 for real edges
     edge_feats: [N, K, E] float32 probe stats (rtt mean/std/min, probe count)
+    by_dst:     `neighbors`' slots sorted by destination, for the gather's VJP:
+                derived data of a training run placed on one TPU chip, which
+                only trainer.train_gnn's placement fills. The published graph
+                is the four arrays; without the table the VJP is `jnp.take`'s
     """
 
     node_feats: jnp.ndarray
     neighbors: jnp.ndarray
     mask: jnp.ndarray
     edge_feats: jnp.ndarray
+    by_dst: EdgesByDst | None = None
 
 
 # The scope vocabulary of the training step: every device op of the step
@@ -47,7 +55,8 @@ class TopoGraph(NamedTuple):
 # replaces a part keeps its forward and its backward under that part's name.
 # The scopes do not nest. Never rename a flax module for the trace's sake:
 # module names are parameter-tree keys and seed the initial weights.
-GATHER = "gather"        # neighbor_gather in SAGELayer; its VJP is the scatter-add
+GATHER = "gather"        # neighbor_gather in SAGELayer, and its VJP: a custom_vjp (sorted rows
+                         # summed by run, a kernel) with TopoGraph.by_dst, else XLA's scatter-add
 MESSAGE = "message"      # edge projection, the sum of the three terms, gelu
 REDUCE = "reduce"        # masked_mean over the K neighbor slots
 DENSE = "dense"          # every other Dense / LayerNorm of the encoder, the L2 norm
@@ -86,7 +95,7 @@ class SAGELayer(nn.Module):
                 name="msg_edge",
             )(g.edge_feats.astype(self.dtype))
         with jax.named_scope(GATHER):
-            nbr = neighbor_gather(u, g.neighbors)  # [N, K, F]
+            nbr = neighbor_gather(u, g.neighbors, g.by_dst)  # [N, K, F]
         with jax.named_scope(MESSAGE):
             msg = nn.gelu(nbr + s[:, None, :] + v)  # [N, K, F]
         with jax.named_scope(REDUCE):

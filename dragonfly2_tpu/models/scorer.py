@@ -123,7 +123,7 @@ class GNNScorer:
     def refresh(self, graph: TopoGraph) -> None:
         """Recompute cached node embeddings + head partials (call when
         telemetry updates)."""
-        g = TopoGraph(*(jax.device_put(np.asarray(a), self._device) for a in graph))
+        g = jax.tree.map(lambda a: jax.device_put(np.asarray(a), self._device), graph)
         self._z, self._uc, self._up = self._embed_and_proj(self._params, g)
         self._z.block_until_ready()
 

@@ -12,24 +12,70 @@ on TPU for VMEM-sized graphs.
 
 In the training step the caller names these ops for the device trace
 (models/graphsage.py: `neighbor_gather` under the `gather` scope, with its
-scatter-add VJP; `masked_mean` under `reduce`). A kernel that replaces one
-of them keeps its forward and its backward under the same scope.
+VJP; `masked_mean` under `reduce`). A kernel that replaces one of them keeps
+its forward and its backward under the same scope.
+
+The gather's VJP sums N*K cotangent rows into N. The one XLA derives from
+`jnp.take` is a scatter-add by unsorted row numbers, which the TPU runs row
+by row (17-22 ns a row: over half of a training step, PERF.md). The table is
+fixed for a whole run, so a run placed on one TPU chip sorts its slots by
+destination once, on the host, and `neighbor_gather`, a `custom_vjp` there,
+sums in the cheap direction: gather the cotangent rows into destination
+order, and add up contiguous runs in a kernel (ops.neighbor_agg_pallas:
+`edges_by_destination`, `sum_by_destination`). Everywhere else (no table: CPU,
+meshes of several devices, float32 or odd widths, inference, tools) it is
+`jnp.take` and its derived VJP, to the letter.
 """
 
 from __future__ import annotations
 
+from typing import TYPE_CHECKING
+
 import jax
 import jax.numpy as jnp
 
+if TYPE_CHECKING:
+    from dragonfly2_tpu.ops.neighbor_agg_pallas import EdgesByDst
 
-def neighbor_gather(h: jnp.ndarray, neighbors: jnp.ndarray) -> jnp.ndarray:
+
+def neighbor_gather(
+    h: jnp.ndarray, neighbors: jnp.ndarray, by_dst: EdgesByDst | None = None
+) -> jnp.ndarray:
     """Gather node states for each padded neighbor slot.
 
     h: [N, H] node states; neighbors: [N, K] int32 indices (padding may point
     anywhere valid, typically 0 — the mask zeroes its contribution).
-    Returns [N, K, H].
+    Returns [N, K, H]. With `by_dst` (the same table, sorted, as placement
+    builds it for the kernel) and states the kernel sums, the VJP adds up runs
+    of sorted rows; its value is that of `jnp.take`'s, up to the order of
+    summation.
     """
+    if by_dst is not None:
+        from dragonfly2_tpu.ops import neighbor_agg_pallas as pk
+
+        if h.shape[0] != neighbors.shape[0]:
+            raise ValueError(f"{h.shape[0]} states for a table of {neighbors.shape[0]} rows")
+        if pk.kernel_sums(h.shape[1], h.dtype):
+            return _gather_sorted_vjp(h, neighbors, by_dst)
     return jnp.take(h, neighbors, axis=0)
+
+
+@jax.custom_vjp
+def _gather_sorted_vjp(h, neighbors, by_dst):
+    return jnp.take(h, neighbors, axis=0)
+
+
+def _gather_fwd(h, neighbors, by_dst):
+    return jnp.take(h, neighbors, axis=0), by_dst
+
+
+def _gather_bwd(by_dst, g):
+    from dragonfly2_tpu.ops.neighbor_agg_pallas import sum_by_destination
+
+    return sum_by_destination(by_dst, g), None, None
+
+
+_gather_sorted_vjp.defvjp(_gather_fwd, _gather_bwd)
 
 
 def masked_mean(x: jnp.ndarray, mask: jnp.ndarray, *, eps: float = 1e-6) -> jnp.ndarray:

@@ -72,7 +72,8 @@ def infer_param_sharding(params: Any, mesh: Mesh) -> Any:
 
 
 def graph_shardings(mesh: Mesh) -> tuple[NamedSharding, ...]:
-    """Shardings for TopoGraph fields: node rows over "data"."""
+    """Shardings for TopoGraph's four arrays: node rows over "data". (Its
+    `by_dst` exists on a mesh of one device alone: trainer.train_gnn.)"""
     row = NamedSharding(mesh, P(DATA_AXIS))
     return (
         row,  # node_feats [N, F]

@@ -74,7 +74,7 @@ def init_state(
 
 
 def _as_jnp_graph(g: TopoGraph) -> TopoGraph:
-    return TopoGraph(*(jnp.asarray(a) for a in g))
+    return jax.tree.map(jnp.asarray, g)
 
 
 def loss_fn(apply_fn: Callable, params: Any, g: TopoGraph, batch: PairBatch) -> jnp.ndarray:
@@ -121,9 +121,12 @@ def _place_sharded(
     state: train_state.TrainState, g: TopoGraph, mesh: Mesh
 ) -> tuple[train_state.TrainState, Any, TopoGraph, TopoGraph]:
     """Shared placement: pad node rows to the dp size, kernels over "model",
-    node rows over "data". Returns (state, state_sharding, g, g_sharding)."""
+    node rows over "data". The neighbor table is fixed from here to the run's
+    end, so its transpose is fixed here too (`_edges_by_destination`).
+    Returns (state, state_sharding, g, g_sharding)."""
     dp = mesh.shape[meshlib.DATA_AXIS]
     g = pad_graph(g, meshlib.pad_to_multiple(g.node_feats.shape[0], dp))
+    g = g._replace(by_dst=_edges_by_destination(state, g, mesh))
     param_sh = meshlib.infer_param_sharding(state.params, mesh)
     state_sh = train_state.TrainState(
         step=NamedSharding(mesh, P()),
@@ -135,9 +138,30 @@ def _place_sharded(
         ),
     )
     state = jax.device_put(state, state_sh)
-    g_sh = TopoGraph(*meshlib.graph_shardings(mesh))
+    g_sh = TopoGraph(
+        *meshlib.graph_shardings(mesh), by_dst=jax.tree.map(lambda _: meshlib.replicated(mesh), g.by_dst)
+    )
     g = jax.device_put(_as_jnp_graph(g), g_sh)
     return state, state_sh, g, g_sh
+
+
+def _gathered_states(state: train_state.TrainState) -> tuple[int, Any]:
+    """Width and dtype of the states a SAGE layer gathers, read off the model
+    whose `apply` the state holds (0, None: not a model of ours)."""
+    model = getattr(state.apply_fn, "__self__", None)
+    return getattr(model, "hidden", 0), getattr(model, "dtype", None)
+
+
+def _edges_by_destination(state: train_state.TrainState, g: TopoGraph, mesh: Mesh):
+    """`g.by_dst`: the neighbor table's slots sorted by destination, once, on
+    the host, where the kernel that sums the gather's VJP over them will run:
+    one TPU chip (GSPMD cannot partition a `pallas_call`) and states it adds
+    up exactly. Elsewhere None, and the VJP stays `jnp.take`'s."""
+    if mesh.size != 1 or mesh.devices.flat[0].platform != "tpu":
+        return None
+    from dragonfly2_tpu.ops.neighbor_agg_pallas import edges_by_destination
+
+    return edges_by_destination(np.asarray(g.neighbors), *_gathered_states(state))
 
 
 def shard_for_training(
@@ -257,18 +281,31 @@ def make_scan_step(
 def _placement(mesh: Mesh, state: Any, g: TopoGraph, batch_size: int) -> dict:
     """What the placed run occupies, for the run manifest: the Dense kernels
     the tensor-parallel rule shards and the graph's node rows, both read
-    back from the placed arrays, plus the rows of one pair batch each device
-    is constrained to inside the step."""
+    back from the placed arrays, the rows of one pair batch each device
+    is constrained to inside the step, and which VJP the gather takes."""
     kernels = [
         leaf for leaf in jax.tree.leaves(state.params)
         if leaf.ndim == 2 and meshlib.MODEL_AXIS in leaf.sharding.spec
     ]
     batch_size = meshlib.pad_to_multiple(batch_size, mesh.shape[meshlib.DATA_AXIS])
+    gather_vjp = {"path": "derived"}
+    if g.by_dst is not None:  # built for this state's model alone: the kernel runs
+        blocks, per_block = g.by_dst.perm.shape
+        width, dtype = _gathered_states(state)
+        gather_vjp = {
+            "path": "sorted_kernel", "blocks": blocks,
+            "block_bytes": per_block * width * jnp.dtype(dtype).itemsize,
+        }
     return {
         "mesh": {k: int(v) for k, v in mesh.shape.items()},
         "kernels": meshlib.placement_report(kernels),
-        "graph": meshlib.placement_report(g),
+        "graph": meshlib.placement_report(g._replace(by_dst=None)),
         "batch_rows_per_device": meshlib.batch_sharding(mesh).shard_shape((batch_size,))[0],
+        "gather_vjp": {
+            **gather_vjp,
+            "slots": int(g.neighbors.size),
+            "max_in_degree": int(np.bincount(np.asarray(g.neighbors).ravel()).max()),
+        },
     }
 
 

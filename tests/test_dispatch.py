@@ -375,7 +375,6 @@ class TestNativeHandlePool:
         import jax
         import jax.numpy as jnp
 
-        from dragonfly2_tpu.models.graphsage import TopoGraph
         from dragonfly2_tpu.native import NativeScorer, export_scorer_artifact
         from dragonfly2_tpu.trainer import synthetic, train_gnn
 
@@ -383,7 +382,7 @@ class TestNativeHandlePool:
         cfg = train_gnn.GNNTrainConfig(hidden=64, embed_dim=32, num_layers=2)
         model = train_gnn.make_model(cfg)
         state = train_gnn.init_state(cfg, cluster.graph, rng_seed=3)
-        g = TopoGraph(*(jnp.asarray(a) for a in cluster.graph))
+        g = jax.tree.map(jnp.asarray, cluster.graph)
         z = np.asarray(
             jax.jit(lambda p, gg: model.apply(p, gg, method=model.embed))(state.params, g)
         )

@@ -1,0 +1,240 @@
+"""The gather's VJP over a destination-sorted edge table
+(ops.neighbor_agg_pallas, ops.neighbor_agg.neighbor_gather): the host's table
+in plain numpy, the segmented-sum kernel interpreted on the CPU, the
+`custom_vjp` in a training step, the run manifest's word on it.
+
+SIX tests, most of them loops over cases, in a file of their own, on purpose.
+Tier-1 hands files to its workers by their number of tests, largest first, two
+at a time (xdist's loadfile), so a file's count decides when it runs and what
+every file after it runs beside. The scheduler plane's host-timing assertions
+(tests/test_bench_contract.py::test_ml_observability_contract,
+test_dispatch.py's thread scaling; 15 tests each) pass at the parent because
+they run early, before the JAX-heavy files. As parametrised cases these tests
+made a large file: it started with the run, took a worker for 20 s and pushed
+those assertions into the JAX-heavy middle, where 6 of 13 whole runs failed
+one (ROADMAP D10); a light file of 42 cases alone still moved
+test_bench_contract.py behind an 11 s file. With six tests this file ranks
+after every timing-sensitive one, runs near the end, and leaves the order of
+all files before it as the parent has it. A failure names its case."""
+
+import re
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental.pallas import tpu as pltpu
+
+from dragonfly2_tpu.models.features import FEATURE_DIM
+from dragonfly2_tpu.models.graphsage import TopoGraph
+from dragonfly2_tpu.ops import neighbor_agg_pallas as pk
+from dragonfly2_tpu.ops.neighbor_agg import neighbor_gather
+from dragonfly2_tpu.parallel import mesh as meshlib
+from dragonfly2_tpu.trainer import train_gnn
+from dragonfly2_tpu.trainer.synthetic import PairBatch
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmarks"))
+
+import scope_reduce  # noqa: E402  (the benchmark's reader of the names: plain Python)
+
+
+def _hub_table(n, k, seed=0):
+    """A neighbor table with what the VJP must not trip on: a hub (a quarter
+    of all slots point at row 3), padded slots at row 0, and destinations
+    nobody points at (every row from 3n/4 up, the kernel's last tile whole)."""
+    rng = np.random.default_rng(seed)
+    nbr = rng.integers(1, 3 * n // 4, size=(n, k)).astype(np.int32)
+    pick = rng.random((n, k))
+    nbr[pick < 0.25] = 3
+    nbr[pick > 0.9] = 0
+    return nbr
+
+
+
+def _table_in_blocks(monkeypatch, nbr, width, dtype, blocks):
+    """The table placement would build, its slots in `blocks` source blocks
+    (as if the cotangent were `blocks` times what XLA's gather holds)."""
+    monkeypatch.setattr(pk, "BLOCK_BYTES", nbr.size * width * 2 // blocks)
+    return pk.edges_by_destination(nbr, width, dtype)
+
+
+def _table(kind, n, k):
+    if kind == "hub":
+        return _hub_table(n, k, seed=6)
+    if kind == "one_row":  # every slot a padded one: the largest hub there is
+        return np.zeros((n, k), np.int32)
+    return np.random.default_rng(7).integers(0, n, (n, k)).astype(np.int32)
+
+
+def test_table_sorts_every_slot_once(monkeypatch):
+    """The host's part, plain numpy: whatever the graph, the table is a
+    permutation of each block's slots in destination order, and the kernel's
+    windows count every slot exactly once, in its tile. Where the shapes do
+    not tile there is no table."""
+    for n, k in [(512, 16), (256, 24), (1024, 6), (100, 7), (96, 5)]:
+        for kind in ["hub", "one_row", "uniform"]:
+            for blocks in [1, 4]:
+                case = (n, k, kind, blocks)
+                nbr = _table(kind, n, k)
+                t = _table_in_blocks(monkeypatch, nbr, 128, jnp.bfloat16, blocks)
+                if n % pk.TILE_DST:
+                    assert t is None, case
+                    continue
+                assert t.perm.shape == (blocks, n * k // blocks), case
+                per_block = t.perm.shape[1]
+                assert (np.sort(t.perm, axis=1) == np.arange(per_block)).all(), case
+                dst = np.take_along_axis(nbr.reshape(blocks, -1), t.perm, axis=1)
+                assert (np.diff(dst, axis=1) >= 0).all(), case
+                live = t.live[0]
+                tile, block, start, flags = t.items[:, :live]
+                # by tile, every tile written
+                assert (np.diff(tile) >= 0).all() and set(tile) == set(range(n // pk.TILE_DST)), case
+                assert (start % pk.ALIGN == 0).all() and (start + pk.WINDOW <= per_block).all(), case
+                local = t.local[:live, 0]
+                assert (local >= 0).sum() == n * k and local.max() < pk.TILE_DST, case
+                rows = dst[block[:, None], start[:, None] + np.arange(pk.WINDOW)]
+                assert (np.where(local >= 0, rows - tile[:, None] * pk.TILE_DST, -1) == local).all(), case
+
+
+def test_work_list_shapes_depend_on_n_and_k_alone(monkeypatch):
+    tables = [_table_in_blocks(monkeypatch, _table(kind, 512, 16), 128, jnp.bfloat16, 2)
+              for kind in ("hub", "one_row", "uniform")]
+    shapes = [[x.shape for x in jax.tree.leaves(t)] for t in tables]
+    assert shapes[0] == shapes[1] == shapes[2]
+
+
+def test_source_blocks_follow_the_cotangents_bytes():
+    """Placement sizes the blocks from N*K*H*itemsize, not from the benchmark's cells."""
+    for case in [
+        (32768, 16, 512, jnp.bfloat16, 16),   # gnn-32k-512: 512 MB of cotangent rows in 32 MB blocks
+        (65536, 16, 256, jnp.bfloat16, 16),   # gnn-64k-256
+        (65536, 16, 512, jnp.bfloat16, 32),   # twice the slots or the width: twice the blocks,
+        (32768, 16, 1024, jnp.bfloat16, 32),  # never larger ones (14 ns a row against 3.5)
+        (2048, 16, 256, jnp.bfloat16, 1),
+        (24576, 10, 384, jnp.bfloat16, 6),    # 5.6 blocks' worth: 6 tile it
+        (65536, 16, 1024, jnp.bfloat16, None),  # 64 blocks: the kernel's windows would cost more than XLA's scatter
+        (256, 1, 128, jnp.bfloat16, None),    # fewer slots than a window
+        (32768, 16, 512, jnp.float32, None),
+        (32768, 16, 500, jnp.bfloat16, None),
+        (32700, 16, 512, jnp.bfloat16, None),
+    ]:
+        n, k, width, dtype, blocks = case
+        t = pk.edges_by_destination(np.zeros((n, k), np.int32), width, dtype)
+        if blocks is None:
+            assert t is None, case
+            continue
+        assert isinstance(t, pk.EdgesByDst) and t.perm.shape == (blocks, n * k // blocks), case
+        assert t.perm.shape[1] * width * 2 <= pk.BLOCK_BYTES, case
+
+
+def test_bare_gather_lowers_to_todays_hlo():
+    """Without a table (inference, tools, CPU) the op is `jnp.take`, VJP and all."""
+    rng = np.random.default_rng(0)
+    h = jnp.asarray(rng.normal(size=(64, 16)).astype(np.float32))
+    nbr = jnp.asarray(rng.integers(0, 64, size=(64, 4)).astype(np.int32))
+
+    def loss(gather, hh):
+        return jnp.sum(gather(hh) ** 2)
+
+    ours = jax.jit(jax.grad(lambda hh: loss(lambda x: neighbor_gather(x, nbr), hh))).lower(h).as_text()
+    takes = jax.jit(jax.grad(lambda hh: loss(lambda x: jnp.take(x, nbr, axis=0), hh))).lower(h).as_text()
+    assert ours == takes and "scatter" in ours
+
+
+# (n, k, width, dtype, source blocks): small, the interpreter takes 50 ms a window
+KERNEL_CASES = [
+    (512, 12, 128, jnp.bfloat16, 1),  # two tiles, the hub's run of windows longer
+    (512, 12, 128, jnp.bfloat16, 4),  # a window for every block and tile
+    (256, 24, 256, jnp.bfloat16, 2),
+]
+# where no table is built: the VJP is jnp.take's
+DERIVED_CASES = [
+    (512, 12, 96, jnp.bfloat16, 1),   # rows not whole lanes wide
+    (100, 7, 33, jnp.bfloat16, 1),    # n not a multiple of the tile
+    (100, 7, 33, jnp.float32, 1),
+    (512, 12, 128, jnp.float32, 4),   # the MXU would round float32 rows
+]
+
+
+def _take_vjp(nbr, g):
+    h = jnp.zeros((nbr.shape[0], g.shape[-1]), g.dtype)
+    return jax.vjp(lambda x: jnp.take(x, nbr, axis=0), h)[1](g)[0]
+
+
+def test_gather_vjp_with_the_placed_table(monkeypatch):
+    """Against `jax.vjp(jnp.take)` on a graph with a hub, padded slots at row
+    0 and destinations nobody points at: the kernel's sum is as close to the
+    float32 sum as a bfloat16 result can be; without a table it is the
+    derived VJP, bit for bit."""
+    for case in KERNEL_CASES + DERIVED_CASES:
+        n, k, width, dtype, blocks = case
+        nbr = _hub_table(n, k, seed=2)
+        g = jnp.asarray(np.random.default_rng(3).normal(size=(n, k, width)), dtype)
+        h = jnp.zeros((n, width), dtype)
+        table = _table_in_blocks(monkeypatch, nbr, width, dtype, blocks)
+        assert (table is not None) == (case in KERNEL_CASES), case
+        with pltpu.force_tpu_interpret_mode():  # the kernel, where there is a table, on the CPU
+            out, vjp = jax.vjp(lambda x: neighbor_gather(x, nbr, jax.tree.map(jnp.asarray, table)), h)
+            got = np.asarray(vjp(g)[0].astype(jnp.float32))
+        assert out.shape == (n, k, width) and out.dtype == dtype, case
+        if table is None:
+            np.testing.assert_array_equal(got, np.asarray(_take_vjp(nbr, g), np.float32), err_msg=str(case))
+            continue
+        assert table.perm.shape[0] == blocks, case
+        want = np.asarray(_take_vjp(nbr, g.astype(jnp.float32)))
+        np.testing.assert_allclose(got, want, rtol=2.0 ** -8, atol=2.0 ** -8 * np.abs(want).max(), err_msg=str(case))
+        assert not got[3 * n // 4:].any(), case  # nobody points there
+
+    # a table of another graph is refused; states the kernel does not sum leave it alone
+    nbr = _hub_table(512, 12)
+    table = _table_in_blocks(monkeypatch, nbr, 128, jnp.bfloat16, 1)
+    try:
+        neighbor_gather(jnp.zeros((256, 128), jnp.bfloat16), nbr, table)
+    except ValueError as e:
+        assert "256 states for a table of 512 rows" in str(e)
+    else:
+        raise AssertionError("a table of 512 rows took 256 states")
+    h = jnp.ones((512, 128), jnp.float32)
+    assert "scatter" in jax.jit(jax.grad(lambda x: jnp.sum(neighbor_gather(x, nbr, table) ** 2))).lower(h).as_text()
+
+
+def test_a_placed_step_with_the_table(monkeypatch):
+    """A training run at the smallest shapes the kernel takes. The run
+    manifest's `placement` (`train_gnn._placement`) names the VJP: no TPU
+    here, so placement builds no table and the path is `derived`; with the
+    table one TPU chip would get, the kernel's, with its blocks, and the table
+    is not counted as the graph's. In the compiled step the `custom_vjp`'s
+    rule inherits the scope and JAX's backward marker: the reorder's gathers
+    and the kernel (interpreted here: a loop) read as (`gather`, backward), as
+    `scope.gather_bwd_ms` reads them, and no scatter is left under `gather`."""
+    n, k = 256, 16
+    cfg = train_gnn.GNNTrainConfig(hidden=128, embed_dim=16, num_layers=2, batch_size=64)
+    graph = TopoGraph(np.zeros((n, 12), np.float32), _hub_table(n, k), np.ones((n, k), np.float32),
+                      np.zeros((n, k, 4), np.float32))
+    pairs = PairBatch(np.zeros(64, np.int32), np.zeros(64, np.int32),
+                      np.zeros((64, FEATURE_DIM), np.float32), np.zeros(64, np.float32))
+    mesh = meshlib.make_mesh()
+    unplaced = train_gnn.init_state(cfg, graph, 0)
+    state, g, _, _ = train_gnn.shard_for_training_scan(
+        unplaced, graph, pairs, mesh, batch_size=64, steps_per_call=2)
+    assert g.by_dst is None
+    counts = {"slots": n * k, "max_in_degree": int(np.bincount(graph.neighbors.ravel()).max())}
+    assert train_gnn._placement(mesh, state, g, 64)["gather_vjp"] == {"path": "derived", **counts}
+    assert train_gnn._gathered_states(state) == (128, jnp.bfloat16)
+    table = _table_in_blocks(monkeypatch, graph.neighbors, 128, jnp.bfloat16, 2)
+    placement = train_gnn._placement(mesh, state, g._replace(by_dst=table), 64)
+    assert placement["gather_vjp"] == {
+        "path": "sorted_kernel", "blocks": 2, "block_bytes": n * k // 2 * 128 * 2, **counts}
+    assert placement["graph"]["leaves"] == 4
+
+    with pltpu.force_tpu_interpret_mode():  # (unplaced: the interpreter's callbacks take no mesh)
+        step = jax.jit(train_gnn.make_train_step())
+        text = step.lower(unplaced, graph._replace(by_dst=table), pairs).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    gather = {name for name in names if scope_reduce.classify(name)[0] == "gather"}
+    backward = {name for name in gather if scope_reduce.classify(name)[1]}
+    assert any(name.endswith("/gather") for name in backward), sorted(gather)  # the reorder
+    assert any("sum_by_destination" in name and "while" in name for name in backward), sorted(gather)  # the sum
+    assert any(name.endswith("/gather") for name in gather - backward), sorted(gather)  # the forward
+    assert not any("scatter" in name for name in gather), sorted(gather)

@@ -7,7 +7,6 @@ import pytest
 
 from dragonfly2_tpu.models import BandwidthMLP, GraphSAGE, TopoScorer
 from dragonfly2_tpu.models.features import FEATURE_DIM, BASE_WEIGHTS
-from dragonfly2_tpu.models.graphsage import TopoGraph
 from dragonfly2_tpu.models.scorer import GNNScorer, LinearScorer
 from dragonfly2_tpu.ops.neighbor_agg import masked_mean, neighbor_aggregate, neighbor_gather
 from dragonfly2_tpu.trainer import synthetic
@@ -55,7 +54,7 @@ class TestModels:
         assert np.all((np.asarray(out) >= 0) & (np.asarray(out) <= 1))
 
     def test_graphsage_embeddings_normalized(self, tiny_cluster):
-        g = TopoGraph(*(jnp.asarray(a) for a in tiny_cluster.graph))
+        g = jax.tree.map(jnp.asarray, tiny_cluster.graph)
         model = GraphSAGE(hidden=32, embed_dim=16, num_layers=2)
         params = model.init(jax.random.PRNGKey(0), g)
         z = model.apply(params, g)
@@ -63,7 +62,7 @@ class TestModels:
         np.testing.assert_allclose(np.linalg.norm(np.asarray(z), axis=-1), 1.0, atol=1e-3)
 
     def test_toposcorer_jits(self, tiny_cluster):
-        g = TopoGraph(*(jnp.asarray(a) for a in tiny_cluster.graph))
+        g = jax.tree.map(jnp.asarray, tiny_cluster.graph)
         model = TopoScorer(hidden=32, embed_dim=16, num_layers=2)
         idx = jnp.arange(8, dtype=jnp.int32)
         feats = jnp.zeros((8, FEATURE_DIM))
@@ -124,7 +123,7 @@ class TestScorers:
         assert scores.shape == (40,)
         assert np.all((scores > 0) & (scores < 1))
         # scorer head must agree with full-model forward
-        g = TopoGraph(*(jnp.asarray(a) for a in tiny_cluster.graph))
+        g = jax.tree.map(jnp.asarray, tiny_cluster.graph)
         full = model.apply(
             state.params, g, jnp.asarray(child), jnp.asarray(parent), jnp.asarray(tiny_cluster.pairs.feats[:40])
         )

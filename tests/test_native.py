@@ -23,10 +23,9 @@ def trained():
     cfg = train_gnn.GNNTrainConfig(hidden=64, embed_dim=32, num_layers=2)
     model = train_gnn.make_model(cfg)
     state = train_gnn.init_state(cfg, cluster.graph, rng_seed=3)
-    from dragonfly2_tpu.models.graphsage import TopoGraph
     import jax.numpy as jnp
 
-    g = TopoGraph(*(jnp.asarray(a) for a in cluster.graph))
+    g = jax.tree.map(jnp.asarray, cluster.graph)
     z = np.asarray(jax.jit(lambda p, gg: model.apply(p, gg, method=model.embed))(state.params, g))
     jax_scorer = GNNScorer(model, state.params)
     jax_scorer.refresh(g)

@@ -83,7 +83,6 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
 
-    from dragonfly2_tpu.models.graphsage import TopoGraph
     from dragonfly2_tpu.ops.neighbor_agg import masked_mean, neighbor_gather
     from dragonfly2_tpu.trainer import synthetic, train_gnn
 
@@ -97,7 +96,7 @@ def main() -> None:
     cfg = train_gnn.GNNTrainConfig(hidden=hidden, batch_size=batch)
     model = train_gnn.make_model(cfg)
     state = train_gnn.init_state(cfg, cluster.graph, rng_seed=7)
-    g = TopoGraph(*(jnp.asarray(a) for a in cluster.graph))
+    g = jax.tree.map(jnp.asarray, cluster.graph)
     rng = np.random.default_rng(7)
     sel = rng.integers(0, len(cluster.pairs.child), size=batch)
     pb = type(cluster.pairs)(
