@@ -402,11 +402,11 @@ def _pallas_check(compiled: bool) -> dict:
 
 
 def _data_parallel_run(n_dev: int) -> dict:
-    """train_async under make_mesh(model_parallel=1) ({data: n}): node rows
-    and the pair batch must span the devices, a 1/n share each."""
+    """train_async on the mesh the program decides for itself (no mesh given:
+    `parallel.mesh.mesh_for_run`, `{data: n}`): node rows and the pair batch
+    must span the devices, a 1/n share each."""
     import numpy as np
 
-    from dragonfly2_tpu.parallel import mesh as meshlib
     from dragonfly2_tpu.trainer import synthetic, train_gnn
     from dragonfly2_tpu.trainer.metrics import TrainRunTelemetry
 
@@ -414,13 +414,13 @@ def _data_parallel_run(n_dev: int) -> dict:
     cluster = synthetic.make_cluster(num_nodes=1024, num_neighbors=16, num_pairs=65536, seed=SEED)
     tel = TrainRunTelemetry("gnn", batch_size=cfg.batch_size)
     _state, losses = asyncio.run(train_gnn.train_async(
-        cfg, cluster.graph, cluster.pairs, steps=20,
-        mesh=meshlib.make_mesh(model_parallel=1), telemetry=tel,
+        cfg, cluster.graph, cluster.pairs, steps=20, telemetry=tel,
     ))
     p = tel.placement
     graph, rows = p["graph"], p["batch_rows_per_device"]
     ok = (
         all(np.isfinite(losses))
+        and p["decision"] == {"rule": "rows_over_data", "devices": n_dev}
         and p["mesh"] == {"data": n_dev, "model": 1}
         and len(graph["per_device_bytes"]) == n_dev
         and all(b * n_dev == graph["bytes"] for b in graph["per_device_bytes"])

@@ -125,8 +125,6 @@ def _place_sharded(
     end, so its transpose is fixed here too (`_edges_by_destination`).
     Returns (state, state_sharding, g, g_sharding)."""
     dp = mesh.shape[meshlib.DATA_AXIS]
-    g = pad_graph(g, meshlib.pad_to_multiple(g.node_feats.shape[0], dp))
-    g = g._replace(by_dst=_edges_by_destination(state, g, mesh))
     param_sh = meshlib.infer_param_sharding(state.params, mesh)
     state_sh = train_state.TrainState(
         step=NamedSharding(mesh, P()),
@@ -137,11 +135,17 @@ def _place_sharded(
             lambda leaf: meshlib.param_leaf_sharding(leaf, mesh), state.opt_state
         ),
     )
-    state = jax.device_put(state, state_sh)
-    g_sh = TopoGraph(
-        *meshlib.graph_shardings(mesh), by_dst=jax.tree.map(lambda _: meshlib.replicated(mesh), g.by_dst)
-    )
-    g = jax.device_put(_as_jnp_graph(g), g_sh)
+    # the host's part of placement, timed: padding, the sorted table, and the
+    # copies onto the devices (a row shard each on a `data` mesh)
+    with default_tracer().span("trainer.gnn.place", data=dp, model=mesh.shape[meshlib.MODEL_AXIS]):
+        g = pad_graph(g, meshlib.pad_to_multiple(g.node_feats.shape[0], dp))
+        g = g._replace(by_dst=_edges_by_destination(state, g, mesh))
+        state = jax.device_put(state, state_sh)
+        g_sh = TopoGraph(
+            *meshlib.graph_shardings(mesh),
+            by_dst=jax.tree.map(lambda _: meshlib.replicated(mesh), g.by_dst),
+        )
+        g = jax.block_until_ready(jax.device_put(_as_jnp_graph(g), g_sh))
     return state, state_sh, g, g_sh
 
 
@@ -184,19 +188,15 @@ def shard_for_training(
 
 
 def pad_graph(g: TopoGraph, n_padded: int) -> TopoGraph:
-    """Pad node dim to n_padded with masked isolated nodes (static shapes)."""
-    n = g.node_feats.shape[0]
-    if n_padded == n:
+    """Pad node dim to n_padded (static shapes, whole row shards) with copies
+    of node 0. No neighbour slot and no pair names a padding row, so it moves
+    no loss and no gradient; a copy, and not a row of zeros, because a node
+    whose state is all zero has an all-zero embedding, and the embedding's L2
+    norm has no gradient there (0 x NaN in every parameter's sum)."""
+    pad = n_padded - g.node_feats.shape[0]
+    if pad == 0:
         return g
-    pad = n_padded - n
-    return TopoGraph(
-        np.concatenate([g.node_feats, np.zeros((pad, g.node_feats.shape[1]), np.float32)]),
-        np.concatenate([g.neighbors, np.zeros((pad, g.neighbors.shape[1]), np.int32)]),
-        np.concatenate([g.mask, np.zeros((pad, g.mask.shape[1]), np.float32)]),
-        np.concatenate(
-            [g.edge_feats, np.zeros((pad,) + g.edge_feats.shape[1:], np.float32)]
-        ),
-    )
+    return TopoGraph(*(np.concatenate([a, np.repeat(np.asarray(a[:1]), pad, axis=0)]) for a in g[:4]))
 
 
 def shard_for_training_scan(
@@ -278,11 +278,13 @@ def make_scan_step(
     )
 
 
-def _placement(mesh: Mesh, state: Any, g: TopoGraph, batch_size: int) -> dict:
-    """What the placed run occupies, for the run manifest: the Dense kernels
-    the tensor-parallel rule shards and the graph's node rows, both read
-    back from the placed arrays, the rows of one pair batch each device
-    is constrained to inside the step, and which VJP the gather takes."""
+def _placement(mesh: Mesh, decision: dict, state: Any, g: TopoGraph, batch_size: int) -> dict:
+    """What the placed run occupies, for the run manifest: the mesh and who
+    chose it (`parallel.mesh.mesh_for_run`'s record; `{"rule": "given"}` for
+    a caller's own), the Dense kernels the tensor-parallel rule shards and
+    the graph's node rows, both read back from the placed arrays, the rows of
+    one pair batch each device is constrained to inside the step, and which
+    VJP the gather takes."""
     kernels = [
         leaf for leaf in jax.tree.leaves(state.params)
         if leaf.ndim == 2 and meshlib.MODEL_AXIS in leaf.sharding.spec
@@ -298,6 +300,7 @@ def _placement(mesh: Mesh, state: Any, g: TopoGraph, batch_size: int) -> dict:
         }
     return {
         "mesh": {k: int(v) for k, v in mesh.shape.items()},
+        "decision": decision,
         "kernels": meshlib.placement_report(kernels),
         "graph": meshlib.placement_report(g._replace(by_dst=None)),
         "batch_rows_per_device": meshlib.batch_sharding(mesh).shard_shape((batch_size,))[0],
@@ -338,14 +341,20 @@ async def train_async(
     extra D2H sync: the per-call np.asarray pull already materializes them.
     It also gets every call's start and end once, at the run's end.
 
+    The mesh, when the caller gives none, is `parallel.mesh.mesh_for_run`'s
+    (every device on `data`); the run manifest's `placement.decision` says so.
+
     Spans (children of the caller's current span; to_thread copies the
     context): `trainer.gnn.setup` around init + placement + building the
-    jit, `trainer.gnn.call` around each call in the worker, and inside it
-    `trainer.gnn.dispatch` (key split + enqueue) and `trainer.gnn.pull` (the
+    jit, inside it `trainer.gnn.place` around the host's padding and the
+    copies onto the devices, `trainer.gnn.call` around each call in the
+    worker, and inside it `trainer.gnn.dispatch` (key split + enqueue) and `trainer.gnn.pull` (the
     D2H pulls). The loop's turn between two calls is the gap between two
     `trainer.gnn.call` spans.
     """
-    mesh = mesh or meshlib.make_mesh()
+    decision = {"rule": "given"}
+    if mesh is None:
+        mesh, decision = meshlib.mesh_for_run()
     steps_per_call = max(1, min(steps_per_call, steps))
     calls = -(-steps // steps_per_call)
     with_metrics = telemetry is not None
@@ -363,7 +372,7 @@ async def train_async(
 
     state, g, pool, multi_step = await asyncio.to_thread(_setup)
     if telemetry is not None:
-        telemetry.on_placed(_placement(mesh, state, g, cfg.batch_size))
+        telemetry.on_placed(_placement(mesh, decision, state, g, cfg.batch_size))
     key = jax.random.PRNGKey(seed)
 
     # each call's (start, end) in the worker, always on: two clock reads a
@@ -421,7 +430,7 @@ def train(
     log: Callable[[str], None] = lambda s: None,
 ) -> tuple[train_state.TrainState, list[float]]:
     """Full training driver; returns final state + loss history."""
-    mesh = mesh or meshlib.make_mesh()
+    mesh = mesh or meshlib.mesh_for_run()[0]
     state = init_state(cfg, graph, seed)
     state, g, step_fn = shard_for_training(state, graph, mesh, remat=cfg.remat)
     rng = np.random.default_rng(seed)

@@ -64,6 +64,7 @@ def test_a_training_run_leaves_the_span_tree(trained):
     run_gnn = "trainer.train_gnn < trainer.train_run"
     assert Counter(path(s) for s in spans if s["name"].startswith(("trainer.gnn", "trainer.export"))) == {
         f"trainer.gnn.setup < {run_gnn}": 1,
+        f"trainer.gnn.place < trainer.gnn.setup < {run_gnn}": 1,
         f"trainer.gnn.call < {run_gnn}": calls,
         f"trainer.gnn.dispatch < trainer.gnn.call < {run_gnn}": calls,
         f"trainer.gnn.pull < trainer.gnn.call < {run_gnn}": calls,
