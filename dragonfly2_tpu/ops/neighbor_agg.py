@@ -22,9 +22,13 @@ fixed for a whole run, so a run placed on one TPU chip sorts its slots by
 destination once, on the host, and `neighbor_gather`, a `custom_vjp` there,
 sums in the cheap direction: gather the cotangent rows into destination
 order, and add up contiguous runs in a kernel (ops.neighbor_agg_pallas:
-`edges_by_destination`, `sum_by_destination`). Everywhere else (no table: CPU,
-meshes of several devices, float32 or odd widths, inference, tools) it is
-`jnp.take` and its derived VJP, to the letter.
+`edges_by_destination`, `sum_by_destination`). The table counts its slots
+K-major (over `neighbors.T`): the TPU compiler keeps the message tensor, and
+so the cotangent, with K major-most, and blocks of that order are bitcasts of
+it where blocks of the row-major order cost a copy of the whole cotangent a
+layer. Everywhere else (no table: CPU, meshes of several devices, float32 or
+odd widths, inference, tools) it is `jnp.take` and its derived VJP, to the
+letter.
 """
 
 from __future__ import annotations

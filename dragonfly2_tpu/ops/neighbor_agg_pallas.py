@@ -142,7 +142,14 @@ def supports_pallas(h: jnp.ndarray) -> bool:
 # The slots are sorted inside equal blocks of the source rows, each of at most
 # BLOCK_BYTES: XLA gathers rows out of a table it can hold in VMEM four times
 # faster than out of one it cannot (3.5 against 14 ns a row on the v5e with
-# 32 MB and 64 MB blocks, PERF.md). The kernel then sums, per tile of TILE_DST
+# 32 MB and 64 MB blocks, PERF.md). The slots count K-major, slot k*N + n for
+# neighbors[n, k], because that is how the cotangent lies in memory: the TPU
+# compiler keeps the message tensor [N, K, H] with K major-most (the mean over
+# K is then a sum over whole [N, H] slabs), so a block, an equal contiguous
+# range of those slots, is a bitcast of what the backward pass wrote (with K
+# blocks, block k is g[:, k, :]). Blocks of the row-major order cost a layout
+# copy of the whole cotangent a layer (PERF.md). The kernel never learns what
+# a slot number means. It sums, per tile of TILE_DST
 # destination rows, each block's run of rows that point into the tile, in
 # windows of WINDOW rows that start on a multiple of ALIGN (a DMA out of a
 # tiled array starts on a whole tile). Its time goes by the window, and the
@@ -155,15 +162,17 @@ TILE_DST = 256
 WINDOW = 384
 ALIGN = 16
 FIRST, LAST = 1, 2  # flags of a window: it opens / closes its tile
+SLOT_ORDER = "k_major"  # the run manifest's word for the table's format
 
 
 class EdgesByDst(NamedTuple):
     """The neighbor table's transpose, as the kernel walks it.
 
     perm:  [B, N*K/B] int32: for each of B equal blocks of the slots
-           (row-major over [N, K]), the block's slot numbers, counted from the
-           block's first, sorted by the row they point at (stable); padded
-           slots are slots like any other
+           (K-major: row-major over neighbors.T, [K, N], the order the
+           compiler keeps the cotangent's rows in), the block's slot numbers,
+           counted from the block's first, sorted by the row they point at
+           (stable); padded slots are slots like any other
     items: [4, W] int32, the work list by tile: tile of destination rows,
            source block, first row of the window in the block's sorted rows,
            flags. W depends on N, K and B alone; `live` of them count
@@ -198,17 +207,18 @@ def _source_blocks(slots: int, row_bytes: int) -> int:
 def edges_by_destination(neighbors: np.ndarray, width: int, dtype) -> EdgesByDst | None:
     """Sort a neighbor table's slots by destination for cotangent rows
     [N*K, width] of `dtype`: numpy, on the host, once per placed run (~0.1 s
-    for a million slots). None where the kernel does not apply (`kernel_sums`,
-    N a multiple of TILE_DST) or does not pay (MAX_BLOCKS): the gather then
-    keeps `jnp.take`'s VJP. Every shape depends on N, K and the rows' bytes
-    alone: a hub is a longer run of windows for its tile, a tile nobody points
-    at one window that adds nothing."""
+    for a million slots), inside equal blocks of the K-major slot order (the
+    text above BLOCK_BYTES). None where the kernel does not apply
+    (`kernel_sums`, N a multiple of TILE_DST) or does not pay (MAX_BLOCKS): the
+    gather then keeps `jnp.take`'s VJP. Every shape depends on N, K and the
+    rows' bytes alone: a hub is a longer run of windows for its tile, a tile
+    nobody points at one window that adds nothing."""
     n, k = neighbors.shape
     blocks = _source_blocks(n * k, width * 2) if kernel_sums(width, dtype) and n % TILE_DST == 0 else 0
     if not blocks:
         return None
     per_block = n * k // blocks
-    flat = np.asarray(neighbors, np.int32).reshape(blocks, per_block)
+    flat = np.asarray(neighbors, np.int32).T.reshape(blocks, per_block)  # slots in K-major order
     perm = np.argsort(flat, axis=1, kind="stable").astype(np.int32)
     dst = np.take_along_axis(flat, perm, axis=1)
     tiles = n // TILE_DST
@@ -282,13 +292,15 @@ def _segment_sum_kernel(tile_ref, block_ref, start_ref, flags_ref, local_ref, *r
 def sum_by_destination(by_dst: EdgesByDst, g: jnp.ndarray) -> jnp.ndarray:
     """Cotangent [N, K, H] -> [N, H]: what a scatter-add by the neighbor table
     gives, with float32 accumulation (`kernel_sums(H, g.dtype)`). Gathers, the
-    cheap direction, block by block (perm permutes a block's slots), then one
-    grid step per live window, by tile: a tile's output block stays in VMEM
-    from its first window to its last."""
+    cheap direction, block by block (perm permutes a block's slots; the blocks
+    are ranges of the K-major rows, [K, N, H], which on the TPU is the
+    cotangent as it lies), then one grid step per live window, by tile: a
+    tile's output block stays in VMEM from its first window to its last."""
     n, _, width = g.shape
+    blocks = jnp.swapaxes(g, 0, 1).reshape(by_dst.perm.shape[0], -1, width)
     rows = [
         part.at[perm].get(unique_indices=True, mode="promise_in_bounds")
-        for part, perm in zip(g.reshape(by_dst.perm.shape[0], -1, width), by_dst.perm)
+        for part, perm in zip(blocks, by_dst.perm)
     ]
     tile, block, start, flags = by_dst.items
     return pl.pallas_call(

@@ -292,10 +292,12 @@ def _placement(mesh: Mesh, decision: dict, state: Any, g: TopoGraph, batch_size:
     batch_size = meshlib.pad_to_multiple(batch_size, mesh.shape[meshlib.DATA_AXIS])
     gather_vjp = {"path": "derived"}
     if g.by_dst is not None:  # built for this state's model alone: the kernel runs
+        from dragonfly2_tpu.ops.neighbor_agg_pallas import SLOT_ORDER
+
         blocks, per_block = g.by_dst.perm.shape
         width, dtype = _gathered_states(state)
         gather_vjp = {
-            "path": "sorted_kernel", "blocks": blocks,
+            "path": "sorted_kernel", "slot_order": SLOT_ORDER, "blocks": blocks,
             "block_bytes": per_block * width * jnp.dtype(dtype).itemsize,
         }
     return {

@@ -3,7 +3,8 @@
 in plain numpy, the segmented-sum kernel interpreted on the CPU, the
 `custom_vjp` in a training step, the run manifest's word on it.
 
-SIX tests, most of them loops over cases, in a file of their own, on purpose.
+SEVEN tier-1 tests, most of them loops over cases, in a file of their own, on
+purpose (an eighth is marked `slow`: it loads the TPU's compiler).
 Tier-1 hands files to its workers by their number of tests, largest first, two
 at a time (xdist's loadfile), so a file's count decides when it runs and what
 every file after it runs beside. The scheduler plane's host-timing assertions
@@ -13,10 +14,11 @@ they run early, before the JAX-heavy files. As parametrised cases these tests
 made a large file: it started with the run, took a worker for 20 s and pushed
 those assertions into the JAX-heavy middle, where 6 of 13 whole runs failed
 one (ROADMAP D10); a light file of 42 cases alone still moved
-test_bench_contract.py behind an 11 s file. With six tests this file ranks
+test_bench_contract.py behind an 11 s file. With seven tests this file ranks
 after every timing-sensitive one, runs near the end, and leaves the order of
 all files before it as the parent has it. A failure names its case."""
 
+import os
 import re
 import sys
 from pathlib import Path
@@ -24,6 +26,7 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.experimental.pallas import tpu as pltpu
 
 from dragonfly2_tpu.models.features import FEATURE_DIM
@@ -52,6 +55,17 @@ def _hub_table(n, k, seed=0):
 
 
 
+def _run_inputs(nbr, cfg):
+    """(graph over the neighbor table, pair pool of one batch) for a training run."""
+    n, k = nbr.shape
+    b = cfg.batch_size
+    graph = TopoGraph(np.zeros((n, 12), np.float32), nbr, np.ones((n, k), np.float32),
+                      np.zeros((n, k, 4), np.float32))
+    pairs = PairBatch(np.zeros(b, np.int32), np.zeros(b, np.int32),
+                      np.zeros((b, FEATURE_DIM), np.float32), np.zeros(b, np.float32))
+    return graph, pairs
+
+
 def _table_in_blocks(monkeypatch, nbr, width, dtype, blocks):
     """The table placement would build, its slots in `blocks` source blocks
     (as if the cotangent were `blocks` times what XLA's gather holds)."""
@@ -69,9 +83,10 @@ def _table(kind, n, k):
 
 def test_table_sorts_every_slot_once(monkeypatch):
     """The host's part, plain numpy: whatever the graph, the table is a
-    permutation of each block's slots in destination order, and the kernel's
-    windows count every slot exactly once, in its tile. Where the shapes do
-    not tile there is no table."""
+    permutation of each block's slots in destination order, the slots counted
+    K-major (slot k*N + n is neighbors[n, k]), and the kernel's windows count
+    every slot exactly once, in its tile. Where the shapes do not tile there
+    is no table."""
     for n, k in [(512, 16), (256, 24), (1024, 6), (100, 7), (96, 5)]:
         for kind in ["hub", "one_row", "uniform"]:
             for blocks in [1, 4]:
@@ -84,8 +99,10 @@ def test_table_sorts_every_slot_once(monkeypatch):
                 assert t.perm.shape == (blocks, n * k // blocks), case
                 per_block = t.perm.shape[1]
                 assert (np.sort(t.perm, axis=1) == np.arange(per_block)).all(), case
-                dst = np.take_along_axis(nbr.reshape(blocks, -1), t.perm, axis=1)
+                dst = np.take_along_axis(nbr.T.reshape(blocks, -1), t.perm, axis=1)
                 assert (np.diff(dst, axis=1) >= 0).all(), case
+                slot_k, slot_n = np.divmod(t.perm + np.arange(blocks)[:, None] * per_block, n)
+                assert (nbr[slot_n, slot_k] == dst).all(), case
                 live = t.live[0]
                 tile, block, start, flags = t.items[:, :live]
                 # by tile, every tile written
@@ -142,11 +159,39 @@ def test_bare_gather_lowers_to_todays_hlo():
     assert ours == takes and "scatter" in ours
 
 
+def test_a_step_without_a_table_is_jnp_takes_on_one_device_and_on_four(monkeypatch):
+    """The table's format is the kernel's own business: where placement builds
+    none (the CPU here; a `data` mesh of four devices, as the four-chip cell's)
+    the served scan step lowers to the text it has with `jnp.take` in the
+    gather's place, letter for letter."""
+    from dragonfly2_tpu.models import graphsage
+
+    cfg = train_gnn.GNNTrainConfig(hidden=128, embed_dim=16, num_layers=2, batch_size=64)
+    graph, pairs = _run_inputs(_hub_table(256, 16), cfg)
+    unplaced = train_gnn.init_state(cfg, graph, 0)
+
+    def lowered(n_devices):
+        mesh, _ = meshlib.mesh_for_run(jax.devices()[:n_devices])
+        state, g, pool, step = train_gnn.shard_for_training_scan(
+            unplaced, graph, pairs, mesh, batch_size=64, steps_per_call=2)
+        assert g.by_dst is None
+        return step.lower(state, g, pool, jax.random.PRNGKey(0)).as_text()
+
+    for n_devices in (1, 4):
+        ours = lowered(n_devices)
+        with monkeypatch.context() as m:
+            m.setattr(graphsage, "neighbor_gather", lambda h, nbr, by_dst=None: jnp.take(h, nbr, axis=0))
+            takes = lowered(n_devices)
+        assert ours == takes and "scatter" in ours, n_devices
+
+
 # (n, k, width, dtype, source blocks): small, the interpreter takes 50 ms a window
 KERNEL_CASES = [
     (512, 12, 128, jnp.bfloat16, 1),  # two tiles, the hub's run of windows longer
     (512, 12, 128, jnp.bfloat16, 4),  # a window for every block and tile
     (256, 24, 256, jnp.bfloat16, 2),
+    (1024, 2, 128, jnp.bfloat16, 4),  # twice as many blocks as K: half a slice g[:, k, :] a block
+    (512, 12, 128, jnp.bfloat16, 8),  # fewer blocks than K, and not whole slices: one and a half a block
 ]
 # where no table is built: the VJP is jnp.take's
 DERIVED_CASES = [
@@ -210,10 +255,7 @@ def test_a_placed_step_with_the_table(monkeypatch):
     `scope.gather_bwd_ms` reads them, and no scatter is left under `gather`."""
     n, k = 256, 16
     cfg = train_gnn.GNNTrainConfig(hidden=128, embed_dim=16, num_layers=2, batch_size=64)
-    graph = TopoGraph(np.zeros((n, 12), np.float32), _hub_table(n, k), np.ones((n, k), np.float32),
-                      np.zeros((n, k, 4), np.float32))
-    pairs = PairBatch(np.zeros(64, np.int32), np.zeros(64, np.int32),
-                      np.zeros((64, FEATURE_DIM), np.float32), np.zeros(64, np.float32))
+    graph, pairs = _run_inputs(_hub_table(n, k), cfg)
     mesh = meshlib.make_mesh()
     unplaced = train_gnn.init_state(cfg, graph, 0)
     state, g, _, _ = train_gnn.shard_for_training_scan(
@@ -225,7 +267,8 @@ def test_a_placed_step_with_the_table(monkeypatch):
     table = _table_in_blocks(monkeypatch, graph.neighbors, 128, jnp.bfloat16, 2)
     placement = train_gnn._placement(mesh, {"rule": "given"}, state, g._replace(by_dst=table), 64)
     assert placement["gather_vjp"] == {
-        "path": "sorted_kernel", "blocks": 2, "block_bytes": n * k // 2 * 128 * 2, **counts}
+        "path": "sorted_kernel", "slot_order": "k_major", "blocks": 2, "block_bytes": n * k // 2 * 128 * 2,
+        **counts}
     assert placement["graph"]["leaves"] == 4
 
     with pltpu.force_tpu_interpret_mode():  # (unplaced: the interpreter's callbacks take no mesh)
@@ -238,3 +281,39 @@ def test_a_placed_step_with_the_table(monkeypatch):
     assert any("sum_by_destination" in name and "while" in name for name in backward), sorted(gather)  # the sum
     assert any(name.endswith("/gather") for name in gather - backward), sorted(gather)  # the forward
     assert not any("scatter" in name for name in gather), sorted(gather)
+
+
+@pytest.mark.slow  # loads the TPU's compiler: alone in its process, never under tier-1's workers
+def test_no_layout_copy_of_the_cotangent_on_a_described_v5e():
+    """`gnn-32k-512`'s placed step (32,768 x 16 x 512, the table one TPU chip
+    gets), compiled for a described v5e with no chip attached (30-45 s). The
+    compiler keeps the message tensor K-major, `bf16[N,16,H]{2,0,1}`; blocks of
+    the row-major slot order made it turn the cotangent with a `copy` of the
+    whole `[N, K, H]` a layer (`message/add_any`, 1.7 ms each on the chip).
+    With K-major blocks the entry computation has none."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds its lock
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    chip = SingleDeviceSharding(topo.devices[0])
+    n, k, hidden = 32768, 16, 512
+    cfg = train_gnn.GNNTrainConfig(hidden=hidden, embed_dim=hidden // 2, batch_size=2048)
+    state = train_gnn.init_state(cfg, _run_inputs(_table("uniform", 8, k), cfg)[0], 0)  # weights do not depend on N
+    graph, pairs = _run_inputs(_table("uniform", n, k), cfg)
+    graph = graph._replace(by_dst=pk.edges_by_destination(graph.neighbors, hidden, jnp.bfloat16))
+    assert graph.by_dst.perm.shape == (16, n)  # a block is a K slice, g[:, k, :]
+    shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.result_type(x), sharding=chip),
+                          (state, graph, pairs))
+    compiled = jax.jit(train_gnn.make_train_step()).lower(*shapes).compile()
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY "):]
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call", entry)) == 3, "a segmented sum a layer"
+    written = re.findall(rf"^\s*\S+ = bf16\[{n},{k},{hidden}\]\{{([\d,]+)\S* fusion\(", entry, re.M)
+    assert written and set(written) == {"2,0,1"}, set(written)  # K major-most wherever a fusion writes it
+    copies = re.findall(rf"^\s*(\S+ = bf16\[{n},{k},{hidden}\]\S* copy\(.*?op_name=\S+)", entry, re.M)
+    assert not copies, copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30  # 2.82 GB; 2.38 with the copies
