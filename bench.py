@@ -1,4 +1,6 @@
-"""Headline benchmark: scheduler parent-scoring throughput + GNN training rate.
+"""Host microbenchmarks around one headline: scheduler parent-scoring throughput.
+(The training step is measured by `benchmarks/run.py`; PERF.md and the ledger
+hold its numbers.)
 
 Runs on the accelerator JAX finds and names it in the result (`platform`,
 `device_kind`, `device_count`). Prints exactly ONE JSON line:
@@ -8,8 +10,9 @@ Runs on the accelerator JAX finds and names it in the result (`platform`,
   vs_baseline  against the 10k calls/s north-star target (BASELINE.md; the
                reference's intended path was a TF-Serving RPC per round and
                was never implemented)
-  extra        GNN train steps/sec on the 1k-node synthetic topology
-               (north-star config 2) and scoring p50 latency.
+  extra        scoring p50 latency, the MLP's training rate on the host, and
+               the host sections (control plane, observability, round loop,
+               federation, ...).
 
 This file is both supervisor and worker. The supervisor (default entry)
 never imports jax — the chip belongs to one process at a time — and probes
@@ -67,31 +70,9 @@ print("PROBE_OK", d[0].platform, flush=True)
 """
 
 
-# Published per-chip peaks, keyed by the device_kind JAX reports. A device
-# that is not in this table is an error, not a default: add its peaks here
-# with their source before reading a utilization off it.
-CHIP_PEAKS = {
-    "TPU v5 lite": {
-        "bf16_tflops": 197.0,
-        "hbm_gbps": 819.0,
-        "source": "Google Cloud documentation, 'TPU v5e' (per-chip peak compute and HBM bandwidth)",
-    },
-}
-
 # sections whose failure means the device path is broken: any of them
 # raising makes the worker (and so the supervisor) exit non-zero
-DEVICE_SECTIONS = ("jax_scoring", "gnn_train", "gnn_train_scaled", "mlp_train")
-
-
-def chip_peaks(device_kind: str) -> dict:
-    try:
-        return CHIP_PEAKS[device_kind]
-    except KeyError:
-        raise KeyError(
-            f"no published peaks for device_kind {device_kind!r}: add them to "
-            "bench.CHIP_PEAKS with their source (no utilization is computed "
-            "against assumed peaks)"
-        ) from None
+DEVICE_SECTIONS = ("jax_scoring", "mlp_train")
 
 
 class _SectionTimeout(Exception):
@@ -303,169 +284,6 @@ def bench_native_scoring(
         multi_call_p50 = float(np.percentile(mlat, 50) * 1000)
         scorer.close()
     return multi_rps, single_p50, single_rps, multi_call_p50
-
-
-def _gnn_train_measured(
-    *,
-    num_nodes: int,
-    hidden: int,
-    batch_size: int,
-    calls: int,
-    steps_per_call: int,
-    measure_convergence: bool = False,
-) -> tuple[float, float, float, float, int]:
-    """One GNN training measurement at the given shapes on the live backend.
-    Returns (best-window steps/s, median-window steps/s, FLOPs/step,
-    bytes-accessed/step — both from XLA's compiled cost analysis,
-    measured-steps-to-convergence or 0).
-
-    Convergence is MEASURED, not assumed (VERDICT r4 weak #3): training runs
-    from a fresh state until a 10-step loss window falls below half the first
-    window's mean — the criterion the sharded-convergence test pins
-    (tests/test_distributed.py::test_sharded_convergence_1k_nodes) — and the
-    crossing step is returned.
-
-    Uses the device-resident scan path (shard_for_training_scan): minibatch
-    sampling with the JAX PRNG inside a lax.scan of `steps_per_call` steps,
-    so host dispatch is amortized instead of dominating a model this size."""
-    from dragonfly2_tpu.parallel import mesh as meshlib
-    from dragonfly2_tpu.trainer import synthetic, train_gnn
-
-    import jax
-
-    cluster = synthetic.make_cluster(
-        num_nodes=num_nodes, num_neighbors=16, num_pairs=65536, seed=7
-    )
-    cfg = train_gnn.GNNTrainConfig(hidden=hidden, batch_size=batch_size)
-    mesh = meshlib.make_mesh()
-    state = train_gnn.init_state(cfg, cluster.graph, rng_seed=7)
-    state, g, pool, multi_step = train_gnn.shard_for_training_scan(
-        state, cluster.graph, cluster.pairs, mesh,
-        batch_size=cfg.batch_size, steps_per_call=steps_per_call,
-    )
-    key = jax.random.PRNGKey(7)
-
-    # FLOPs and bytes per step from the compiler, not hand-counting. Lower a
-    # ONE-step scan for the accounting: XLA's cost analysis counts a
-    # while-loop body once regardless of trip count, so analyzing the K-step
-    # call and dividing would undercount by K.
-    flops_per_step = 0.0
-    bytes_per_step = 0.0
-    try:
-        # 1-step variant sharing the ALREADY-placed arrays (shardings
-        # recovered from them): lowering only inspects, never executes or
-        # donates, so no duplicate model init or device allocation
-        one_step = train_gnn.make_scan_step(
-            mesh,
-            jax.tree.map(lambda x: x.sharding, state),
-            jax.tree.map(lambda x: x.sharding, g),
-            jax.tree.map(lambda x: x.sharding, pool),
-            batch_size=cfg.batch_size,
-            steps_per_call=1,
-        )
-        ca = one_step.lower(state, g, pool, key).compile().cost_analysis()
-        flops_per_step = float(ca.get("flops", 0.0))
-        bytes_per_step = float(ca.get("bytes accessed", 0.0))
-    except Exception as e:  # cost analysis is best-effort across backends
-        print(f"bench: cost_analysis unavailable: {e}", file=sys.stderr, flush=True)
-
-    conv_steps = -1  # -1 = not measured; 0 = measured but never crossed
-    if measure_convergence:
-        # fresh state: the compile/warmup calls below would otherwise have
-        # already trained past the interesting region. Wall-clock capped: in
-        # a forced-CPU run 3000 steps can run to ~1h and would blow the whole
-        # section budget (observed) — a time-out leaves conv "not measured",
-        # which is distinct from "measured and never crossed" (0).
-        first_window = None
-        max_steps = 3000
-        budget_s = 120.0
-        t_start = time.perf_counter()
-        done = 0
-        conv_steps = 0
-        while done < max_steps:
-            if time.perf_counter() - t_start > budget_s:
-                conv_steps = -1
-                print(
-                    f"bench: convergence measurement timed out at step {done} "
-                    f"({budget_s:.0f}s budget) — backend too slow, not a "
-                    "convergence regression",
-                    file=sys.stderr, flush=True,
-                )
-                break
-            key, sub = jax.random.split(key)
-            state, losses = multi_step(state, g, pool, sub)
-            window = float(np.mean(np.asarray(losses)))
-            done += steps_per_call
-            if first_window is None:
-                first_window = window
-            elif window < 0.5 * first_window:
-                conv_steps = done
-                break
-
-    key, sub = jax.random.split(key)
-    state, losses = multi_step(state, g, pool, sub)  # compile (no-op if warm)
-    float(np.asarray(losses)[-1])
-    # Four sustained windows (each `calls*steps_per_call` steps); the best
-    # and the MEDIAN window are both returned, so one slow window does not
-    # set the figure and a regression (slow in most windows) stays visible.
-    #
-    # Each window ends by PULLING the final step's loss to the host: the
-    # losses are the data the trainer needs from a call anyway
-    # (train_gnn.train_async pulls them per call), and the last one chains
-    # through every optimizer step of every call in the window, so its D2H
-    # materialization is also the window's sync. (dflint DF013 accepts
-    # exactly this np.asarray pull — keep it inside the timed region.)
-    rates = []
-    for _ in range(4):
-        t0 = time.perf_counter()
-        for _ in range(calls):
-            key, sub = jax.random.split(key)
-            state, losses = multi_step(state, g, pool, sub)
-        float(np.asarray(losses)[-1])
-        rates.append(calls * steps_per_call / (time.perf_counter() - t0))
-    return (
-        float(np.max(rates)),
-        float(np.median(rates)),
-        flops_per_step,
-        bytes_per_step,
-        conv_steps,
-    )
-
-
-def bench_gnn_train(calls: int | None = None, steps_per_call: int = 10) -> tuple[float, float, float, float, int]:
-    """North-star config 2 shape: the 1k-node synthetic topology, with the
-    measured steps-to-convergence. Timing-window size is platform-aware: a
-    forced-CPU run makes ~1 step/s, where TPU-sized windows (4 windows of 10
-    calls x 10 steps) alone would blow the 420 s section budget."""
-    import jax
-
-    if calls is None:
-        calls = 2 if jax.devices()[0].platform == "cpu" else 10
-    return _gnn_train_measured(
-        num_nodes=1024, hidden=256, batch_size=4096,
-        calls=calls, steps_per_call=steps_per_call, measure_convergence=True,
-    )
-
-
-def bench_gnn_train_scaled(calls: int = 3, steps_per_call: int = 10) -> tuple[float, float, float, float, int]:
-    """North-star config 3 scale: a full-cluster-sized topology (16k hosts,
-    wider layers, bigger batch). The config-2 model is so small that a step
-    is latency-bound (8 GFLOP at the v5e's 197 TFLOP/s peak is ~40 µs of
-    ideal compute — overhead dominates any such kernel); this section shows
-    what the SAME training path achieves when the GEMMs are big enough to
-    feed the MXU, i.e. that the framework, not the implementation, sets the
-    config-2 number."""
-    import jax
-
-    if jax.devices()[0].platform == "cpu":
-        # ~0.4 TFLOP/step exists to exercise the MXU; in a forced-CPU run it
-        # would only burn the section budget
-        print("bench: gnn_train_scaled skipped on cpu", file=sys.stderr, flush=True)
-        return None, None, None, None, None
-    return _gnn_train_measured(
-        num_nodes=16384, hidden=512, batch_size=16384,
-        calls=calls, steps_per_call=steps_per_call,
-    )
 
 
 def bench_mlp_train(steps: int = 200) -> tuple[float, float]:
@@ -2967,10 +2785,6 @@ def main() -> None:
         jaxenv.pin_host_cpu()
     jaxenv.enable_compile_cache()
     device = jaxenv.device_report()
-    platform = device["platform"]
-    # utilization is only ever computed against the published peaks of the
-    # chip that ran: an accelerator this table does not know stops the run
-    peaks = chip_peaks(device["device_kind"]) if platform != "cpu" else None
     errors: dict[str, str] = {}
 
     def run_section(name: str, fn, default):
@@ -3000,12 +2814,6 @@ def main() -> None:
         native_single_rps,
         native_multi_call_p50_ms,
     ) = run_section("native_scoring", bench_native_scoring, (None, None, None, None))
-    steps_per_sec, steps_median, flops_per_step, bytes_per_step, conv_steps = run_section(
-        "gnn_train", bench_gnn_train, (None, None, None, None, None)
-    )
-    scaled_sps, scaled_median, scaled_flops, scaled_bytes, _ = run_section(
-        "gnn_train_scaled", bench_gnn_train_scaled, (None, None, None, None, None)
-    )
     fanout_mbps, disk_mbps = run_section("checkpoint_fanout", bench_checkpoint_fanout, (None, None))
     piece_pipeline = run_section("piece_pipeline", bench_piece_pipeline, {})
     dataset_build = run_section("dataset_build", bench_dataset_build, {})
@@ -3024,12 +2832,7 @@ def main() -> None:
     # (the headline `value` stays numeric — the driver parses it — but the
     # per-section keys below are null when their section skipped)
     calls_per_sec = max(jax_calls_per_sec or 0.0, native_calls_per_sec or 0.0)
-    skipped = sorted(
-        name for name, probe in (
-            ("native_scoring", native_calls_per_sec),
-            ("gnn_train_scaled", scaled_median),
-        ) if probe is None and name not in errors
-    )
+    skipped = ["native_scoring"] if native_calls_per_sec is None and "native_scoring" not in errors else []
     extra = {
         "native_scoring_calls_per_sec": _r(native_calls_per_sec, 1),
         "native_scoring_p50_ms": _r(native_p50_ms, 4),
@@ -3039,14 +2842,6 @@ def main() -> None:
         "jax_scoring_calls_per_sec": _r(jax_calls_per_sec, 1),
         "jax_scoring_p50_ms": _r(jax_p50_ms, 3),
         "jax_scoring_multi_calls_per_sec": _r(jax_multi_rps, 1),
-        # headline pinned to the MEDIAN window (ADVICE r05 #3: r05 silently
-        # switched this key to best-of-window, making round-over-round diffs
-        # apples-to-oranges; the best window — the machine's stall-free
-        # capability — now lives under its own explicit key)
-        "gnn_train_steps_per_sec": _r(steps_median, 2),
-        "gnn_train_steps_per_sec_best_window": _r(steps_per_sec, 2),
-        "gnn_train_steps_per_sec_median_window": _r(steps_median, 2),
-        "gnn_timing_method": "median_of_4_windows",
         # north-star config 1: MLP bandwidth predictor on the scheduler host
         # CPU (its own deployment hardware)
         "mlp_train_steps_per_sec_cpu": _r(mlp_sps, 2),
@@ -3140,65 +2935,6 @@ def main() -> None:
         **device,
         **serving,
     }
-    # Utilization accounting (VERDICT r3 #10, r4 weak #1): FLOPs and bytes
-    # per step from XLA cost analysis → achieved TFLOP/s and, against the
-    # published peaks of the chip that ran (CHIP_PEAKS), MFU, HBM bandwidth
-    # utilization and the ROOFLINE ceiling — arithmetic intensity against
-    # the chip's ridge point says what MFU the memory system permits at
-    # these shapes, independent of implementation. On a forced-CPU run there
-    # are no peaks and none of the peak-relative keys is written.
-    def utilization(prefix: str, sps, flops, nbytes) -> None:
-        if not flops or not sps:  # skipped (None) or measured-zero: no keys
-            return
-        achieved_tflops = flops * sps / 1e12
-        extra[f"{prefix}_flops_per_step"] = round(flops)
-        extra[f"{prefix}_achieved_tflops_per_sec"] = round(achieved_tflops, 4)
-        if nbytes > 0:
-            extra[f"{prefix}_bytes_per_step"] = round(nbytes)
-            extra[f"{prefix}_arithmetic_intensity_flop_per_byte"] = round(flops / nbytes, 2)
-        if peaks is None:
-            return
-        extra[f"{prefix}_mfu"] = round(achieved_tflops / peaks["bf16_tflops"], 4)
-        if nbytes > 0:
-            ridge = peaks["bf16_tflops"] * 1e12 / (peaks["hbm_gbps"] * 1e9)
-            extra[f"{prefix}_roofline_max_mfu"] = round(min(1.0, flops / nbytes / ridge), 4)
-            extra[f"{prefix}_hbm_bw_util"] = round(
-                nbytes * sps / (peaks["hbm_gbps"] * 1e9), 4
-            )
-
-    utilization("gnn", steps_per_sec, flops_per_step, bytes_per_step)
-    # same median-headline discipline as the config-2 number (ADVICE r05 #3);
-    # null (not 0.0) when the scaled section skipped on the cpu backend
-    extra["gnn_train_scaled_steps_per_sec"] = _r(scaled_median, 2)
-    extra["gnn_train_scaled_steps_per_sec_best_window"] = _r(scaled_sps, 2)
-    extra["gnn_train_scaled_steps_per_sec_median_window"] = _r(scaled_median, 2)
-    utilization("gnn_scaled", scaled_sps, scaled_flops, scaled_bytes)
-    if peaks is not None:
-        extra["chip_peaks"] = peaks
-    if steps_per_sec and conv_steps is not None and conv_steps >= 0:
-        # MEASURED steps to the halved-loss-window criterion on the config-2
-        # synthetic (same criterion the sharded-convergence test pins); the
-        # v5e-16 number extrapolates the measured single-chip time with
-        # linear dp scaling, which the 16-device test path exercises.
-        # conv_steps == 0 means the measurement RAN and the loss never
-        # crossed within the cap — a convergence regression, distinct from
-        # the section not having run at all.
-        extra["measured_convergence_steps"] = conv_steps
-        if conv_steps > 0:
-            # seconds on the chip: written only when a chip ran (the step
-            # count above is a count and stands on any platform)
-            if peaks is not None:
-                extra["measured_convergence_s_single_chip"] = round(
-                    conv_steps / steps_per_sec, 2
-                )
-                extra["est_convergence_s_v5e16_linear_dp"] = round(
-                    conv_steps / steps_per_sec / 16, 2
-                )
-        else:
-            extra["measured_convergence_note"] = (
-                "loss window did not halve within 3000 steps — convergence "
-                "regression"
-            )
     if skipped:
         extra["skipped"] = skipped
     if errors:

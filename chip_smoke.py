@@ -21,11 +21,14 @@ the entry points a user would call, at the widths the repo ships as default:
             a seed is staged unsharded and under a NamedSharding over all
             local devices, pulled back and compared bit for bit. On more than
             one device it also drives train_async under {data: n} so node
-            rows and the pair batch are seen to span them.
+            rows and the pair batch are seen to span them. And the kernel the
+            training step runs, the gather's VJP (`sum_by_destination`),
+            against `jnp.take`'s at two shapes.
   platform  the trainer process AND the device child must both report
-            platform "tpu" with the same device kind and count, and every
-            Pallas shape must have been compiled (interpret=False). A child
-            that fell back to the CPU fails the smoke here.
+            platform "tpu" with the same device kind and count, and Mosaic
+            must have compiled the kernel at every shape, not the
+            interpreter. A child that fell back to the CPU fails the smoke
+            here.
 
 This parent never imports jax: a parent that has touched JAX holds the chip
 and a child that needs it then fails or hangs. The summary (mesh, each
@@ -356,47 +359,43 @@ def _child_device(tmp: str, stage_mib: int) -> dict:
     return {"ok": ok, **out}
 
 
+# (rows, width, hub): K = 16 and bfloat16, as the step's. The first is one
+# source block; the second two, with a row a quarter of all slots point at
+KERNEL_SHAPES = ((1024, 256, False), (4096, 512, True))
+
+
 def _pallas_check(compiled: bool) -> dict:
-    """neighbor_aggregate_pallas against the XLA path, forward and VJP, at
-    1,024x256 and at the largest shape supports_pallas admits (K=16). On the
-    chip the kernel is compiled by Mosaic (interpret=False); anywhere else
-    only the interpreter exists, and supports_pallas admits nothing, so the
-    second shape collapses to one tile."""
+    """`sum_by_destination`, the kernel the training step runs (the gather's
+    VJP on one chip), over `edges_by_destination` of a seeded table, against
+    `jnp.take`'s own VJP in float32, at KERNEL_SHAPES. On the chip Mosaic
+    compiles the kernel; anywhere else only the interpreter exists."""
+    import contextlib
+
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from jax.experimental.pallas import tpu as pltpu
 
     from dragonfly2_tpu.ops import neighbor_agg_pallas as pk
-    from dragonfly2_tpu.ops.neighbor_agg import masked_mean, neighbor_gather
 
-    n_budget = pk.TILE_N
-    while pk.supports_pallas(jax.ShapeDtypeStruct((n_budget + pk.TILE_N, 256), jnp.float32)):
-        n_budget += pk.TILE_N
-    # f32 states through the MXU at default precision are rounded to bf16
-    # (2^-9 relative) before the A@h product; means of |h| <~ 4.5 then differ
-    # from the XLA gather path by < 1e-2. A wrong gather or mask is O(1).
-    atol = 2e-2
     out = {}
-    for n in (1024, n_budget):
+    for n, width, hub in KERNEL_SHAPES:
         rng = np.random.default_rng(n)
-        h = jnp.asarray(rng.standard_normal((n, 256)), jnp.float32)
-        nbr = jnp.asarray(rng.integers(0, n, (n, 16)), jnp.int32)
-        mask = jnp.asarray(rng.random((n, 16)) < 0.8, jnp.float32)
-
-        def pallas(a):
-            return pk.neighbor_aggregate_pallas(a, nbr, mask, interpret=not compiled)
-
-        def xla(a):
-            return masked_mean(neighbor_gather(a, nbr), mask)
-
-        fwd = float(jnp.max(jnp.abs(jax.jit(pallas)(h) - jax.jit(xla)(h))))
-        vjp = float(jnp.max(jnp.abs(
-            jax.jit(jax.grad(lambda a: jnp.sum(pallas(a) ** 2)))(h)
-            - jax.jit(jax.grad(lambda a: jnp.sum(xla(a) ** 2)))(h)
-        )))
-        out[f"{n}x256"] = {
-            "ok": fwd <= atol and vjp <= atol, "compiled": compiled,
-            "fwd_max_err": fwd, "vjp_max_err": vjp,
+        nbr = rng.integers(0, n, (n, 16)).astype(np.int32)
+        if hub:
+            nbr[rng.random(nbr.shape) < 0.25] = 3
+        g = jnp.asarray(rng.standard_normal((n, 16, width)), jnp.bfloat16)
+        table = pk.edges_by_destination(nbr, width, g.dtype)
+        with contextlib.nullcontext() if compiled else pltpu.force_tpu_interpret_mode():
+            got = pk.sum_by_destination(jax.tree.map(jnp.asarray, table), g)
+        _, vjp = jax.vjp(lambda h: jnp.take(h, nbr, axis=0), jnp.zeros((n, width), jnp.float32))
+        want = vjp(g.astype(jnp.float32))[0]
+        # float32 sums rounded once to bfloat16 are within 2^-8 of the largest;
+        # a row summed into the wrong place is O(1)
+        err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)) / jnp.max(jnp.abs(want)))
+        out[f"{n}x16x{width}"] = {
+            "ok": err <= 2.0 ** -7, "compiled": compiled,
+            "blocks": int(table.perm.shape[0]), "max_err": err,
         }
     return out
 

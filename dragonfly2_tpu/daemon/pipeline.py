@@ -1,6 +1,6 @@
 """Zero-copy piece-transfer pipeline: pooled buffers + hash-on-receive.
 
-BENCH_r05 put the checkpoint fan-out path at ~2.3 ns per payload byte of
+A CPU count put the checkpoint fan-out path at ~2.3 ns per payload byte of
 SERIAL single-core CPU: socket recv (~1.1 ns/B) into a freshly allocated
 bytearray, a second full pass for sha256 validation (~0.9 ns/B) on a cold
 buffer, then the store write (~0.3 ns/B) — plus one heap allocation per

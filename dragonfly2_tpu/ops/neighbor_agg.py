@@ -6,9 +6,10 @@ the topology graph is a *dense padded neighbor table* — `neighbors[N, K]`
 int32 with a boolean mask — so aggregation is static-shaped gather + masked
 mean + matmul, all of which XLA tiles onto the MXU with no dynamic shapes.
 
-The XLA path below is the default; ops.neighbor_agg_pallas holds the fused
-MXU kernel for the same contract, auto-selected by `neighbor_aggregate`
-on TPU for VMEM-sized graphs.
+Two ops, both plain XLA in the forward: `neighbor_gather` ([N, H] states ->
+[N, K, H], one row a neighbor slot) and `masked_mean` ([N, K, H] -> [N, H]).
+The one kernel, ops.neighbor_agg_pallas's `sum_by_destination`, is the
+gather's VJP on one TPU chip (below).
 
 In the training step the caller names these ops for the device trace
 (models/graphsage.py: `neighbor_gather` under the `gather` scope, with its
@@ -88,30 +89,3 @@ def masked_mean(x: jnp.ndarray, mask: jnp.ndarray, *, eps: float = 1e-6) -> jnp.
     total = jnp.sum(x * m, axis=1)
     count = jnp.sum(m, axis=1)
     return total / (count + eps)
-
-
-def neighbor_aggregate(
-    h: jnp.ndarray, neighbors: jnp.ndarray, mask: jnp.ndarray, *, impl: str = "auto"
-) -> jnp.ndarray:
-    """Gather + masked mean: [N, H] -> [N, H] neighborhood means.
-
-    impl: "auto" (Pallas on TPU when the graph fits VMEM, else XLA),
-    "pallas", or "xla".
-    """
-    if impl != "xla":
-        from dragonfly2_tpu.ops import neighbor_agg_pallas as pk
-
-        if impl == "pallas" or (impl == "auto" and pk.supports_pallas(h)):
-            return pk.neighbor_aggregate_pallas(h, neighbors, mask)
-    return masked_mean(neighbor_gather(h, neighbors), mask)
-
-
-def segment_mean(values: jnp.ndarray, segment_ids: jnp.ndarray, num_segments: int) -> jnp.ndarray:
-    """COO-style aggregation for data prep: mean of values rows per segment.
-
-    Used when building the padded neighbor table from raw probe records
-    (edge list form), not in the training step itself.
-    """
-    total = jax.ops.segment_sum(values, segment_ids, num_segments)
-    count = jax.ops.segment_sum(jnp.ones_like(values[..., :1]), segment_ids, num_segments)
-    return total / jnp.maximum(count, 1.0)

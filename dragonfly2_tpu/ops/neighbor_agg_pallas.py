@@ -1,26 +1,32 @@
-"""Pallas TPU kernels of the neighbor aggregation: the fused gather + masked
-mean (forward, VMEM-sized graphs) and the gather's VJP over a destination-sorted
-edge table (`edges_by_destination`, `sum_by_destination`: any size, one TPU
-chip; bottom of the file, with all that knows the table's format).
+"""The one Pallas TPU kernel of the neighbor aggregation: the gather's VJP
+(ops.neighbor_agg.neighbor_gather) in the cheap direction, N*K cotangent rows
+summed into N, on one TPU chip. The neighbor table is fixed for a run, so its
+slots are sorted by destination once, on the host (`edges_by_destination`);
+the backward gathers the cotangent rows into that order and a kernel adds up
+the contiguous runs (`sum_by_destination`). All that knows the table's format
+is in this file.
 
-Same contract as ops.neighbor_agg.neighbor_aggregate ([N, H] states,
-[N, K] padded neighbor table + mask → [N, H] neighborhood means), fused so
-the [N, K, H] gathered intermediate never exists in HBM.
-
-Formulation is MXU-native (no per-row dynamic gathers, which Mosaic lowers
-poorly): each grid step owns a TILE_N row block, builds a sparse selection
-matrix A[TILE_N, N] where A[r, c] = #times node c appears as a masked-in
-neighbor of row r (K static one-hot compares on the VPU), then computes the
-neighborhood *sums* as one A @ h matmul on the MXU and divides by the mask
-count. FLOP cost is TILE_N·N·H per tile — wasteful versus a perfect gather
-(density K/N) but it rides the 128×128 systolic array instead of scalar
-loads; it wins whenever h fits VMEM (clusters up to a few thousand hosts,
-the scheduler's whole operating range — guarded by MAX_PALLAS_NODES).
+The slots are sorted inside equal blocks of the source rows, each of at most
+BLOCK_BYTES: XLA gathers rows out of a table it can hold in VMEM four times
+faster than out of one it cannot (3.5 against 14 ns a row on the v5e with
+32 MB and 64 MB blocks, PERF.md). The slots count K-major, slot k*N + n for
+neighbors[n, k], because that is how the cotangent lies in memory: the TPU
+compiler keeps the message tensor [N, K, H] with K major-most (the mean over
+K is then a sum over whole [N, H] slabs), so a block, an equal contiguous
+range of those slots, is a bitcast of what the backward pass wrote (with K
+blocks, block k is g[:, k, :]). Blocks of the row-major order cost a layout
+copy of the whole cotangent a layer (PERF.md). The kernel never learns what
+a slot number means. It sums, per tile of TILE_DST
+destination rows, each block's run of rows that point into the tile, in
+windows of WINDOW rows that start on a multiple of ALIGN (a DMA out of a
+tiled array starts on a whole tile). Its time goes by the window, and the
+windows grow with blocks x tiles: 2.1 times faster than XLA's scatter-add at
+16 blocks (512 MB of cotangent rows), 1.4 at 32 (1 GB), slower at 64 (2 GB;
+PERF.md), so past MAX_BLOCKS there is no table.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple
 
 import jax
@@ -29,133 +35,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-TILE_N = 128
-# A[TILE_N, N] + h[N, H] + out[TILE_N, H] must fit VMEM together; budget
-# conservatively at 12 MB of the ~16 MB. Past that the XLA gather path wins
-# anyway (selection matrix density collapses).
-VMEM_BUDGET_BYTES = 12 << 20
-
-
-def _agg_kernel(nbr_ref, mask_ref, h_ref, out_ref, *, k: int, eps: float):
-    """One row-tile: A = Σ_k onehot(nbr[:, k])·mask[:, k]; out = A@h / count."""
-    n = h_ref.shape[0]
-    tile = nbr_ref.shape[0]
-    col = jax.lax.broadcasted_iota(jnp.int32, (tile, n), 1)
-    acc = jnp.zeros((tile, n), jnp.float32)
-    for kk in range(k):  # K is small and static: unrolled VPU compares
-        idx = nbr_ref[:, kk][:, None]  # [tile, 1]
-        m = mask_ref[:, kk][:, None].astype(jnp.float32)
-        acc = acc + jnp.where(col == idx, m, 0.0)  # dflint: disable=DF012 K<=16 static unroll IS the kernel design
-    sums = jnp.dot(acc, h_ref[:].astype(jnp.float32), preferred_element_type=jnp.float32)
-    count = jnp.sum(mask_ref[:].astype(jnp.float32), axis=1, keepdims=True)
-    out_ref[:] = (sums / (count + eps)).astype(out_ref.dtype)
-
-
-def _forward(h, neighbors, mask, *, eps: float, interpret: bool):
-    n, hdim = h.shape
-    k = neighbors.shape[1]
-    n_pad = max(TILE_N, ((n + TILE_N - 1) // TILE_N) * TILE_N)
-    nbr = jnp.zeros((n_pad, k), jnp.int32).at[:n].set(neighbors.astype(jnp.int32))
-    msk = jnp.zeros((n_pad, k), jnp.float32).at[:n].set(mask.astype(jnp.float32))
-
-    grid = (n_pad // TILE_N,)
-    out = pl.pallas_call(
-        functools.partial(_agg_kernel, k=k, eps=eps),
-        out_shape=jax.ShapeDtypeStruct((n_pad, hdim), h.dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TILE_N, k), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_N, k), lambda i: (i, 0)),
-            pl.BlockSpec((n, hdim), lambda i: (0, 0)),  # full h every tile
-        ],
-        out_specs=pl.BlockSpec((TILE_N, hdim), lambda i: (i, 0)),
-        interpret=interpret,
-    )(nbr, msk, h)
-    return out[:n]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _agg(h, neighbors, mask, eps, interpret):
-    return _forward(h, neighbors, mask, eps=eps, interpret=interpret)
-
-
-def _agg_fwd(h, neighbors, mask, eps, interpret):
-    return _forward(h, neighbors, mask, eps=eps, interpret=interpret), (h.shape, neighbors, mask)
-
-
-def _agg_bwd(eps, interpret, res, g):
-    """d/dh of the masked mean: scatter-add of g rows, weighted by mask/count.
-    XLA segment_sum is the right tool for the (sparse, irregular) backward —
-    the MXU trick only pays off in the dense forward."""
-    (n, hdim), neighbors, mask = res
-    count = jnp.sum(mask.astype(g.dtype), axis=1, keepdims=True) + eps  # [N, 1]
-    contrib = (g / count)[:, None, :] * mask.astype(g.dtype)[:, :, None]  # [N, K, H]
-    gh = jax.ops.segment_sum(
-        contrib.reshape(-1, hdim), neighbors.reshape(-1).astype(jnp.int32), num_segments=n
-    ).astype(g.dtype)
-    return gh, None, None
-
-
-_agg.defvjp(_agg_fwd, _agg_bwd)
-
-
-def neighbor_aggregate_pallas(
-    h: jnp.ndarray,
-    neighbors: jnp.ndarray,
-    mask: jnp.ndarray,
-    *,
-    eps: float = 1e-6,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Fused [N, H] -> [N, H] masked neighborhood mean on TPU via Pallas.
-
-    Differentiable w.r.t. h (custom VJP; backward runs the XLA scatter path).
-    """
-    return _agg(h, neighbors, mask, eps, interpret)
-
-
-def supports_pallas(h: jnp.ndarray) -> bool:
-    """True when the fused kernel applies: TPU backend + VMEM-sized working
-    set (accumulator tile + full h + output tile)."""
-    n, hdim = h.shape
-    n_pad = max(TILE_N, ((n + TILE_N - 1) // TILE_N) * TILE_N)
-    itemsize = 4  # accumulator is f32; h tile counted at its own width below
-    working_set = (
-        TILE_N * n_pad * 4          # selection matrix A (f32)
-        + n * hdim * h.dtype.itemsize  # full node states
-        + TILE_N * hdim * itemsize  # output tile
-    )
-    if working_set > VMEM_BUDGET_BYTES:
-        return False
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:  # pragma: no cover - no backend at all
-        return False
-
-
-# ---------------------------------------------------------------------------
-# The gather's VJP in the cheap direction (ops.neighbor_agg.neighbor_gather):
-# N*K cotangent rows summed into N. The neighbor table is fixed for a run, so
-# its slots are sorted by destination once, on the host; the backward gathers
-# the cotangent rows into that order and a kernel adds up the contiguous runs.
-#
-# The slots are sorted inside equal blocks of the source rows, each of at most
-# BLOCK_BYTES: XLA gathers rows out of a table it can hold in VMEM four times
-# faster than out of one it cannot (3.5 against 14 ns a row on the v5e with
-# 32 MB and 64 MB blocks, PERF.md). The slots count K-major, slot k*N + n for
-# neighbors[n, k], because that is how the cotangent lies in memory: the TPU
-# compiler keeps the message tensor [N, K, H] with K major-most (the mean over
-# K is then a sum over whole [N, H] slabs), so a block, an equal contiguous
-# range of those slots, is a bitcast of what the backward pass wrote (with K
-# blocks, block k is g[:, k, :]). Blocks of the row-major order cost a layout
-# copy of the whole cotangent a layer (PERF.md). The kernel never learns what
-# a slot number means. It sums, per tile of TILE_DST
-# destination rows, each block's run of rows that point into the tile, in
-# windows of WINDOW rows that start on a multiple of ALIGN (a DMA out of a
-# tiled array starts on a whole tile). Its time goes by the window, and the
-# windows grow with blocks x tiles: 2.1 times faster than XLA's scatter-add at
-# 16 blocks (512 MB of cotangent rows), 1.4 at 32 (1 GB), slower at 64 (2 GB;
-# PERF.md), so past MAX_BLOCKS there is no table.
 BLOCK_BYTES = 32 << 20
 MAX_BLOCKS = 32
 TILE_DST = 256

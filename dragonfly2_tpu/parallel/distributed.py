@@ -1,4 +1,4 @@
-"""Multi-process (multi-host) initialization and data feeding.
+"""Multi-process (multi-host) initialization.
 
 North-star configs 3/4 run one JAX process per TPU-VM host (v5e-16 /
 v5p-64); collectives ride ICI between chips and DCN between hosts. This
@@ -10,13 +10,6 @@ module is the process-bootstrap layer for that topology:
                               SAME code path on a laptop/CI: each process
                               hosts `local_device_count` virtual CPU devices
                               and cross-process collectives run over Gloo.
-  process_local_batch()     — per-process data feeding: each host samples /
-                              loads only its own rows and the global array is
-                              assembled from process-local shards
-                              (jax.make_array_from_process_local_data), the
-                              multihost analogue of the piece-granular range
-                              splits the reference uses for downloads
-                              (SURVEY.md §5 long-context note).
   launch_localhost()        — spawn an n-process cluster on 127.0.0.1 for
                               tests and dry runs (the "cluster-in-a-box"
                               strategy, SURVEY.md §4).
@@ -32,7 +25,7 @@ import socket
 import subprocess
 import sys
 from dataclasses import dataclass
-from typing import Any, Sequence
+from typing import Sequence
 
 _ENV_COORD = "DF_DIST_COORDINATOR"
 _ENV_NPROCS = "DF_DIST_NUM_PROCESSES"
@@ -117,32 +110,6 @@ def _force_cpu_devices(count: int) -> None:
 
     if not (jax.config.jax_platforms or os.environ.get("JAX_PLATFORMS")):
         jax.config.update("jax_platforms", "cpu")
-
-
-def process_local_batch(sharding, local_rows: Any, global_shape: tuple[int, ...]):
-    """Assemble a global array from this process's row slice.
-
-    `local_rows` is the contiguous slice of the global batch this process is
-    responsible for (row-ownership follows device order: process p owns rows
-    [p·L, (p+1)·L) of a batch-sharded axis). On a single process this is just
-    device_put — the same call sites work unchanged in both modes.
-    """
-    import jax
-
-    if jax.process_count() == 1:
-        return jax.device_put(local_rows, sharding)
-    return jax.make_array_from_process_local_data(sharding, local_rows, global_shape)
-
-
-def local_row_slice(global_rows: int) -> tuple[int, int]:
-    """[start, stop) of the batch rows this process owns (equal split)."""
-    import jax
-
-    n, p = jax.process_count(), jax.process_index()
-    if global_rows % n:
-        raise ValueError(f"global batch {global_rows} not divisible by {n} processes")
-    per = global_rows // n
-    return p * per, (p + 1) * per
 
 
 def free_port() -> int:

@@ -3,15 +3,14 @@
 Launched per-host by `distributed.launch_localhost` (tests / dry runs) or by
 the real pod launcher: initializes jax.distributed from DF_DIST_* env, builds
 the global ("data", "model") mesh over ALL processes' devices, and runs
-DF_MP_STEPS training steps where each process feeds only its own batch rows
-(`distributed.process_local_batch`). Process 0 prints the loss trajectory as
-`MP_LOSSES <json>`.
+DF_MP_STEPS training steps in one call of the served scan: the pair pool
+replicated on the global mesh, batches sampled on the device. Process 0 prints
+the loss trajectory as `MP_LOSSES <json>`.
 
 This is the code path the reference never had (its trainer dropped dataset
 chunks on the floor, pkg/rpc/trainer/server/server.go:59): data parallelism
 across hosts over DCN/Gloo, tensor parallelism inside a host — the same jit
-and shardings as single-process training; only initialization and batch
-feeding differ.
+and shardings as single-process training; only initialization differs.
 """
 
 from __future__ import annotations
@@ -35,7 +34,6 @@ def main() -> None:
 
     from dragonfly2_tpu.parallel import mesh as meshlib
     from dragonfly2_tpu.trainer import synthetic, train_gnn
-    from dragonfly2_tpu.trainer.synthetic import PairBatch
 
     steps = int(os.environ.get("DF_MP_STEPS", "12"))
     num_nodes = int(os.environ.get("DF_MP_NODES", "128"))
@@ -51,23 +49,12 @@ def main() -> None:
         warmup_steps=2,
     )
     state = train_gnn.init_state(tcfg, cluster.graph, rng_seed=0)
-    state, g, step_fn = train_gnn.shard_for_training(state, cluster.graph, mesh)
-
-    batch_sh = meshlib.batch_sharding(mesh)
-    lo, hi = dist.local_row_slice(tcfg.batch_size)
-    rng = np.random.default_rng(0)  # same seed everywhere → same global batch
-    losses: list[float] = []
-    for _ in range(steps):
-        b = synthetic.sample_batch(cluster.pairs, tcfg.batch_size, rng)
-        gb = PairBatch(
-            *(
-                dist.process_local_batch(batch_sh, a[lo:hi], (tcfg.batch_size,) + a.shape[1:])
-                for a in b
-            )
-        )
-        state, loss = step_fn(state, g, gb)
-        losses.append(float(loss))
-    jax.block_until_ready(state.params)
+    state, g, pool, multi_step = train_gnn.shard_for_training_scan(
+        state, cluster.graph, cluster.pairs, mesh, batch_size=tcfg.batch_size, steps_per_call=steps
+    )
+    # same key everywhere → same global batches
+    state, (ls, _) = multi_step(state, g, pool, jax.random.PRNGKey(0))
+    losses = [float(v) for v in np.asarray(ls)]
     if jax.process_index() == 0:
         print(
             f"mp_train ok: platform={jax.devices()[0].platform} "

@@ -925,8 +925,8 @@ class RoundDispatcher:
     across cores (ISSUE 7 tentpole; ROADMAP open item #1).
 
     The single-loop serving path tops out at the single-core Python ceiling
-    (BENCH_r05: 12.2k raw FFI calls/s vs 4.7k end-to-end rounds/s at
-    ceiling fraction 1.045): every round's feature assembly and glue runs on
+    (a CPU count: 12.2k raw FFI calls/s vs 4.7k end-to-end rounds/s): every
+    round's feature assembly and glue runs on
     the event loop, so adding cores adds nothing. Podracer (arxiv 2104.06272)
     makes the same move decoupling a sequential control loop into sharded
     workers that keep the accelerator-side scoring saturated — here each

@@ -1,10 +1,12 @@
 """Sharded GNN training (north-star configs 2-3).
 
-One jitted train step over a ("data", "model") mesh: graph node rows and the
-pair batch are sharded over "data", Dense kernels over "model"; XLA inserts
-the neighbor-gather all-gathers and the gradient psum from the sharding
-annotations alone (no hand-written collectives — pjit style, per the
-scaling-book recipe).
+One jitted program over a ("data", "model") mesh, `multi_step`: a `lax.scan`
+of optimizer steps that samples its minibatches on the device
+(`shard_for_training_scan` places a run and builds it, `train_async` drives
+it). Graph node rows and the sampled pair batch are sharded over "data", Dense
+kernels over "model"; XLA inserts the neighbor-gather all-gathers and the
+gradient psum from the sharding annotations alone (no hand-written
+collectives — pjit style, per the scaling-book recipe).
 
 Replaces the reference's never-implemented trainer loop (trainer/ is
 config+metrics only; the Train RPC at pkg/rpc/trainer/server/server.go:59
@@ -25,12 +27,12 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from flax.training import train_state
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
 from dragonfly2_tpu.models.graphsage import LOSS, OPTIMIZER, SAMPLE, TopoGraph, TopoScorer
 from dragonfly2_tpu.observability.tracing import default_tracer
 from dragonfly2_tpu.parallel import mesh as meshlib
-from dragonfly2_tpu.trainer.synthetic import PairBatch, sample_batch
+from dragonfly2_tpu.trainer.synthetic import PairBatch
 from dragonfly2_tpu.utils import jaxenv
 
 
@@ -43,7 +45,6 @@ class GNNTrainConfig:
     learning_rate: float = 3e-3
     weight_decay: float = 1e-4
     warmup_steps: int = 100
-    remat: bool = False
 
 
 def make_model(cfg: GNNTrainConfig) -> TopoScorer:
@@ -83,38 +84,20 @@ def loss_fn(apply_fn: Callable, params: Any, g: TopoGraph, batch: PairBatch) -> 
         return jnp.mean((pred - batch.label) ** 2)
 
 
-def make_train_step(remat: bool = False, *, with_metrics: bool = False) -> Callable:
-    """One optimizer step; with `remat` the model apply is wrapped in
-    jax.checkpoint, so the backward pass RECOMPUTES the GNN forward instead
-    of holding its activations — the [N, K, H] message tensors dominate live
-    memory at scaled node counts (16k nodes × 16 neighbors × hidden), and
-    trading them for FLOPs is what lets the scaled shape fit a single chip's
-    HBM. Verified structurally: the lowered HLO at the 16k-node shape gains
-    recomputation dot_generals (tests/test_trainer.py pins this).
-
-    with_metrics=False (default) keeps the historic (state, loss) return;
-    True widens it to (state, (loss, grad_norm)) — the global pre-update
-    gradient norm the training-run telemetry exports per step (ISSUE 15).
-    Opt-in so existing jitted callers (bench, profile tools, the sharded
-    equivalence tests) keep their compiled shapes."""
+def make_train_step() -> Callable:
+    """One optimizer step, `(state, g, batch) -> (state, (loss, grad_norm))`:
+    the body `multi_step` scans. The norm is the global one of the gradients
+    before clipping, which the training-run telemetry exports per step."""
 
     def step(
         state: train_state.TrainState, g: TopoGraph, batch: PairBatch
     ):
-        apply_fn = jax.checkpoint(state.apply_fn) if remat else state.apply_fn
-        loss, grads = jax.value_and_grad(partial(loss_fn, apply_fn))(state.params, g, batch)
+        loss, grads = jax.value_and_grad(partial(loss_fn, state.apply_fn))(state.params, g, batch)
         with jax.named_scope(OPTIMIZER):
-            if with_metrics:
-                gnorm = optax.global_norm(grads)
-                return state.apply_gradients(grads=grads), (loss, gnorm)
-            return state.apply_gradients(grads=grads), loss
+            gnorm = optax.global_norm(grads)
+            return state.apply_gradients(grads=grads), (loss, gnorm)
 
     return step
-
-
-# the default (no-remat) step keeps its name: shard_for_training /
-# make_scan_step build their own from make_train_step when remat is on
-train_step = make_train_step(remat=False)
 
 
 def _place_sharded(
@@ -127,7 +110,7 @@ def _place_sharded(
     dp = mesh.shape[meshlib.DATA_AXIS]
     param_sh = meshlib.infer_param_sharding(state.params, mesh)
     state_sh = train_state.TrainState(
-        step=NamedSharding(mesh, P()),
+        step=meshlib.replicated(mesh),
         apply_fn=state.apply_fn,
         params=param_sh,
         tx=state.tx,
@@ -168,25 +151,6 @@ def _edges_by_destination(state: train_state.TrainState, g: TopoGraph, mesh: Mes
     return edges_by_destination(np.asarray(g.neighbors), *_gathered_states(state))
 
 
-def shard_for_training(
-    state: train_state.TrainState, g: TopoGraph, mesh: Mesh, *, remat: bool = False
-) -> tuple[train_state.TrainState, TopoGraph, Callable]:
-    """Place state/graph per the mesh rules and return the jitted step.
-
-    Node rows over "data" (pad N to the dp size first), kernels over "model",
-    batch rows over "data".
-    """
-    state, state_sh, g, g_sh = _place_sharded(state, g, mesh)
-    batch_sh = PairBatch(*([meshlib.batch_sharding(mesh)] * 4))
-    step = jax.jit(
-        make_train_step(remat),
-        in_shardings=(state_sh, g_sh, batch_sh),
-        out_shardings=(state_sh, NamedSharding(mesh, P())),
-        donate_argnums=(0,),
-    )
-    return state, g, step
-
-
 def pad_graph(g: TopoGraph, n_padded: int) -> TopoGraph:
     """Pad node dim to n_padded (static shapes, whole row shards) with copies
     of node 0. No neighbour slot and no pair names a padding row, so it moves
@@ -207,8 +171,6 @@ def shard_for_training_scan(
     *,
     batch_size: int = 4096,
     steps_per_call: int = 10,
-    remat: bool = False,
-    with_metrics: bool = False,
 ) -> tuple[train_state.TrainState, TopoGraph, PairBatch, Callable]:
     """Device-resident training: the pair POOL lives on device and each
     jitted call runs `steps_per_call` optimizer steps via lax.scan, sampling
@@ -217,43 +179,17 @@ def shard_for_training_scan(
     This removes the per-step host round trip (numpy sampling + H2D transfer
     + dispatch) that dominates wall clock for a model this size — the
     scaling-book rule: don't bounce to the host between steps. Returns
-    (state, g, pairs, multi_step) where
-    ``multi_step(state, g, pairs, key) -> (state, losses[steps_per_call])``.
+    (state, g, pairs, multi_step) where ``multi_step(state, g, pairs, key) ->
+    (state, (losses[steps_per_call], grad_norms[steps_per_call]))``.
     """
     batch_size = meshlib.pad_to_multiple(batch_size, mesh.shape[meshlib.DATA_AXIS])
     state, state_sh, g, g_sh = _place_sharded(state, g, mesh)
     # the full pool is small (MBs) and replicated; sampled rows get
     # constrained onto the data axis inside the step
-    pool_sh = PairBatch(*([NamedSharding(mesh, P())] * 4))
+    pool_sh = PairBatch(*([meshlib.replicated(mesh)] * 4))
     pairs = jax.device_put(PairBatch(*(jnp.asarray(a) for a in pairs)), pool_sh)
-    jitted = make_scan_step(
-        mesh, state_sh, g_sh, pool_sh,
-        batch_size=batch_size, steps_per_call=steps_per_call, remat=remat,
-        with_metrics=with_metrics,
-    )
-    return state, g, pairs, jitted
-
-
-def make_scan_step(
-    mesh: Mesh,
-    state_sh: Any,
-    g_sh: TopoGraph,
-    pool_sh: PairBatch,
-    *,
-    batch_size: int,
-    steps_per_call: int,
-    remat: bool = False,
-    with_metrics: bool = False,
-) -> Callable:
-    """The jitted K-step scan alone, given already-known shardings — lets a
-    caller with placed arrays build variants (e.g. a 1-step lowering for
-    FLOPs accounting) without re-placing state on the device. Shardings can
-    be recovered from placed arrays via ``jax.tree.map(lambda x: x.sharding,
-    tree)``. with_metrics widens the scan's ys from losses[K] to
-    (losses[K], grad_norms[K]) — the replicated out-sharding below is a
-    pytree PREFIX, so it covers either shape."""
-    batch_sh = NamedSharding(mesh, P(meshlib.DATA_AXIS))
-    step = make_train_step(remat, with_metrics=with_metrics)
+    batch_sh = meshlib.batch_sharding(mesh)
+    step = make_train_step()
 
     def multi_step(st, gg, pool, key):
         n_pool = pool.child.shape[0]
@@ -270,12 +206,14 @@ def make_scan_step(
             keys = jax.random.split(key, steps_per_call)
         return jax.lax.scan(one, st, keys)
 
-    return jax.jit(
+    # the replicated out-sharding is a pytree prefix: it covers both ys
+    jitted = jax.jit(
         multi_step,
-        in_shardings=(state_sh, g_sh, pool_sh, NamedSharding(mesh, P())),
-        out_shardings=(state_sh, NamedSharding(mesh, P())),
+        in_shardings=(state_sh, g_sh, pool_sh, meshlib.replicated(mesh)),
+        out_shardings=(state_sh, meshlib.replicated(mesh)),
         donate_argnums=(0,),
     )
+    return state, g, pairs, jitted
 
 
 def _placement(mesh: Mesh, decision: dict, state: Any, g: TopoGraph, batch_size: int) -> dict:
@@ -338,10 +276,10 @@ async def train_async(
     is steps rounded up to a whole number of calls.
 
     telemetry: optional trainer.metrics.TrainRunTelemetry — per-step loss +
-    grad-norm land in the dragonfly_train_* families after every call. The
-    grad norms ride the scan's ys (with_metrics), so the telemetry costs no
-    extra D2H sync: the per-call np.asarray pull already materializes them.
-    It also gets every call's start and end once, at the run's end.
+    grad-norm land in the dragonfly_train_* families after every call. Both
+    ride the scan's ys and are pulled every call, so the compiled program is
+    the same with and without it. It also gets every call's start and end
+    once, at the run's end.
 
     The mesh, when the caller gives none, is `parallel.mesh.mesh_for_run`'s
     (every device on `data`); the run manifest's `placement.decision` says so.
@@ -359,7 +297,6 @@ async def train_async(
         mesh, decision = meshlib.mesh_for_run()
     steps_per_call = max(1, min(steps_per_call, steps))
     calls = -(-steps // steps_per_call)
-    with_metrics = telemetry is not None
 
     tracer = default_tracer()
 
@@ -369,7 +306,6 @@ async def train_async(
             return shard_for_training_scan(
                 state, graph, pairs, mesh,
                 batch_size=cfg.batch_size, steps_per_call=steps_per_call,
-                remat=cfg.remat, with_metrics=with_metrics,
             )
 
     state, g, pool, multi_step = await asyncio.to_thread(_setup)
@@ -389,13 +325,11 @@ async def train_async(
                 # the first call compiles the step or loads it from the
                 # persistent cache: under a key that holds its scope names
                 with jaxenv.op_names_in_cache_key() if index == 0 else contextlib.nullcontext():
-                    st, ys = multi_step(st, g, pool, sub)
+                    st, (ls, gn) = multi_step(st, g, pool, sub)
             # D2H pull materializes the whole call's chain before returning to
-            # the loop — the same sync discipline the bench windows use
+            # the loop
             with tracer.span("trainer.gnn.pull"):
-                ls, gn = ys if with_metrics else (ys, None)
-                ls = np.asarray(ls)
-                gn = None if gn is None else np.asarray(gn)
+                ls, gn = np.asarray(ls), np.asarray(gn)
         call_times.append((t_start, time.perf_counter()))
         return st, k, ls, gn
 
@@ -403,7 +337,7 @@ async def train_async(
     t0 = time.perf_counter()
     for i in range(calls):
         state, key, ls, gn = await asyncio.to_thread(_one_call, state, key, i)
-        if telemetry is not None and gn is not None:
+        if telemetry is not None:
             for lv, gv in zip(ls, gn):
                 telemetry.on_step(
                     float(lv), float(gv), examples=cfg.batch_size
@@ -417,35 +351,4 @@ async def train_async(
             )
     if telemetry is not None:
         telemetry.on_calls(call_times)
-    return state, losses
-
-
-def train(
-    cfg: GNNTrainConfig,
-    graph: TopoGraph,
-    pairs: PairBatch,
-    *,
-    steps: int,
-    mesh: Mesh | None = None,
-    seed: int = 0,
-    log_every: int = 100,
-    log: Callable[[str], None] = lambda s: None,
-) -> tuple[train_state.TrainState, list[float]]:
-    """Full training driver; returns final state + loss history."""
-    mesh = mesh or meshlib.mesh_for_run()[0]
-    state = init_state(cfg, graph, seed)
-    state, g, step_fn = shard_for_training(state, graph, mesh, remat=cfg.remat)
-    rng = np.random.default_rng(seed)
-    # Batch rows shard over "data": round up so every shard is equal-sized.
-    batch_size = meshlib.pad_to_multiple(cfg.batch_size, mesh.shape[meshlib.DATA_AXIS])
-    losses: list[float] = []
-    t0 = time.perf_counter()
-    for i in range(steps):
-        batch = sample_batch(pairs, batch_size, rng)
-        state, loss = step_fn(state, g, PairBatch(*(jnp.asarray(a) for a in batch)))
-        if (i + 1) % log_every == 0 or i == 0:
-            lv = float(loss)
-            losses.append(lv)
-            log(f"step {i + 1}/{steps} loss={lv:.5f} ({(i + 1) / (time.perf_counter() - t0):.2f} steps/s)")
-    jax.block_until_ready(state.params)
     return state, losses

@@ -1,10 +1,9 @@
 """Contract tests for bench.py's measurement helpers.
 
-The bench is the round's perf record; these pin the parts a refactor could
-silently break: the 5-tuple shape of the GNN measurement (best window,
-median window, compiler FLOPs/bytes, measured convergence), the
-best >= median invariant of the windowed statistic, and the one-line JSON
-payload schema the driver parses.
+bench.py holds the host microbenchmarks (the training step is measured by
+benchmarks/run.py); these pin the parts a refactor could silently break: each
+section's key set at a tiny size, the device sections whose failure fails the
+run, and the one-line JSON payload schema.
 """
 
 import json
@@ -12,24 +11,30 @@ import os
 import subprocess
 import sys
 
-import pytest
-
 import bench
 
 
-def test_gnn_train_measured_contract():
-    best, median, flops, nbytes, conv = bench._gnn_train_measured(
-        num_nodes=64, hidden=16, batch_size=64,
-        calls=1, steps_per_call=2, measure_convergence=True,
-    )
-    # a real rate, windows ordered, compiler accounting populated
-    assert best > 0 and median > 0
-    assert best >= median  # max-of-windows can never undercut the median
-    assert flops > 0 and nbytes > 0
-    # convergence on this synthetic: > 0 is the measured crossing step;
-    # -1 is the bench's documented benign slow-backend timeout and must not
-    # fail CI; 0 ("ran and never crossed") is the one true regression signal
-    assert conv != 0
+def test_jax_scoring_contract():
+    """The device section left beside `mlp_train`: single-round rate, its p50
+    and the multi-round rate of the JAX scorer, all measured."""
+    single_rps, p50_ms, multi_rps = bench.bench_scoring(rounds=40, candidates=8)
+    assert single_rps > 0 and p50_ms > 0 and multi_rps > 0
+
+
+def test_device_sections_are_sections_the_worker_runs():
+    """A failure of a section named in DEVICE_SECTIONS exits the worker
+    non-zero: each name must be one `main()` hands to `run_section`, or the
+    refusal would never fire."""
+    import ast
+    import inspect
+
+    run = {
+        call.args[0].value
+        for call in ast.walk(ast.parse(inspect.getsource(bench.main)))
+        if isinstance(call, ast.Call) and getattr(call.func, "id", None) == "run_section"
+    }
+    assert bench.DEVICE_SECTIONS == ("jax_scoring", "mlp_train")
+    assert set(bench.DEVICE_SECTIONS) <= run, run
 
 
 def test_dataset_build_contract():
@@ -379,13 +384,6 @@ def test_payload_schema():
     assert d["value"] == 1234.5
     assert d["vs_baseline"] == round(1234.5 / 10_000, 3)
     assert d["extra"]["platform"] == "cpu"
-
-
-def test_unknown_device_kind_is_an_error_not_v5e_peaks():
-    peaks = bench.chip_peaks("TPU v5 lite")
-    assert (peaks["bf16_tflops"], peaks["hbm_gbps"]) == (197.0, 819.0) and peaks["source"]
-    with pytest.raises(KeyError, match="no published peaks"):
-        bench.chip_peaks("TPU v9 imaginary")
 
 
 def test_supervisor_refuses_cpu_unless_the_caller_forced_it():

@@ -13,6 +13,7 @@ import sys
 from pathlib import Path
 
 import jax
+import pytest
 
 import chip_smoke
 from dragonfly2_tpu.native import scorer as native_scorer
@@ -71,7 +72,7 @@ def test_result_line_has_exactly_the_contract_keys(monkeypatch, capsys):
     "count"}} and nothing else; the detail is the stderr summary."""
     tpu = {"platform": "tpu", "device_kind": "TPU v5 lite", "device_count": 1}
     trainer = {"ok": True, "device": tpu, "mesh": {"data": 1, "model": 1}, "gnn_artifact": "g"}
-    child = {"ok": True, **tpu, "pallas": {"1024x256": {"ok": True, "compiled": True}}}
+    child = {"ok": True, **tpu, "pallas": {"1024x16x256": {"ok": True, "compiled": True}}}
     monkeypatch.setattr(chip_smoke, "_trainer_phase", lambda *a: trainer)
     monkeypatch.setattr(chip_smoke, "_artifacts_phase", lambda t: {"ok": True, "missing": []})
     monkeypatch.setattr(chip_smoke, "_run_child", lambda name, *a, **k: child)
@@ -91,8 +92,8 @@ def test_platform_phase_refuses_a_device_child_that_fell_back():
     interpreted."""
     tpu = {"platform": "tpu", "device_kind": "TPU v5 lite", "device_count": 1}
     cpu = {"platform": "cpu", "device_kind": "cpu", "device_count": 1}
-    compiled = {"1024x256": {"ok": True, "compiled": True}}
-    interpreted = {"1024x256": {"ok": True, "compiled": False}}
+    compiled = {"1024x16x256": {"ok": True, "compiled": True}}
+    interpreted = {"1024x16x256": {"ok": True, "compiled": False}}
     assert chip_smoke._platform_phase(tpu, {"ok": True, **tpu, "pallas": compiled})["ok"]
     assert not chip_smoke._platform_phase(tpu, {"ok": True, **cpu, "pallas": interpreted})["ok"]
     assert not chip_smoke._platform_phase(tpu, {"ok": True, **tpu, "pallas": interpreted})["ok"]
@@ -102,6 +103,25 @@ def test_platform_phase_refuses_a_device_child_that_fell_back():
     assert not chip_smoke._platform_phase(tpu, {"ok": False, "error": "boom"})["ok"]
     assert not chip_smoke._platform_phase(
         tpu, {"ok": True, **tpu, "device_count": 4, "pallas": compiled})["ok"]
+
+
+@pytest.fixture(scope="module")
+def kernel_leg():
+    """The device child's kernel leg as it runs off the chip: interpreted."""
+    return chip_smoke._pallas_check(False)
+
+
+@pytest.mark.parametrize("shape", chip_smoke.KERNEL_SHAPES, ids=lambda s: "x".join(map(str, s[:2])))
+def test_kernel_leg_checks_the_steps_kernel_interpreted_on_cpu(kernel_leg, shape):
+    """The kernel the training step runs (`sum_by_destination` over a seeded
+    table), interpreted, is within bfloat16 of `jnp.take`'s own VJP at both of
+    the smoke's shapes; the second has a hub row and two source blocks. A
+    kernel summing rows into the wrong place fails it."""
+    n, width, hub = shape
+    assert len(kernel_leg) == len(chip_smoke.KERNEL_SHAPES)
+    result = kernel_leg[f"{n}x16x{width}"]
+    assert result["ok"] and result["compiled"] is False
+    assert result["blocks"] == (2 if hub else 1) and 0 < result["max_err"] <= 2.0 ** -7
 
 
 def test_compile_cache_honours_env_else_fixed_checkout_path(tmp_path, monkeypatch):

@@ -1,9 +1,10 @@
 """Multi-chip / multi-process evidence tests (north-star configs 2-4).
 
 Convergence UNDER sharding on the 1k-node synthetic, mesh-shape invariance,
-a 16-device run, and a real jax.distributed 2-process localhost cluster with
-per-process batch feeding — the CPU-simulated versions of the v5e-16 /
-v5p-64 topologies (SURVEY.md §4 "cluster-in-a-box" strategy).
+a 16-device run, and a real jax.distributed 2-process localhost cluster —
+the CPU-simulated versions of the v5e-16 / v5p-64 topologies (SURVEY.md §4
+"cluster-in-a-box" strategy). All of them drive the served program: one call
+of `multi_step`, batches sampled inside the scan from one key.
 """
 
 import json
@@ -11,6 +12,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -18,6 +20,16 @@ from dragonfly2_tpu.parallel import mesh as meshlib
 from dragonfly2_tpu.trainer import synthetic, train_gnn
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _scan_losses(cfg, cluster, mesh, steps: int, seed: int) -> list[float]:
+    """`steps` optimizer steps in one call of the served scan on `mesh`."""
+    state, g, pool, multi_step = train_gnn.shard_for_training_scan(
+        train_gnn.init_state(cfg, cluster.graph, rng_seed=seed), cluster.graph, cluster.pairs, mesh,
+        batch_size=cfg.batch_size, steps_per_call=steps,
+    )
+    _, (losses, _) = multi_step(state, g, pool, jax.random.PRNGKey(seed))
+    return np.asarray(losses).tolist()
 
 
 def test_sharded_convergence_1k_nodes():
@@ -40,19 +52,7 @@ def test_sharded_convergence_1k_nodes():
     cfg = train_gnn.GNNTrainConfig(
         hidden=64, embed_dim=32, num_layers=2, batch_size=512, warmup_steps=5
     )
-    state, g, step_fn = train_gnn.shard_for_training(
-        train_gnn.init_state(cfg, cluster.graph, rng_seed=7), cluster.graph, mesh
-    )
-    import jax.numpy as jnp
-
-    from dragonfly2_tpu.trainer.synthetic import PairBatch
-
-    rng = np.random.default_rng(7)
-    losses = []
-    for _ in range(50):
-        b = synthetic.sample_batch(cluster.pairs, cfg.batch_size, rng)
-        state, loss = step_fn(state, g, PairBatch(*(jnp.asarray(a) for a in b)))
-        losses.append(float(loss))
+    losses = _scan_losses(cfg, cluster, mesh, 50, seed=7)
     assert all(np.isfinite(v) for v in losses)
     windows = [float(np.mean(losses[i : i + 10])) for i in range(0, 50, 10)]
     assert windows[1] < windows[0], f"no initial descent: {windows}"
@@ -64,27 +64,13 @@ def test_sharded_convergence_1k_nodes():
 def test_mesh_shape_invariance_small():
     """The same seed must give (numerically close) trajectories on tp and
     pure-dp meshes — sharding is an execution layout, not a model change."""
-    import jax.numpy as jnp
-
-    from dragonfly2_tpu.trainer.synthetic import PairBatch
-
     cluster = synthetic.make_cluster(num_nodes=64, num_neighbors=4, num_pairs=1024, seed=0)
-    trajectories = []
-    for mp in (4, 1):
-        mesh = meshlib.make_mesh(model_parallel=mp)
-        cfg = train_gnn.GNNTrainConfig(
-            hidden=32, embed_dim=16, num_layers=2, batch_size=128, warmup_steps=2
-        )
-        state, g, step_fn = train_gnn.shard_for_training(
-            train_gnn.init_state(cfg, cluster.graph, rng_seed=0), cluster.graph, mesh
-        )
-        rng = np.random.default_rng(0)
-        losses = []
-        for _ in range(8):
-            b = synthetic.sample_batch(cluster.pairs, cfg.batch_size, rng)
-            state, loss = step_fn(state, g, PairBatch(*(jnp.asarray(a) for a in b)))
-            losses.append(float(loss))
-        trajectories.append(losses)
+    cfg = train_gnn.GNNTrainConfig(
+        hidden=32, embed_dim=16, num_layers=2, batch_size=128, warmup_steps=2
+    )
+    trajectories = [
+        _scan_losses(cfg, cluster, meshlib.make_mesh(model_parallel=mp), 8, seed=0) for mp in (4, 1)
+    ]
     np.testing.assert_allclose(trajectories[0], trajectories[1], rtol=2e-2)
     assert trajectories[0][-1] < trajectories[0][0]
 
@@ -121,7 +107,7 @@ def test_dryrun_16_devices_subprocess():
 @pytest.mark.slow
 def test_multiprocess_distributed_training():
     """Real jax.distributed: 2 processes × 4 virtual devices, Gloo
-    cross-process collectives, per-process batch rows — loss decreases.
+    cross-process collectives, the pool replicated over both — loss decreases.
 
     Marked slow: on the 2-core CI image the Gloo collectives reliably
     deadlock (2 procs × 4 virtual devices oversubscribe it), so in tier-1
@@ -148,14 +134,3 @@ def test_multiprocess_distributed_training():
     assert np.mean(losses[-3:]) < np.mean(losses[:3]) * 0.5, losses
     ok = next(l for l in done[0].stdout.splitlines() if l.startswith("mp_train ok"))
     assert "procs=2 devices=8" in ok
-
-
-def test_local_row_slice_single_process():
-    from dragonfly2_tpu.parallel import distributed as dist
-
-    lo, hi = dist.local_row_slice(128)
-    assert (lo, hi) == (0, 128)  # single process owns everything
-    # process_local_batch degrades to a plain device_put on one process
-    sh = meshlib.batch_sharding(meshlib.make_mesh())
-    arr = dist.process_local_batch(sh, np.ones((16, 4), np.float32), (16, 4))
-    assert arr.shape == (16, 4) and "data" in str(arr.sharding.spec)

@@ -73,7 +73,7 @@ def _program(inputs: tuple, mesh, dtype) -> tuple:
     model = TopoScorer(hidden=cfg.hidden, embed_dim=cfg.embed_dim, num_layers=cfg.num_layers, dtype=dtype)
     state = state.replace(apply_fn=model.apply)
     state, g, pool, multi_step = train_gnn.shard_for_training_scan(
-        state, graph, pairs, mesh, batch_size=cfg.batch_size, steps_per_call=STEPS, with_metrics=True)
+        state, graph, pairs, mesh, batch_size=cfg.batch_size, steps_per_call=STEPS)
     _, sub = jax.random.split(jax.random.PRNGKey(opt["sample_seed"]))
     text = multi_step.lower(state, g, pool, sub).compile().as_text()
     _, (losses, gnorms) = multi_step(state, g, pool, sub)
@@ -189,7 +189,7 @@ def test_one_device_lowers_to_the_program_a_hand_built_mesh_gives(dataset):
     for mesh in (decided, meshlib.make_mesh(jax.devices()[:1])):
         state, g, pool, multi_step = train_gnn.shard_for_training_scan(
             train_gnn.init_state(cfg, graph, 0), graph, pairs, mesh,
-            batch_size=cfg.batch_size, steps_per_call=STEPS, with_metrics=True)
+            batch_size=cfg.batch_size, steps_per_call=STEPS)
         texts.append(multi_step.lower(state, g, pool, jax.random.PRNGKey(0)).as_text())
     assert texts[0] == texts[1]
 

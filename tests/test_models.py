@@ -8,7 +8,7 @@ import pytest
 from dragonfly2_tpu.models import BandwidthMLP, GraphSAGE, TopoScorer
 from dragonfly2_tpu.models.features import FEATURE_DIM, BASE_WEIGHTS
 from dragonfly2_tpu.models.scorer import GNNScorer, LinearScorer
-from dragonfly2_tpu.ops.neighbor_agg import masked_mean, neighbor_aggregate, neighbor_gather
+from dragonfly2_tpu.ops.neighbor_agg import masked_mean, neighbor_gather
 from dragonfly2_tpu.trainer import synthetic
 
 
@@ -37,7 +37,7 @@ class TestOps:
         h = rng.standard_normal((10, 8)).astype(np.float32)
         nbrs = rng.integers(0, 10, (10, 3)).astype(np.int32)
         mask = (rng.random((10, 3)) > 0.3).astype(np.float32)
-        out = np.asarray(neighbor_aggregate(jnp.asarray(h), jnp.asarray(nbrs), jnp.asarray(mask)))
+        out = np.asarray(masked_mean(neighbor_gather(jnp.asarray(h), jnp.asarray(nbrs)), jnp.asarray(mask)))
         for i in range(10):
             sel = h[nbrs[i]][mask[i] > 0]
             want = sel.mean(0) if len(sel) else np.zeros(8)

@@ -47,7 +47,7 @@ def op_names() -> set[str]:
     )
     state, g, pool, multi_step = train_gnn.shard_for_training_scan(
         train_gnn.init_state(CFG, _graph(), 0), _graph(), pairs, meshlib.make_mesh(),
-        batch_size=CFG.batch_size, steps_per_call=3, with_metrics=True,
+        batch_size=CFG.batch_size, steps_per_call=3,
     )
     text = multi_step.lower(state, g, pool, jax.random.PRNGKey(0)).compile().as_text()
     return set(re.findall(r'op_name="([^"]*)"', text))

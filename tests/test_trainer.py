@@ -1,5 +1,8 @@
-"""Trainer tests: sharded train step over the virtual 8-device mesh,
-convergence on the synthetic cluster, GNN beating the linear baseline."""
+"""Trainer tests: the served scan step over the virtual 8-device mesh,
+convergence on the synthetic cluster, GNN beating the linear baseline, and
+one program whoever asks for it."""
+
+import asyncio
 
 import jax
 import jax.numpy as jnp
@@ -44,16 +47,17 @@ class TestShardedTraining:
         mesh = meshlib.make_mesh()
         cfg = train_gnn.GNNTrainConfig(hidden=32, embed_dim=16, num_layers=2, batch_size=64, warmup_steps=2)
         state = train_gnn.init_state(cfg, cluster.graph)
-        state, g, step_fn = train_gnn.shard_for_training(state, cluster.graph, mesh)
+        state, g, pool, multi_step = train_gnn.shard_for_training_scan(
+            state, cluster.graph, cluster.pairs, mesh, batch_size=64, steps_per_call=1)
         # params actually sharded over the model axis
         kernels = [p for p in jax.tree.leaves(state.params) if getattr(p, "ndim", 0) == 2]
         assert any("model" in str(k.sharding.spec) for k in kernels)
-        # graph rows actually sharded over the data axis
+        # graph rows actually sharded over the data axis, the pool on every device
         assert "data" in str(g.node_feats.sharding.spec)
-        rng = np.random.default_rng(0)
-        batch = synthetic.sample_batch(cluster.pairs, 64, rng)
-        state, loss = step_fn(state, g, PairBatch(*(jnp.asarray(a) for a in batch)))
-        assert np.isfinite(float(loss))
+        assert pool.child.sharding.is_fully_replicated
+        state, (losses, gnorms) = multi_step(state, g, pool, jax.random.PRNGKey(0))
+        assert losses.shape == gnorms.shape == (1,)
+        assert np.isfinite(float(losses[0])) and float(gnorms[0]) > 0
 
     def test_convergence_beats_linear_baseline(self, cluster):
         train_pairs = PairBatch(*(a[:8192] for a in cluster.pairs))
@@ -61,9 +65,11 @@ class TestShardedTraining:
         cfg = train_gnn.GNNTrainConfig(
             hidden=64, embed_dim=32, num_layers=2, batch_size=512, warmup_steps=10, learning_rate=3e-3
         )
-        state, losses = train_gnn.train(
-            cfg, cluster.graph, train_pairs, steps=120, mesh=meshlib.make_mesh(), log_every=40
-        )
+        state, g, pool, multi_step = train_gnn.shard_for_training_scan(
+            train_gnn.init_state(cfg, cluster.graph), cluster.graph, train_pairs, meshlib.make_mesh(),
+            batch_size=cfg.batch_size, steps_per_call=120)
+        state, (losses, _) = multi_step(state, g, pool, jax.random.PRNGKey(0))
+        losses = np.asarray(losses).tolist()
         assert losses[-1] < losses[0] * 0.5, f"no convergence: {losses}"
 
         # Held-out pairs (same graph, never trained on): GNN must beat the
@@ -90,8 +96,8 @@ class TestShardedTraining:
 
 def test_scan_training_converges_and_matches_semantics():
     """Device-resident scan path (shard_for_training_scan): sampling inside
-    lax.scan over the on-device pool must converge like the per-step path
-    and keep params sharded over the model axis."""
+    lax.scan over the on-device pool must converge over several calls and
+    keep params sharded over the model axis."""
     cluster = synthetic.make_cluster(num_nodes=128, num_neighbors=8, num_pairs=8192, seed=3)
     cfg = train_gnn.GNNTrainConfig(hidden=64, embed_dim=32, num_layers=2, warmup_steps=5)
     mesh = meshlib.make_mesh()
@@ -105,45 +111,74 @@ def test_scan_training_converges_and_matches_semantics():
     losses = []
     for _ in range(8):  # 80 steps in 8 dispatches
         key, sub = jax.random.split(key)
-        state, batch_losses = multi(state, g, pool, sub)
+        state, (batch_losses, _) = multi(state, g, pool, sub)
         losses.extend(np.asarray(batch_losses).tolist())
     assert len(losses) == 80 and all(np.isfinite(v) for v in losses)
     assert np.mean(losses[-10:]) < np.mean(losses[:10]) * 0.5, losses[:3] + losses[-3:]
 
 
-def test_remat_changes_lowered_hlo_at_16k_nodes():
-    """GNNTrainConfig.remat is live (ROADMAP #2 satellite; VERDICT #2): at
-    the 16k-node scaled shape the rematted step's lowered HLO differs from
-    the baseline and carries MORE matmuls — the backward pass re-runs the
-    GNN forward instead of holding the [N, K, H] activations. Lowering only
-    (ShapeDtypeStruct args for the scaled operands), no 16k compile/alloc."""
-    from dragonfly2_tpu.models.features import FEATURE_DIM
-    from dragonfly2_tpu.models.graphsage import TopoGraph
-    from dragonfly2_tpu.trainer.synthetic import EDGE_FEATURE_DIM
+@pytest.fixture(scope="module")
+def served_runs():
+    """Two short `train_async` runs, one with a telemetry sink and one
+    without: {with sink?: (losses, lowered text of the scan step)}, and the sink."""
+    from dragonfly2_tpu.trainer.metrics import TrainRunTelemetry
 
-    cfg = train_gnn.GNNTrainConfig(hidden=32, embed_dim=16, num_layers=2)
-    # params/opt-state shapes are node-count independent: init on a tiny
-    # graph, lower against the abstract 16k-node operands
-    tiny = synthetic.make_cluster(num_nodes=32, num_neighbors=4, num_pairs=64, seed=0)
-    state = train_gnn.init_state(cfg, tiny.graph)
-    N, K, B = 16384, 16, 1024
-    sds = jax.ShapeDtypeStruct
-    g16k = TopoGraph(
-        sds((N, tiny.graph.node_feats.shape[1]), jnp.float32),
-        sds((N, K), jnp.int32),
-        sds((N, K), jnp.float32),
-        sds((N, K, EDGE_FEATURE_DIM), jnp.float32),
-    )
-    batch = PairBatch(
-        sds((B,), jnp.int32), sds((B,), jnp.int32),
-        sds((B, FEATURE_DIM), jnp.float32), sds((B,), jnp.float32),
-    )
-    base = jax.jit(train_gnn.make_train_step(remat=False)).lower(state, g16k, batch).as_text()
-    remat = jax.jit(train_gnn.make_train_step(remat=True)).lower(state, g16k, batch).as_text()
-    assert base != remat, "remat knob did not change the lowered HLO"
-    assert remat.count("dot_general") > base.count("dot_general"), (
-        remat.count("dot_general"), base.count("dot_general"),
-    )
+    cluster = synthetic.make_cluster(num_nodes=64, num_neighbors=4, num_pairs=512, seed=1)
+    cfg = train_gnn.GNNTrainConfig(hidden=32, embed_dim=16, num_layers=2, batch_size=64, warmup_steps=2)
+    built = []
+    build = train_gnn.shard_for_training_scan
+
+    def spy(*args, **kwargs):
+        built.append(build(*args, **kwargs))
+        return built[-1]
+
+    sink = TrainRunTelemetry("gnn")
+    runs = {}
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(train_gnn, "shard_for_training_scan", spy)
+        for telemetry in (sink, None):
+            _, losses = asyncio.run(train_gnn.train_async(
+                cfg, cluster.graph, cluster.pairs, steps=6, steps_per_call=3, telemetry=telemetry,
+                mesh=meshlib.make_mesh(jax.devices()[:1])))  # one device: a quick compile
+            state, g, pool, multi_step = built.pop()
+            shapes = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding), (state, g, pool))
+            runs[telemetry is not None] = losses, multi_step.lower(*shapes, jax.random.PRNGKey(0)).as_text()
+    return runs, sink
+
+
+def test_the_scan_step_is_one_program_with_and_without_telemetry(served_runs):
+    """Telemetry is a reader of what every call pulls, not a switch of the
+    compiled program: the served run lowers to the same text either way."""
+    runs, _ = served_runs
+    assert runs[True][1] == runs[False][1]
+
+
+def test_train_async_without_telemetry_returns_the_same_losses(served_runs):
+    runs, sink = served_runs
+    (with_sink, _), (without, _) = runs[True], runs[False]
+    assert len(without) == 6 and without == with_sink
+    assert sink.steps == 6 and sink.last_loss == with_sink[-1] and sink.last_grad_norm > 0
+
+
+def test_the_body_and_the_scan_agree():
+    """`make_train_step()` is what `multi_step` scans: jitted alone on the
+    batch a one-step scan samples, it gives that scan's loss and gradient
+    norm (tests/test_gather_vjp.py lowers the body alone)."""
+    cluster = synthetic.make_cluster(num_nodes=64, num_neighbors=4, num_pairs=64, seed=2)
+    cfg = train_gnn.GNNTrainConfig(hidden=32, embed_dim=16, num_layers=2, batch_size=64, warmup_steps=2)
+    unplaced = train_gnn.init_state(cfg, cluster.graph)
+    state, g, pool, multi_step = train_gnn.shard_for_training_scan(
+        unplaced, cluster.graph, cluster.pairs, meshlib.make_mesh(jax.devices()[:1]),
+        batch_size=cfg.batch_size, steps_per_call=1)
+    key = jax.random.PRNGKey(5)
+    # the rows the scan's one step samples: its key is split off `key` as multi_step does
+    idx = np.asarray(jax.random.randint(jax.random.split(key, 1)[0], (cfg.batch_size,), 0, 64))
+    batch = PairBatch(*(jnp.asarray(np.asarray(a)[idx]) for a in cluster.pairs))
+    _, (loss, gnorm) = jax.jit(train_gnn.make_train_step())(unplaced, jax.tree.map(jnp.asarray, cluster.graph), batch)
+    _, (losses, gnorms) = multi_step(state, g, pool, key)
+    np.testing.assert_allclose(float(losses[0]), float(loss), rtol=1e-5)
+    np.testing.assert_allclose(float(gnorms[0]), float(gnorm), rtol=1e-5)
 
 
 def test_mlp_training_learns_bandwidth():

@@ -173,7 +173,7 @@ MUTATOR_METHODS = {
 # Calls that force completion of queued device work (DF013). A D2H
 # materialization (np.asarray / .item() / jax.device_get) is accepted as a
 # sync: the host cannot hold a value the device has not finished computing
-# (bench.py _gnn_train_measured ends its windows this way).
+# (train_gnn.train_async ends every call this way).
 SYNC_ATTRS = {"block_until_ready", "item"}
 SYNC_DOTTED = {
     "jax.block_until_ready", "jax.device_get", "np.asarray", "numpy.asarray",
