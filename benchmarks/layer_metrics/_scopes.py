@@ -1,6 +1,8 @@
 """What the readers of the program's own names share: the device time of the
-scan program's ops by scope (`scope.*`), and the idle gap between two scan
-calls split by the host's `trainer.gnn.call` events (`host.*_ms`)."""
+scan program's ops by scope (`scope.*`), the same with the unnamed copies
+that feed a scope's ops (the gather VJP's two rooflines), and the idle gap
+between two scan calls split by the host's `trainer.gnn.call` events
+(`host.*_ms`)."""
 
 from __future__ import annotations
 
@@ -50,16 +52,23 @@ def step_ops(ctx: dict) -> list[tuple] | None:
         if xplane is None:
             return None
         ops = scope_reduce.device_ops(xplane)
-    starts = [m[2] for m in calls]
-    inside = []
-    for op in scope_reduce.scoped_ops(ops, view.start_ns, view.stop_ns):
-        i = bisect.bisect_right(starts, op[0]) - 1
-        if i >= 0 and op[0] + op[1] <= calls[i][2] + calls[i][3]:
-            inside.append(op)
+    in_a_call = _within(calls)
+    inside = [op for op in scope_reduce.scoped_ops(ops, view.start_ns, view.stop_ns) if in_a_call(op[0], op[0] + op[1])]
     named = sum(op[1] for op in inside if op[2] is not None)
     if 2 * named >= sum(m[3] for m in calls):
         ctx["step_ops"] = inside
     return ctx["step_ops"]
+
+
+def _within(calls: list):
+    """The test whether [start, stop] lies inside one of the executions `calls` (sorted)."""
+    starts = [m[2] for m in calls]
+
+    def inside(start: int, stop: int) -> bool:
+        i = bisect.bisect_right(starts, start) - 1
+        return i >= 0 and stop <= calls[i][2] + calls[i][3]
+
+    return inside
 
 
 def scope_ms(ctx: dict, select) -> float | None:
@@ -69,6 +78,35 @@ def scope_ms(ctx: dict, select) -> float | None:
     if ops is None:
         return None
     return sum(op[1] for op in ops if select(op[2], op[3])) / steps_in_window(ctx) / 1e6
+
+
+def fed_ms(ctx: dict, select) -> float | None:
+    """Device milliseconds a step of the ops WITHOUT a name whose result only
+    ops for which `select(scope, backward)` holds read
+    (`scope_reduce.scopes_by_consumer`): the waits on the asynchronous copies
+    that feed them. The HLO text that names the operands comes from the
+    trace's first device plane, as the ops do."""
+    ops = step_ops(ctx)
+    if ops is None:
+        return None
+    in_a_call = _within(scan_calls(ctx))
+    scoped = {op[4]: (op[2], op[3]) for op in ops if op[2] is not None}
+    # every op of the scan program's executions, those of no duration too: a slice's `-done` is read by a
+    # `custom-call` that assembles buffers in no time, and that by the kernel
+    texts = {op[0]: op[1] for op in ctx["view"].devices[0]["ops"] if in_a_call(op[2], op[2] + op[3])}
+    fed = {name for name, found in scope_reduce.scopes_by_consumer(texts, scoped).items() if select(*found)}
+    return sum(op[1] for op in ops if op[4] in fed) / steps_in_window(ctx) / 1e6
+
+
+def gather_vjp_ms(ctx: dict) -> float | None:
+    """Device milliseconds a step that the gather's VJP takes, whatever
+    implements it: the backward ops under the program's `gather` scope
+    (`scope.gather_bwd_ms`) and the unnamed copies that only they read."""
+    def select(scope, backward):
+        return scope == "gather" and backward
+
+    named = scope_ms(ctx, select)
+    return None if not named else named + fed_ms(ctx, select)
 
 
 def gap_parts(ctx: dict) -> dict | None:

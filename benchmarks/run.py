@@ -165,8 +165,13 @@ def measure(args, cell: dict, work: Path) -> dict:
              "--gnn-steps", str(gnn_steps), "--mlp-steps", str(mlp_steps), *config["server_flags"]]
     env = {}
     if args.trace:
-        # the program's own spans, every one of them, for the idle gaps' names
-        env = {"DRAGONFLY_TRACE_FILE": str(work / "spans.jsonl"), "DRAGONFLY_TRACE_SAMPLE": "1"}
+        # the program's own spans, every one of them, for the idle gaps' names;
+        # and one malloc arena: `jax.profiler.stop_trace()` runs in the child's
+        # control thread, and glibc gives a thread new to the allocator an arena
+        # of its own, in which collecting the trace takes four times as long
+        # (PERF.md, PR 32). An untraced run's environment is left as it is
+        env = {"DRAGONFLY_TRACE_FILE": str(work / "spans.jsonl"), "DRAGONFLY_TRACE_SAMPLE": "1",
+               "MALLOC_ARENA_MAX": "1"}
     trainer = TrainerProcess(REPO, flags, work / "trainer.log",
                              launcher=Path(args.launcher) if args.launcher else None, env=env)
     try:
@@ -322,7 +327,12 @@ def one_run(args, cell: dict, work: Path) -> dict:
         result["metrics"] = {}
     if breakdown is not None:
         result["breakdown"] = breakdown
-    result.update(setup_split=out["setup_split"], detail=detail, after_window=post.get("timing", {}))
+    after_window = dict(post.get("timing", {}))
+    trace = window.get("trace") or {}
+    if "stop_s" in trace:
+        # what the trainer's child took to answer `trace_stop`, and what it was given
+        after_window.update(trace_stop_s=trace["stop_s"], trace_stop_limit_s=trace["stop_limit_s"])
+    result.update(setup_split=out["setup_split"], detail=detail, after_window=after_window)
     if args.control:
         # the control and each planted fault, put in the program's place, go
         # through the same judge against the same limits

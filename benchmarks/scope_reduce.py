@@ -16,6 +16,14 @@ needs (tsl/profiler/protobuf/xplane.proto), with the standard library alone:
 run.py's process may run it, and never imports jax. A trace of a program
 without scopes gives ops whose scope is None, a trace without the stat gives
 the same: the readers then find nothing to read.
+
+Ops the compiler adds without metadata have no `op_name` and so no scope:
+among them the asynchronous copies into and out of fast memory (`copy-start`
+/ `copy-done`, `slice-start` / `slice-done`), whose `-done` lasts as long as
+the device waits for the transfer. An op's HLO text names its operands, so
+such an op can be given the scope its result is read under
+(`scopes_by_consumer`); the two rooflines of the gather's VJP count it, the
+`scope.*_ms` metrics do not.
 """
 
 from __future__ import annotations
@@ -30,6 +38,9 @@ VOCABULARY = json.loads((Path(__file__).resolve().parent / "scopes.json").read_t
 # `transpose(jvp(loss))` -> `loss`: JAX wraps the outermost name of a
 # differentiated function in the transformation's own name
 _WRAPPED = re.compile(r"(?:[\w.-]+\()*([^()]*)\)*")
+# an operand of an HLO instruction: `%name` after a space or a bracket, not
+# the computation an attribute names (`calls=%fused_computation.5`)
+_OPERAND = re.compile(r"(?<![=\w])%([\w.-]+)")
 
 
 def classify(op_name: str | None) -> tuple[str | None, bool]:
@@ -135,4 +146,38 @@ def scoped_ops(ops: list[list], start_ns: int, stop_ns: int) -> list[tuple]:
         a, b = max(start, start_ns), min(start + duration, stop_ns)
         if b > a:
             out.append((a, b - a, *classify(op_name), name))
+    return out
+
+
+def operands_of(text: str) -> list[str]:
+    """Names of the instructions that an HLO instruction's text reads."""
+    return _OPERAND.findall(text.partition(" = ")[2])
+
+
+def scopes_by_consumer(texts: dict[str, str], scoped: dict[str, tuple]) -> dict[str, tuple]:
+    """(scope, backward) for the ops of `texts` (op name -> HLO text) that
+    have none in `scoped` (op name -> (scope, backward)): the one that every
+    named op reading the op's result has, through other unnamed ops (a
+    `copy-start` is read by its `copy-done`, that by a fusion). An op whose
+    result nothing named reads, or ops of several scopes do, is left out."""
+    readers: dict[str, list[str]] = {}
+    for name, text in texts.items():
+        for operand in operands_of(text):
+            readers.setdefault(operand, []).append(name)
+
+    def named_readers(name: str, seen: set) -> set:
+        found = set()
+        for reader in readers.get(name, ()):
+            if reader in seen:
+                continue
+            seen.add(reader)
+            found |= {scoped[reader]} if reader in scoped else named_readers(reader, seen)
+        return found
+
+    out = {}
+    for name in texts:
+        if name not in scoped:
+            found = named_readers(name, {name})
+            if len(found) == 1:
+                out[name] = found.pop()
     return out

@@ -74,6 +74,22 @@ TINY_CONFIG = {
 }
 
 
+BODY = "jit(multi_step)/while/body/closed_call/"
+FORWARD = BODY + "jvp(TopoScorer)/encoder/SAGELayer_0/"
+BACKWARD = BODY + "transpose(jvp(TopoScorer))/encoder/SAGELayer_0/"
+# the op_name of each of a call's seven ops, in the order `four_plane_trace` lists them: on a `data` mesh the
+# all-gather of `u` and the reduce-scatter of its cotangent carry the `gather` scope (PERF.md, PR 27)
+OP_NAMES = [FORWARD + "gather/jit(_take)/gather", FORWARD + "dense/msg_self/dot_general", FORWARD + "gather/jit(_take)/gather",
+            FORWARD + "gather/jit(_take)/gather", BACKWARD + "gather/jit(_take)/scatter-add",
+            BACKWARD + "gather/jit(_take)/scatter-add", BODY + "optimizer/psum"]
+# the per-shard kernel of PR 31's program in place of the scatter-add (the shapes of its traced four-chip run,
+# shrunk): a reorder gather of a cotangent slice, the wait on a table's copy into fast memory, the kernel
+REORDER = "%fusion.772 = bf16[128,32]{1,0} fusion(bf16[128,32]{1,0} %bitcast.438, s32[128]{0:S(1)} %copy-done.75), kind=kCustom, calls=%gather"
+TABLE_DONE = "%copy-done.187 = s32[24]{0:S(1)} copy-done((s32[24]{0:S(1)}, s32[24]{0}, u32[]{:S(2)}) %copy-start.187)"
+SHARD_KERNEL = ("%sum_by_destination.1 = bf16[64,32]{1,0} custom-call(s32[]{:T(128)} %bitcast.396, s32[24]{0:S(1)} %copy-done.187, "
+                "s32[24]{0:S(1)} %copy-done.188, bf16[256,32]{1,0} %fusion.772), custom_call_target=\"tpu_custom_call\"")
+
+
 def four_plane_trace():
     """Three executions of `jit_multi_step`, 100 us each, 20 us apart, on four
     planes. In each: an asynchronous all-gather, its start [0, 1) and its done
@@ -106,6 +122,7 @@ def test_mesh_readers_on_a_hand_built_four_plane_trace():
     placement = {"graph": {"bytes": 4_000, "per_device_bytes": [1_000, 1_000, 1_000, 1_000]}}
     ctx = {"config": TINY_CONFIG, "view": trace_reduce.TraceView(compact, a, b), "peaks": PEAKS,
            "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 4},
+           "device_ops": named(compact, OP_NAMES),
            "runs": [{"models": {"gnn": {"placement": placement}}}]}
     layer_dir = BENCH / "layer_metrics"
 
@@ -127,12 +144,14 @@ def test_mesh_readers_on_a_hand_built_four_plane_trace():
     assert read("mesh.graph_shard_pct") == pytest.approx(25.0)
     # busy: 81 us on plane 0 (1 + 8 + 1 + 40 + 6 + 20 + 5), 84 us on plane 3
     assert read("mesh.plane_skew_pct") == pytest.approx(100 * (84 / 81 - 1))
-    # a row shard's kernels, first plane, against a chip's quarter of the whole graph's floor: the scatter-add
-    # (result [N, H], the shard's 256 row numbers and [256, H] cotangent) 6 us a call; the ops on the shard's
-    # [16, 16, 32] that are not it (the forward gather) 40 us a call. One chip's readers match neither shape
+    # a row shard's kernels, first plane, against a chip's quarter of the whole graph's floor. The gather's VJP
+    # by the program's scope: the scatter-add (result [N, H], the shard's 256 row numbers and [256, H]
+    # cotangent) 6 us a call and the reduce-scatter of the partial sums 20 us. The ops on the shard's
+    # [16, 16, 32] that are not the scatter-add's shape (the forward gather) 40 us a call. One chip's readers
+    # read nothing on four: their floors are one chip's, and `msg_roofline` matches no whole-N shape
     scatter = flops.scatter_floor(TINY_CONFIG, PEAKS)
     assert scatter["bytes"] == 1024 * 32 * 2 + 1024 * 4 + 64 * 32 * 2
-    assert read("mesh.scatter_roofline") == pytest.approx(100 * scatter["seconds"] / 4 * 10 / 6e-6)
+    assert read("mesh.scatter_roofline") == pytest.approx(100 * scatter["seconds"] / 4 * 10 / 26e-6)
     assert read("mesh.msg_roofline") == pytest.approx(
         100 * flops.message_floor(TINY_CONFIG, PEAKS)["seconds"] / 4 * 10 / 40e-6)
     assert read("scatter_roofline") is None and read("msg_roofline") is None
@@ -150,14 +169,69 @@ def test_mesh_readers_on_a_hand_built_four_plane_trace():
            "view": trace_reduce.TraceView({**compact, "devices": compact["devices"][:1]}, a, b)}
     assert harness.read_layer_metric(layer_dir, "mesh.plane_skew_pct", one) is None
     assert harness.read_layer_metric(layer_dir, "mesh.collective_roofline", one) is None
-    # kernels over `model` (the parent's default mesh on four chips) leave [N, K, H/4]: no shard of rows to read
+    # kernels over `model` (the parent's default mesh on four chips) leave [N, K, H/4]: no shard of rows for
+    # `mesh.msg_roofline` to read by shape; the VJP's scope is there whatever the shapes
     for op in (op for d in compact["devices"] for op in d["ops"]):
         op[1] = op[1].replace(",32]", ",8]")
-    assert read("mesh.scatter_roofline") is None and read("mesh.msg_roofline") is None
+    assert read("mesh.msg_roofline") is None
+    assert read("mesh.scatter_roofline") == pytest.approx(100 * scatter["seconds"] / 4 * 10 / 26e-6)
+    # a program that names nothing (no scopes in its op names): nothing to read
+    ctx["device_ops"] = [[op[0], "", op[2], op[3]] for op in ctx["device_ops"]]
+    ctx.pop("step_ops")
+    assert read("mesh.scatter_roofline") is None
     ctx["view"] = None
     assert all(read(name) is None for name in
                ("mesh.collective_ms", "mesh.collective_roofline", "mesh.step_mfu", "mesh.plane_skew_pct",
                 "mesh.scatter_roofline", "mesh.msg_roofline"))
+
+
+def named(compact, op_names):
+    """`scope_reduce.device_ops` of the first plane: each op with its op_name."""
+    ops = compact["devices"][0]["ops"]
+    return [[op[0], op_names[i % len(op_names)], op[2], op[3]] for i, op in enumerate(ops)]
+
+
+def test_mesh_scatter_roofline_follows_the_vjp_through_a_per_shard_kernel():
+    """The four-plane trace with the derived scatter-add [54, 60) replaced by
+    what PR 31's program ran on a shard: a reorder gather [50, 52), the wait
+    on a block table's unnamed copy into fast memory [52, 54), the kernel
+    [54, 60); the reduce-scatter [60, 80) stays. No op has the scatter-add's
+    shapes (the reader by shape found nothing in that program's traced run);
+    by the scope the VJP is 30 us a call, the wait on the copy with it."""
+    import _mesh
+
+    compact = four_plane_trace()
+    for d in compact["devices"]:
+        ops = []
+        for op in d["ops"]:
+            if op[0] != "fusion.775":
+                ops.append(op)
+                continue
+            t = op[2] - 4_000
+            ops += [["fusion.772", REORDER, t, 2_000], ["copy-done.187", TABLE_DONE, t + 2_000, 2_000],
+                    ["sum_by_destination.1", SHARD_KERNEL, t + 4_000, 6_000]]
+        d["ops"] = ops
+    # plane 0 ran its forward gather [10, 50): the reorder gather follows it at once
+    a, b = trace_reduce.window_of(compact, {}, "multi_step", None)
+    view = trace_reduce.TraceView(compact, a, b)
+    assert view.op_seconds(lambda name, shapes: _mesh.is_shard_scatter(TINY_CONFIG, 4, shapes)) == 0
+    kernel = BACKWARD + "gather/jit(sum_by_destination)/pallas_call"
+    op_names = OP_NAMES[:4] + [BACKWARD + "gather/jit(_take)/gather", "", kernel] + OP_NAMES[5:]
+    ctx = {"config": TINY_CONFIG, "view": view, "peaks": PEAKS, "device_ops": named(compact, op_names),
+           "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 4}, "runs": []}
+    layer_dir = BENCH / "layer_metrics"
+    floor = flops.scatter_floor(TINY_CONFIG, PEAKS)["seconds"]
+    assert harness.read_layer_metric(layer_dir, "mesh.scatter_roofline", ctx) == pytest.approx(100 * floor / 4 * 10 / 30e-6)
+    assert harness.read_layer_metric(layer_dir, "scope.gather_bwd_ms", ctx) == pytest.approx(28e-6 * 1e3 / 10)
+    # the same wait on a copy that a `message` op reads is none of the VJP's
+    for op in compact["devices"][0]["ops"]:
+        if op[0] == "sum_by_destination.1":
+            op[1] = op[1].replace("%copy-done.187, ", "")
+        if op[0] == "fusion.829":
+            op[1] = op[1].replace("%h)", "%h, s32[24]{0:S(1)} %copy-done.187)")
+    ctx = {**ctx, "view": trace_reduce.TraceView(compact, a, b)}
+    ctx.pop("step_ops")
+    assert harness.read_layer_metric(layer_dir, "mesh.scatter_roofline", ctx) == pytest.approx(100 * floor / 4 * 10 / 28e-6)
 
 
 def test_benchmark_json_lists_the_new_cell_where_its_readers_read():
@@ -203,3 +277,7 @@ def test_rehearsal_of_the_cell_on_four_virtual_devices(tmp_path):
     # on the CPU no device plane is traced and no device metric is read; what the host's counters give is there
     assert "compile.in_window" in result["rehearsal"]["read"]
     assert not any(name.startswith("mesh.") for name in result["rehearsal"]["read"])
+    # a traced run's line says what the trainer's child took to answer `trace_stop`, beside the limit it was given
+    after = result["after_window"]
+    assert 0 < after["trace_stop_s"] < after["trace_stop_limit_s"] and after["trace_stop_limit_s"] >= 120.0
+    assert "trace_reduce_s" in after

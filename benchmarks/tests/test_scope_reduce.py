@@ -1,8 +1,12 @@
 """The readers of the program's own names (the step's scopes, the trainer's
 `trainer.gnn.call` events): the pass over an xplane built by hand, a trace
-small enough to work out by hand, the trace recorded on the chip with the
-scopes in it (data/recorded_scopes.json.gz, cut from a traced run of
-gnn-32k-512.steady), and the older recorded trace, which has neither."""
+small enough to work out by hand, the traces recorded on the chip with the
+scopes in them (data/recorded_scopes.json.gz, PR 25: the gather's VJP a
+scatter-add fusion; data/recorded_sorted_vjp.json.gz, PR 32: reorder gathers
+and the kernel `sum_by_destination`; both cut from traced runs of
+gnn-32k-512.steady), and the older recorded trace, which has neither. The
+gather VJP's roofline (`scatter_roofline`) reads the `gather` backward scope
+with the unnamed copies that feed it, so it reads in both programs."""
 
 import gzip
 import json
@@ -23,6 +27,7 @@ import trace_reduce  # noqa: E402
 from test_trace_reduce import DENSE, GATHER, SCATTER, TINY_CONFIG  # noqa: E402
 
 LAYER_DIR = BENCH / "layer_metrics"
+PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 SCOPE_METRICS = ["scope.gather_bwd_ms", "scope.message_ms", "scope.dense_ms", "scope.optimizer_ms"]
 HOST_PARTS = ["host.dispatch_ms", "host.pull_tail_ms", "host.loop_turn_ms"]
 NEW_METRICS = SCOPE_METRICS + ["scope.unattributed_pct"] + HOST_PARTS + ["host.stall_ms"]
@@ -118,8 +123,8 @@ def context(compact, names, config, **extra):
     ops = compact["devices"][0]["ops"]
     return {"config": config, "view": trace_reduce.TraceView(compact, a, b),
             "device_ops": names and [[op[0], name, op[2], op[3]] for op, name in zip(ops, names)],
-            "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
-            "device": {"platform": "tpu", "memory_peak_bytes": 7_000_000_000},
+            "peaks": PEAKS,
+            "device": {"platform": "tpu", "count": 1, "memory_peak_bytes": 7_000_000_000},
             "window": {"window_start": 10.0, "window_stop": 20.0, "kind": "scan_calls"},
             "runs": [], "compiles": [], **extra}
 
@@ -144,6 +149,87 @@ def test_readers_on_the_hand_trace():
     assert (got["host.dispatch_ms"], got["host.pull_tail_ms"], got["host.loop_turn_ms"]) \
         == pytest.approx((0.008, 0.005, 0.007))
     assert got["host.stall_ms"] == 12.5
+    # the gather's VJP (here a scatter-add fusion, found by its scope) against its floor: 50 us a call of ten steps
+    floor = flops.scatter_floor(TINY_CONFIG, PEAKS)["seconds"]
+    ctx = context(compact, names, TINY_CONFIG)
+    assert read_all(ctx, ["scatter_roofline"])["scatter_roofline"] == pytest.approx(100 * floor * 10 / 50e-6)
+    # one chip's floor: nothing to read on a mesh, where `mesh.scatter_roofline` takes a chip's share of it
+    ctx["device"]["count"] = 4
+    got = read_all(ctx, ["scatter_roofline", "mesh.scatter_roofline"])
+    assert got["scatter_roofline"] is None
+    assert got["mesh.scatter_roofline"] == pytest.approx(100 * floor / 4 * 10 / 50e-6)
+
+
+# ---- the unnamed copies that feed a scope's ops ----
+
+COPY_START = "%copy-start.7 = (bf16[1024,32]{1,0:S(1)}, bf16[1024,32]{1,0}, u32[]{:S(2)}) copy-start(bf16[1024,32]{1,0} %c)"
+COPY_DONE = "%copy-done.7 = bf16[1024,32]{1,0:S(1)} copy-done((bf16[1024,32]{1,0:S(1)}, bf16[1024,32]{1,0}, u32[]{:S(2)}) %copy-start.7)"
+SLICE_DONE = "%slice-done.8 = bf16[64,32]{1,0:S(1)} async-done(((bf16[64,32]{1,0}), bf16[64,32]{1,0:S(1)}, s32[]{:S(2)}) %slice-start.8)"
+KERNEL = ("%sum_by_destination = bf16[64,32]{1,0} custom-call(s32[]{:T(128)} %bitcast.3, bf16[1024,32]{1,0:S(1)} %copy-done.7), "
+          "custom_call_target=\"tpu_custom_call\"")
+READS_SLICE = "%fusion.2 = bf16[1024,32]{1,0} fusion(bf16[64,32]{1,0:S(1)} %slice-done.8, s32[1024]{0} %i), kind=kLoop, calls=%fused_computation.2"
+KERNEL_NAME = BODY + "transpose(jvp(TopoScorer))/encoder/SAGELayer_0/gather/jit(sum_by_destination)/pallas_call"
+
+
+def test_operands_are_the_instructions_read_not_the_computations_called():
+    assert scope_reduce.operands_of(KERNEL) == ["bitcast.3", "copy-done.7"]
+    assert scope_reduce.operands_of(READS_SLICE) == ["slice-done.8", "i"]
+    assert scope_reduce.operands_of(COPY_DONE) == ["copy-start.7"]
+    assert scope_reduce.operands_of("fusion.1") == []
+
+
+def test_an_unnamed_op_takes_the_scope_its_result_is_read_under():
+    texts = {"copy-start.7": COPY_START, "copy-done.7": COPY_DONE, "slice-done.8": SLICE_DONE,
+             "sum_by_destination": KERNEL, "fusion.2": READS_SLICE,
+             "copy-done.9": "%copy-done.9 = bf16[64,32]{1,0} copy-done(%copy-start.9)",
+             "fusion.5": "%fusion.5 = bf16[64,32]{1,0} fusion(bf16[64,32]{1,0} %copy-done.9, bf16[64,32]{1,0} %copy-done.7)",
+             "copy-done.11": "%copy-done.11 = bf16[64,32]{1,0} copy-done(%copy-start.11)"}
+    scoped = {"sum_by_destination": ("gather", True), "fusion.2": ("gather", False), "fusion.5": ("dense", True)}
+    found = scope_reduce.scopes_by_consumer({k: v for k, v in texts.items() if k != "fusion.5"}, scoped)
+    # the start through its done to the kernel; the slice to the forward gather; one that nothing reads is left out
+    assert found == {"copy-start.7": ("gather", True), "copy-done.7": ("gather", True), "slice-done.8": ("gather", False)}
+    # read under two scopes: left with none (and so is its start); what `dense` alone reads goes to `dense`
+    found = scope_reduce.scopes_by_consumer(texts, scoped)
+    assert found == {"slice-done.8": ("gather", False), "copy-done.9": ("dense", True)}
+
+
+def fed_trace():
+    """The hand trace with the VJP a kernel that waits for a copy into fast
+    memory: in each execution a dense op [0, 30), the forward gather's wait on
+    a slice [30, 36) and the gather [36, 60), the copy's start [60, 60), the
+    wait on it [60, 64), the kernel [64, 100)."""
+    ops, names, modules = [], [], []
+    for call in range(3):
+        t = 1_000 + call * 120_000
+        modules.append(["jit_multi_step(123)", "", t, 100_000])
+        ops += [["while.1", "%while.1 = () while()", t, 100_000], ["convolution.3", DENSE, t, 30_000],
+                ["slice-done.8", SLICE_DONE, t + 30_000, 6_000], ["fusion.2", READS_SLICE, t + 36_000, 24_000],
+                ["copy-start.7", COPY_START, t + 60_000, 0], ["copy-done.7", COPY_DONE, t + 60_000, 4_000],
+                ["sum_by_destination", KERNEL, t + 64_000, 36_000]]
+        names += ["jit(multi_step)/while", DENSE_NAME, "", GATHER_NAME, "", "", KERNEL_NAME]
+    return {"devices": [{"plane": "/device:TPU:0", "ops": ops, "modules": modules}], "host": [], "marker_ns": 500}, names
+
+
+def test_a_copy_that_feeds_the_gathers_backward_is_counted_and_one_that_feeds_another_scope_is_not():
+    compact, names = fed_trace()
+    ctx = context(compact, names, TINY_CONFIG)
+    got = read_all(ctx, SCOPE_METRICS + ["scope.unattributed_pct", "scatter_roofline"])
+    # the scopes keep their definition: named ops only, the two waits (10 us of 100) under no name
+    assert got["scope.gather_bwd_ms"] == pytest.approx(0.0036) and got["scope.message_ms"] == pytest.approx(0.0024)
+    assert got["scope.unattributed_pct"] == pytest.approx(10.0)
+    # the VJP's roofline counts the kernel and the wait on the copy it reads, 40 us a call; not the slice's
+    floor = flops.scatter_floor(TINY_CONFIG, PEAKS)["seconds"]
+    assert got["scatter_roofline"] == pytest.approx(100 * floor * 10 / 40e-6)
+    import _scopes
+
+    assert _scopes.fed_ms(ctx, lambda scope, backward: scope == "gather" and not backward) == pytest.approx(0.0006)
+    assert _scopes.fed_ms(ctx, lambda scope, backward: scope == "message") == 0
+    # the same kernel reading its input in place: the scope alone
+    compact, names = fed_trace()
+    for op in compact["devices"][0]["ops"]:
+        op[1] = op[1].replace("%copy-done.7)", "%cotangent)")
+    got = read_all(context(compact, names, TINY_CONFIG), ["scatter_roofline"])
+    assert got["scatter_roofline"] == pytest.approx(100 * floor * 10 / 36e-6)
 
 
 def test_readers_find_nothing_without_names_or_a_trace():
@@ -196,6 +282,58 @@ def test_recorded_scopes_cover_the_step(recorded):
     by_shape_ms = view.op_seconds(lambda name, shapes: flops.is_scatter(CONFIG_32K, shapes)) * 1e3 / steps
     assert got["scope.gather_bwd_ms"] == pytest.approx(by_shape_ms, rel=0.03)
     assert got["scope.gather_bwd_ms"] > got["scope.message_ms"] > got["scope.dense_ms"] > got["scope.optimizer_ms"] > 0
+    # and the VJP's roofline, by the scope: the floor over that time (no unnamed copy feeds a scatter-add)
+    floor_ms = flops.scatter_floor(CONFIG_32K, PEAKS)["seconds"] * 1e3
+    assert floor_ms == pytest.approx(2.0970, rel=1e-3)
+    roofline = read_all(recorded, ["scatter_roofline"])["scatter_roofline"]
+    assert roofline == pytest.approx(100 * floor_ms / got["scope.gather_bwd_ms"]) == pytest.approx(6.0, abs=0.1)
+
+
+@pytest.fixture(scope="module")
+def recorded_sorted():
+    with gzip.open(TESTS / "data" / "recorded_sorted_vjp.json.gz", "rt") as f:
+        trace = json.load(f)
+    return context(trace, trace["op_names"], CONFIG_32K)
+
+
+def test_recorded_sorted_vjp_reads_by_its_scope_where_no_shape_matches(recorded_sorted):
+    """The program since PR 26: the VJP is reorder gathers and the kernel. No
+    op has the scatter-add's shapes, so a reader by shape is silent; by the
+    scope the roofline reads, and the five parts still add up to the step."""
+    view = recorded_sorted["view"]
+    assert view.op_seconds(lambda name, shapes: flops.is_scatter(CONFIG_32K, shapes)) == 0
+    names = SCOPE_METRICS + ["scope.unattributed_pct", "step.device_ms", "scatter_roofline", "device.idle_pct.steady"]
+    got = read_all(recorded_sorted, names)
+    assert got["step.device_ms"] == pytest.approx(43.04, abs=0.01)
+    assert sum(got[m] for m in SCOPE_METRICS) + got["scope.unattributed_pct"] / 100 * got["step.device_ms"] \
+        == pytest.approx(got["step.device_ms"])
+    kernels = [op for op in recorded_sorted["step_ops"] if op[4].startswith("sum_by_destination")]
+    assert len(kernels) == 2 * 10 * 3 and {(op[2], op[3]) for op in kernels} == {("gather", True)}
+    import _scopes
+
+    fed = _scopes.fed_ms(recorded_sorted, lambda scope, backward: scope == "gather" and backward)
+    floor_ms = flops.scatter_floor(CONFIG_32K, PEAKS)["seconds"] * 1e3
+    assert got["scatter_roofline"] == pytest.approx(100 * floor_ms / (got["scope.gather_bwd_ms"] + fed))
+    assert got["scatter_roofline"] == pytest.approx(12.43, abs=0.05) and 0 <= fed < 0.05
+    # the unnamed copies this program waits on feed `dense`'s backward (the kernel's sums copied out of fast memory)
+    assert _scopes.fed_ms(recorded_sorted, lambda scope, backward: scope == "dense" and backward) == pytest.approx(0.358, abs=0.01)
+
+
+def test_recorded_ops_of_no_duration_hide_no_kernel(recorded_sorted):
+    """Until PR 32 `leaf_ops` took an op for a container when the profiler had
+    stamped an op of no duration with its start: in this trace 3.3% of the
+    step (kernels and gathers of the `gather` scope, mostly) read as time in
+    no op, and as idle time of the device."""
+    ops = recorded_sorted["view"].compact["devices"][0]["ops"]
+    leaves = {id(op) for op in trace_reduce.leaf_ops(ops)}
+    assert {op[0] for op in ops if id(op) not in leaves} == {"while.8"}
+    stamped = {op[2] for op in ops if op[3] == 0}
+    hidden = [op for op in ops if op[3] > 0 and op[2] in stamped and not op[0].startswith("while")]
+    assert len(hidden) > 150 and sum(op[3] for op in hidden) > 50e6
+    got = read_all(recorded_sorted, ["scope.unattributed_pct", "device.idle_pct.steady", "scope.gather_bwd_ms"])
+    assert got["scope.unattributed_pct"] == pytest.approx(1.29, abs=0.02)
+    assert got["device.idle_pct.steady"] == pytest.approx(1.42, abs=0.02)
+    assert got["scope.gather_bwd_ms"] == pytest.approx(16.87, abs=0.01)
 
 
 def test_recorded_host_parts_sum_to_the_gap(recorded):

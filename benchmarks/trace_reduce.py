@@ -95,10 +95,15 @@ def read_spans(path: Path | None) -> list[dict]:
 def leaf_ops(ops: list[list]) -> list[list]:
     """The ops that hold no other op: a `while` (the scan), a conditional or
     a call spans the ops of its body on the same line, and would count their
-    time twice."""
+    time twice. An op of no duration is held by nothing and holds nothing: the
+    TPU's profiler stamps a `custom-call` that only assembles buffers with the
+    start of the op that follows it, often to the same nanosecond, and that op
+    is no container for it: dropped, its time would read as time in no op."""
     ordered = sorted(ops, key=lambda op: (op[2], -op[3]))
-    return [op for op, nxt in zip(ordered, ordered[1:] + [None])
-            if nxt is None or not (nxt[2] < op[2] + op[3] and nxt[2] + nxt[3] <= op[2] + op[3] and op[3] > nxt[3])]
+    timed = [op for op in ordered if op[3] > 0]
+    holders = {id(op) for op, nxt in zip(timed, timed[1:])
+               if nxt[2] < op[2] + op[3] and nxt[2] + nxt[3] <= op[2] + op[3] and op[3] > nxt[3]}
+    return [op for op in ordered if id(op) not in holders]
 
 
 def _union_ns(intervals: list[tuple[int, int]]) -> tuple[int, list[tuple[int, int]]]:

@@ -30,6 +30,17 @@ import time
 from pathlib import Path
 
 POLL_S = 0.02
+# `trace_stop` may take what is left of the run's budget, less what the end of
+# the training run, the export and post_child.py need after it, and never less
+# than the floor: the profiler's collection grows with the trace (ops a step x
+# steps x chips), so its limit is the run's and no flat one
+TRACE_STOP_RESERVE_S = 240.0
+TRACE_STOP_FLOOR_S = 120.0
+
+
+def trace_stop_limit_s(deadline: float, now: float) -> float:
+    """Seconds the trainer's child may take to answer `trace_stop`."""
+    return max(TRACE_STOP_FLOOR_S, deadline - now - TRACE_STOP_RESERVE_S)
 
 
 def server_step_flags(config: dict, traffic: dict, seconds: float) -> tuple[int, int]:
@@ -89,7 +100,10 @@ class Driver:
         self.trace = {"started_monotonic": time.monotonic(), **out}
 
     def _trace_stop(self) -> None:
-        self.trace.update(self.trainer.ctl("trace_stop"))
+        t = time.monotonic()
+        limit = trace_stop_limit_s(self.deadline, t)
+        self.trace.update(self.trainer.ctl("trace_stop", timeout=limit))
+        self.trace.update(stop_s=time.monotonic() - t, stop_limit_s=limit)
 
     # ---- windows ----
 
