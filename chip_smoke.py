@@ -350,11 +350,11 @@ def _child_device(tmp: str, stage_mib: int) -> dict:
         del staged
     out["staged_bytes"] = int(sum(t.nbytes for t in tensors.values()))
     out["mismatched"] = mismatched
-    out["pallas"] = _pallas_check(out["platform"] == "tpu")
+    out["pallas"] = _pallas_check(out["platform"] == "tpu", n_dev)
     ok = not mismatched and all(r["ok"] for r in out["pallas"].values())
 
     if n_dev > 1:
-        out["data_parallel"] = _data_parallel_run(n_dev)
+        out["data_parallel"] = _data_parallel_run(n_dev, out["platform"] == "tpu")
         ok = ok and out["data_parallel"]["ok"]
     return {"ok": ok, **out}
 
@@ -364,12 +364,17 @@ def _child_device(tmp: str, stage_mib: int) -> dict:
 KERNEL_SHAPES = ((1024, 256, False), (4096, 512, True))
 
 
-def _pallas_check(compiled: bool) -> dict:
+def _pallas_check(compiled: bool, n_dev: int = 1) -> dict:
     """`sum_by_destination`, the kernel the training step runs (the gather's
-    VJP on one chip), over `edges_by_destination` of a seeded table, against
-    `jnp.take`'s own VJP in float32, at KERNEL_SHAPES. On the chip Mosaic
-    compiles the kernel; anywhere else only the interpreter exists."""
+    VJP), over `edges_by_destination` of a seeded table, against `jnp.take`'s
+    own VJP in float32, at KERNEL_SHAPES; on several devices also the last
+    shape through `neighbor_gather` on the program's own mesh, a table per row
+    shard (`<shape>/<devices>`). On the chip Mosaic compiles the kernel;
+    anywhere else only an interpreter exists (Pallas's HLO interpreter, which
+    any truthy value but the TPU interpreter's parameters selects: plain XLA
+    ops, so it also runs under a mesh's `shard_map`)."""
     import contextlib
+    from functools import partial
 
     import jax
     import jax.numpy as jnp
@@ -377,33 +382,70 @@ def _pallas_check(compiled: bool) -> dict:
     from jax.experimental.pallas import tpu as pltpu
 
     from dragonfly2_tpu.ops import neighbor_agg_pallas as pk
+    from dragonfly2_tpu.ops.neighbor_agg import neighbor_gather
+    from dragonfly2_tpu.parallel import mesh as meshlib
 
-    out = {}
-    for n, width, hub in KERNEL_SHAPES:
+    def take_vjp(nbr, g):
+        _, vjp = jax.vjp(lambda h: jnp.take(h, nbr, axis=0), jnp.zeros((nbr.shape[0], g.shape[-1]), jnp.float32))
+        return vjp(g.astype(jnp.float32))[0]
+
+    def relative_err(got, want):
+        # float32 sums rounded once to bfloat16 are within 2^-8 of the largest
+        # (a chip's; the chips' sums add up in bfloat16); a row summed into the
+        # wrong place is O(1)
+        return float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)) / jnp.max(jnp.abs(want)))
+
+    def seeded(n, width, hub):
         rng = np.random.default_rng(n)
         nbr = rng.integers(0, n, (n, 16)).astype(np.int32)
         if hub:
             nbr[rng.random(nbr.shape) < 0.25] = 3
-        g = jnp.asarray(rng.standard_normal((n, 16, width)), jnp.bfloat16)
+        return rng, nbr, jnp.asarray(rng.standard_normal((n, 16, width)), jnp.bfloat16)
+
+    interpreted = contextlib.nullcontext if compiled else partial(pltpu.force_tpu_interpret_mode, True)
+    out = {}
+    for n, width, hub in KERNEL_SHAPES:
+        _, nbr, g = seeded(n, width, hub)
         table = pk.edges_by_destination(nbr, width, g.dtype)
-        with contextlib.nullcontext() if compiled else pltpu.force_tpu_interpret_mode():
+        with interpreted():
             got = pk.sum_by_destination(jax.tree.map(jnp.asarray, table), g)
-        _, vjp = jax.vjp(lambda h: jnp.take(h, nbr, axis=0), jnp.zeros((n, width), jnp.float32))
-        want = vjp(g.astype(jnp.float32))[0]
-        # float32 sums rounded once to bfloat16 are within 2^-8 of the largest;
-        # a row summed into the wrong place is O(1)
-        err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)) / jnp.max(jnp.abs(want)))
+        err = relative_err(got, take_vjp(nbr, g))
         out[f"{n}x16x{width}"] = {
             "ok": err <= 2.0 ** -7, "compiled": compiled,
             "blocks": int(table.perm.shape[0]), "max_err": err,
         }
+    if n_dev > 1:  # the last shape again, rows over `data`: what a four-chip host's step runs
+        n, width, hub = KERNEL_SHAPES[-1]
+        rng, nbr, g = seeded(n, width, hub)
+        mesh, _ = meshlib.mesh_for_run()
+        tables, reason = pk.gather_vjp_tables(nbr, width, g.dtype, mesh)
+        key = f"{n}x16x{width}/{n_dev}"
+        if tables is None:
+            out[key] = {"ok": False, "compiled": compiled, "reason": reason}
+            return out
+        rows = meshlib.batch_sharding(mesh)
+        h = jnp.asarray(rng.standard_normal((n, width)), jnp.bfloat16)
+        states, slots, tables, cotangent = jax.device_put(
+            (h, jnp.asarray(nbr), tables, g), (rows, rows, jax.tree.map(lambda _: rows, tables), rows))
+        with interpreted():
+            gathered, vjp = jax.vjp(lambda x: neighbor_gather(x, slots, tables), states)
+            got = vjp(cotangent)[0]
+        report = pk.gather_vjp_report(tables, nbr.shape, width, g.dtype, mesh)
+        err = relative_err(got, take_vjp(nbr, g))
+        exact = bool(jnp.all(gathered == jnp.take(h, nbr, axis=0)))
+        out[key] = {
+            "ok": exact and err <= 2.0 ** -6 and report["shards"] == n_dev, "compiled": compiled,
+            "shards": report["shards"], "blocks": report["blocks"], "live_windows": report["live_windows"],
+            "forward_exact": exact, "max_err": err,
+        }
     return out
 
 
-def _data_parallel_run(n_dev: int) -> dict:
+def _data_parallel_run(n_dev: int, on_tpu: bool) -> dict:
     """train_async on the mesh the program decides for itself (no mesh given:
     `parallel.mesh.mesh_for_run`, `{data: n}`): node rows and the pair batch
-    must span the devices, a 1/n share each."""
+    must span the devices, a 1/n share each, and on TPU chips the gather's VJP
+    must be the kernel's, over a sorted table a row shard."""
     import numpy as np
 
     from dragonfly2_tpu.trainer import synthetic, train_gnn
@@ -416,7 +458,7 @@ def _data_parallel_run(n_dev: int) -> dict:
         cfg, cluster.graph, cluster.pairs, steps=20, telemetry=tel,
     ))
     p = tel.placement
-    graph, rows = p["graph"], p["batch_rows_per_device"]
+    graph, rows, vjp = p["graph"], p["batch_rows_per_device"], p["gather_vjp"]
     ok = (
         all(np.isfinite(losses))
         and p["decision"] == {"rule": "rows_over_data", "devices": n_dev}
@@ -424,6 +466,7 @@ def _data_parallel_run(n_dev: int) -> dict:
         and len(graph["per_device_bytes"]) == n_dev
         and all(b * n_dev == graph["bytes"] for b in graph["per_device_bytes"])
         and rows * n_dev == cfg.batch_size
+        and (not on_tpu or (vjp["path"] == "sorted_kernel" and vjp["shards"] == n_dev))
     )
     return {"ok": bool(ok), "placement": p, "steps": len(losses), "final_loss": losses[-1]}
 

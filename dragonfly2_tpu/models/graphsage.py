@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from dragonfly2_tpu.ops.neighbor_agg import masked_mean, neighbor_gather
 
 if TYPE_CHECKING:
-    from dragonfly2_tpu.ops.neighbor_agg_pallas import EdgesByDst
+    from dragonfly2_tpu.ops.neighbor_agg_pallas import EdgesByDst, EdgesByShard
 
 
 class TopoGraph(NamedTuple):
@@ -33,16 +33,17 @@ class TopoGraph(NamedTuple):
     mask:       [N, K] float32 1.0 for real edges
     edge_feats: [N, K, E] float32 probe stats (rtt mean/std/min, probe count)
     by_dst:     `neighbors`' slots sorted by destination, for the gather's VJP:
-                derived data of a training run placed on one TPU chip, which
-                only trainer.train_gnn's placement fills. The published graph
-                is the four arrays; without the table the VJP is `jnp.take`'s
+                derived data of a training run placed on TPU chips (one table,
+                or one a row shard of the mesh's `data` axis), which only
+                trainer.train_gnn's placement fills. The published graph is
+                the four arrays; without the table the VJP is `jnp.take`'s
     """
 
     node_feats: jnp.ndarray
     neighbors: jnp.ndarray
     mask: jnp.ndarray
     edge_feats: jnp.ndarray
-    by_dst: EdgesByDst | None = None
+    by_dst: EdgesByDst | EdgesByShard | None = None
 
 
 # The scope vocabulary of the training step: every device op of the step
@@ -56,7 +57,9 @@ class TopoGraph(NamedTuple):
 # The scopes do not nest. Never rename a flax module for the trace's sake:
 # module names are parameter-tree keys and seed the initial weights.
 GATHER = "gather"        # neighbor_gather in SAGELayer, and its VJP: a custom_vjp (sorted rows
-                         # summed by run, a kernel) with TopoGraph.by_dst, else XLA's scatter-add
+                         # summed by run, a kernel) with TopoGraph.by_dst, else XLA's scatter-add;
+                         # on a `data` mesh also the all-gather of the states and the
+                         # reduce-scatter of the cotangent's sums, written or the partitioner's
 MESSAGE = "message"      # edge projection, the sum of the three terms, gelu
 REDUCE = "reduce"        # masked_mean over the K neighbor slots
 DENSE = "dense"          # every other Dense / LayerNorm of the encoder, the L2 norm

@@ -1,10 +1,15 @@
 """The one Pallas TPU kernel of the neighbor aggregation: the gather's VJP
 (ops.neighbor_agg.neighbor_gather) in the cheap direction, N*K cotangent rows
-summed into N, on one TPU chip. The neighbor table is fixed for a run, so its
+summed into N, on TPU chips. The neighbor table is fixed for a run, so its
 slots are sorted by destination once, on the host (`edges_by_destination`);
 the backward gathers the cotangent rows into that order and a kernel adds up
-the contiguous runs (`sum_by_destination`). All that knows the table's format
-is in this file.
+the contiguous runs (`sum_by_destination`). On a mesh whose `data` axis splits
+the node rows every chip has a table of its own, over its row shard's slots,
+which point into all N rows: it sums its own cotangent rows into [N, H], and
+the chips' sums are reduce-scattered (`EdgesByShard`; GSPMD cannot partition a
+`pallas_call`, so that runs under `shard_map`). Which VJP a placed run takes
+is decided here, once, from its shapes and its mesh (`gather_vjp_tables`).
+All that knows the table's format is in this file.
 
 The slots are sorted inside equal blocks of the source rows, each of at most
 BLOCK_BYTES: XLA gathers rows out of a table it can hold in VMEM four times
@@ -27,6 +32,8 @@ PERF.md), so past MAX_BLOCKS there is no table.
 
 from __future__ import annotations
 
+from dataclasses import dataclass
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -34,6 +41,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from dragonfly2_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 BLOCK_BYTES = 32 << 20
 MAX_BLOCKS = 32
@@ -42,6 +52,7 @@ WINDOW = 384
 ALIGN = 16
 FIRST, LAST = 1, 2  # flags of a window: it opens / closes its tile
 SLOT_ORDER = "k_major"  # the run manifest's word for the table's format
+PLATFORM = "tpu"  # the devices Mosaic compiles the kernel for; elsewhere it can only be interpreted
 
 
 class EdgesByDst(NamedTuple):
@@ -66,6 +77,21 @@ class EdgesByDst(NamedTuple):
     live: jnp.ndarray
 
 
+@partial(jax.tree_util.register_dataclass, data_fields=["tables"], meta_fields=["mesh"])
+@dataclass(frozen=True)
+class EdgesByShard:
+    """A table for every row shard of a mesh's `data` axis: the shards'
+    `EdgesByDst` stacked on a leading axis, which placement splits over
+    `data`, so that a chip holds its own alone. A shard's slots are its rows
+    of `neighbors` (K-major, as the shard's cotangent lies) and point into all
+    N rows. Every shape is the same from shard to shard; `live` is not: a
+    shard whose rows point at few tiles walks fewer windows. The mesh is what
+    `shard_map` runs the shards' sums on (static: no array of the pytree)."""
+
+    tables: EdgesByDst
+    mesh: Mesh
+
+
 def kernel_sums(width: int, dtype) -> bool:
     """Rows the kernel adds up exactly: bfloat16 (the MXU's passes would round
     float32 rows, 2e-5 of the largest sum on the v5e) and whole lanes wide."""
@@ -83,24 +109,100 @@ def _source_blocks(slots: int, row_bytes: int) -> int:
     return 0
 
 
-def edges_by_destination(neighbors: np.ndarray, width: int, dtype) -> EdgesByDst | None:
+def _table_blocks(slots: int, dst_rows: int, width: int, dtype) -> tuple[int, str]:
+    """The source blocks of one table over `slots` cotangent rows
+    [slots, width] of `dtype` that point into `dst_rows` rows, or 0 and why
+    the kernel does not take those shapes."""
+    if not kernel_sums(width, dtype):
+        name = None if dtype is None else jnp.dtype(dtype).name
+        return 0, f"the kernel sums bfloat16 rows of whole lanes (128), not {name}[{width}]"
+    if dst_rows % TILE_DST:
+        return 0, f"{dst_rows} destination rows are not whole tiles of {TILE_DST}"
+    blocks = _source_blocks(slots, width * 2)
+    if not blocks:
+        return 0, (f"{slots} slots of {width * 2} bytes do not tile into at most {MAX_BLOCKS} "
+                   f"blocks of {BLOCK_BYTES >> 20} MB and whole windows of {WINDOW} rows")
+    return blocks, ""
+
+
+def why_derived(shape: tuple[int, int], width: int, dtype, mesh: Mesh) -> str:
+    """THE rule for which VJP the gather of a placed run takes, from what the
+    program can observe: the placed `neighbors`' shape [N, K], the gathered
+    states' width and dtype, and the mesh. "" where the kernel runs, over one
+    table a row shard of `data`; else the reason it stays `jnp.take`'s."""
+    n, k = shape
+    shards = mesh.shape[DATA_AXIS]
+    platform = mesh.devices.flat[0].platform
+    if platform != PLATFORM:
+        return f"{platform} devices: the kernel compiles for {PLATFORM} alone"
+    if mesh.shape[MODEL_AXIS] != 1:
+        return f"`{MODEL_AXIS}` axis of {mesh.shape[MODEL_AXIS]}: the kernel sums whole rows, not column shards"
+    if n % shards:
+        return f"{n} node rows are not whole row shards of {shards}"
+    return _table_blocks(n // shards * k, n, width, dtype)[1]
+
+
+def gather_vjp_tables(
+    neighbors: np.ndarray, width: int, dtype, mesh: Mesh
+) -> tuple[EdgesByDst | EdgesByShard | None, str]:
+    """What placement hangs on the graph for the gather's VJP, and why: the
+    sorted table (one device), a table per row shard of `data` (more: each
+    over the shard's own rows of `neighbors`, pointing into all N), or None
+    and `why_derived`'s reason. Numpy, on the host, once per placed run."""
+    reason = why_derived(neighbors.shape, width, dtype, mesh)
+    if reason:
+        return None, reason
+    n, shards = neighbors.shape[0], mesh.shape[DATA_AXIS]
+    tables = [edges_by_destination(rows, width, dtype, n) for rows in np.split(np.asarray(neighbors), shards)]
+    if shards == 1:
+        return tables[0], ""
+    return EdgesByShard(EdgesByDst(*(np.stack(arrays) for arrays in zip(*tables))), mesh), ""
+
+
+def gather_vjp_report(
+    by_dst: EdgesByDst | EdgesByShard | None, shape: tuple[int, int], width: int, dtype, mesh: Mesh
+) -> dict:
+    """The run manifest's words for what `gather_vjp_tables` returned to a
+    placed run: the table(s) the kernel walks (its time goes by the window, so
+    `live_windows` over the shards is what a shard heavy with hubs shows), or
+    the rule's reason for none."""
+    if by_dst is None:
+        return {"path": "derived", "reason": why_derived(shape, width, dtype, mesh)}
+    one = isinstance(by_dst, EdgesByDst)
+    perm, live = (by_dst.perm[None], by_dst.live) if one else (by_dst.tables.perm, by_dst.tables.live)
+    shards, blocks, per_block = perm.shape
+    live = np.asarray(live)
+    return {
+        "path": "sorted_kernel", "slot_order": SLOT_ORDER, "shards": shards, "blocks": blocks,
+        "block_bytes": per_block * width * jnp.dtype(dtype).itemsize,
+        "live_windows": {"least": int(live.min()), "most": int(live.max())},
+    }
+
+
+def edges_by_destination(
+    neighbors: np.ndarray, width: int, dtype, dst_rows: int | None = None
+) -> EdgesByDst | None:
     """Sort a neighbor table's slots by destination for cotangent rows
     [N*K, width] of `dtype`: numpy, on the host, once per placed run (~0.1 s
     for a million slots), inside equal blocks of the K-major slot order (the
-    text above BLOCK_BYTES). None where the kernel does not apply
-    (`kernel_sums`, N a multiple of TILE_DST) or does not pay (MAX_BLOCKS): the
-    gather then keeps `jnp.take`'s VJP. Every shape depends on N, K and the
-    rows' bytes alone: a hub is a longer run of windows for its tile, a tile
-    nobody points at one window that adds nothing."""
+    text above BLOCK_BYTES). `dst_rows` is the number of rows the slots point
+    into where it is not the table's own N: a row shard's [N/dp, K] points
+    into all N, so the tiles come from the destinations, the slots and the
+    blocks from the shard's rows. None where the kernel does not apply or
+    does not pay (`_table_blocks`): the gather then keeps `jnp.take`'s VJP.
+    Every shape depends on N, K, the destination rows and the rows' bytes
+    alone: a hub is a longer run of windows for its tile, a tile nobody points
+    at one window that adds nothing."""
     n, k = neighbors.shape
-    blocks = _source_blocks(n * k, width * 2) if kernel_sums(width, dtype) and n % TILE_DST == 0 else 0
+    dst_rows = n if dst_rows is None else dst_rows
+    blocks, _ = _table_blocks(n * k, dst_rows, width, dtype)
     if not blocks:
         return None
     per_block = n * k // blocks
     flat = np.asarray(neighbors, np.int32).T.reshape(blocks, per_block)  # slots in K-major order
     perm = np.argsort(flat, axis=1, kind="stable").astype(np.int32)
     dst = np.take_along_axis(flat, perm, axis=1)
-    tiles = n // TILE_DST
+    tiles = dst_rows // TILE_DST
     # the block's run of rows for each tile: [first[b, t], first[b, t + 1])
     first = np.stack([np.searchsorted(d, np.arange(tiles + 1) * TILE_DST) for d in dst])
     begin = first[:, :-1] // ALIGN * ALIGN
@@ -167,14 +269,15 @@ def _segment_sum_kernel(tile_ref, block_ref, start_ref, flags_ref, local_ref, *r
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
-@jax.jit  # traced and lowered once for every layer of a step
-def sum_by_destination(by_dst: EdgesByDst, g: jnp.ndarray) -> jnp.ndarray:
-    """Cotangent [N, K, H] -> [N, H]: what a scatter-add by the neighbor table
-    gives, with float32 accumulation (`kernel_sums(H, g.dtype)`). Gathers, the
-    cheap direction, block by block (perm permutes a block's slots; the blocks
-    are ranges of the K-major rows, [K, N, H], which on the TPU is the
-    cotangent as it lies), then one grid step per live window, by tile: a
-    tile's output block stays in VMEM from its first window to its last."""
+@partial(jax.jit, static_argnames="dst_rows")  # traced and lowered once for every layer of a step
+def sum_by_destination(by_dst: EdgesByDst, g: jnp.ndarray, dst_rows: int | None = None) -> jnp.ndarray:
+    """Cotangent [N, K, H] -> [N, H] (a row shard's [N/dp, K, H] -> the whole
+    [dst_rows, H], as its table was built): what a scatter-add by the neighbor
+    table gives, with float32 accumulation (`kernel_sums(H, g.dtype)`).
+    Gathers, the cheap direction, block by block (perm permutes a block's
+    slots; the blocks are ranges of the K-major rows, [K, N, H], which on the
+    TPU is the cotangent as it lies), then one grid step per live window, by
+    tile: a tile's output block stays in VMEM from its first window to its last."""
     n, _, width = g.shape
     blocks = jnp.swapaxes(g, 0, 1).reshape(by_dst.perm.shape[0], -1, width)
     rows = [
@@ -184,7 +287,7 @@ def sum_by_destination(by_dst: EdgesByDst, g: jnp.ndarray) -> jnp.ndarray:
     tile, block, start, flags = by_dst.items
     return pl.pallas_call(
         _segment_sum_kernel,
-        out_shape=jax.ShapeDtypeStruct((n, width), g.dtype),
+        out_shape=jax.ShapeDtypeStruct((n if dst_rows is None else dst_rows, width), g.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(by_dst.live[0],),
@@ -199,3 +302,27 @@ def sum_by_destination(by_dst: EdgesByDst, g: jnp.ndarray) -> jnp.ndarray:
         ),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
     )(tile, block, start, flags, by_dst.local, *rows)
+
+
+def sum_by_shard(by_shard: EdgesByShard, g: jnp.ndarray) -> jnp.ndarray:
+    """Cotangent [N, K, H], rows over `data` -> [N, H], rows over `data`: every
+    chip sums its own cotangent rows into all N rows with its own table
+    (`sum_by_destination` under `shard_map`), and the chips' [N, H] sums are
+    added up and split by rows, in the cotangent's dtype: a reduce-scatter.
+    That last step is left to the partitioner, as the sum over the shard axis
+    of the stacked sums with its rows constrained to `data`: a `psum_scatter`
+    inside the `shard_map` compiles to the same fused collective on the TPU,
+    but the compiler drops its `op_name`, and the device trace is read by the
+    step's scope names (models/graphsage.STEP_SCOPES)."""
+    mesh = by_shard.mesh
+    shards = mesh.shape[DATA_AXIS]
+
+    def shard_sums(table, g_rows):
+        table = jax.tree.map(lambda a: a[0], table)  # the shard's own of the stack
+        return sum_by_destination(table, g_rows, dst_rows=g_rows.shape[0] * shards)[None]
+
+    rows = P(DATA_AXIS)
+    sums = jax.shard_map(  # (the pallas_call's out_shape says nothing of mesh axes: no check of them)
+        shard_sums, mesh=mesh, in_specs=(rows, rows), out_specs=rows, check_vma=False
+    )(by_shard.tables, g)
+    return jax.lax.with_sharding_constraint(jax.lax.reduce_sum(sums, axes=(0,)), NamedSharding(mesh, rows))

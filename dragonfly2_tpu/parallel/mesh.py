@@ -13,8 +13,9 @@ shardings on inputs/params, let XLA insert the collectives, profile. Axes:
 Which mesh a training run gets is decided where the run is placed
 (`mesh_for_run`): every device on `data`, `model` 1. One device is
 `{data: 1, model: 1}`; a four-chip host is `{data: 4, model: 1}`: node rows
-and the pair batch are split, and with them the rows XLA's gather and its
-scatter-add VJP pay for one by one. `make_mesh` builds any other shape for a
+and the pair batch are split, and with them the rows XLA's gather pays for one
+by one and the cotangent rows a chip sums (ops.neighbor_agg_pallas, a sorted
+table a row shard). `make_mesh` builds any other shape for a
 caller that names one (the tests of the `model` rules, `mp_train`).
 
 The reference has no ICI story at all (its parallelism is goroutines + gRPC,
@@ -96,7 +97,8 @@ def infer_param_sharding(params: Any, mesh: Mesh) -> Any:
 
 def graph_shardings(mesh: Mesh) -> tuple[NamedSharding, ...]:
     """Shardings for TopoGraph's four arrays: node rows over "data". (Its
-    `by_dst` exists on a mesh of one device alone: trainer.train_gnn.)"""
+    `by_dst`, one sorted table per row shard where "data" has several, is
+    stacked by shard and split the same way: trainer.train_gnn places it.)"""
     row = NamedSharding(mesh, P(DATA_AXIS))
     return (
         row,  # node_feats [N, F]

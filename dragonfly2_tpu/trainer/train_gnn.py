@@ -105,8 +105,14 @@ def _place_sharded(
 ) -> tuple[train_state.TrainState, Any, TopoGraph, TopoGraph]:
     """Shared placement: pad node rows to the dp size, kernels over "model",
     node rows over "data". The neighbor table is fixed from here to the run's
-    end, so its transpose is fixed here too (`_edges_by_destination`).
+    end, so its transpose is fixed here too, once, on the host, where the
+    kernel that sums the gather's VJP over it will run
+    (`neighbor_agg_pallas.gather_vjp_tables` decides): one table on one
+    device, a table per row shard on a `data` mesh, each beside its shard.
+    Elsewhere None, and the VJP stays `jnp.take`'s.
     Returns (state, state_sharding, g, g_sharding)."""
+    from dragonfly2_tpu.ops.neighbor_agg_pallas import gather_vjp_tables
+
     dp = mesh.shape[meshlib.DATA_AXIS]
     param_sh = meshlib.infer_param_sharding(state.params, mesh)
     state_sh = train_state.TrainState(
@@ -118,16 +124,16 @@ def _place_sharded(
             lambda leaf: meshlib.param_leaf_sharding(leaf, mesh), state.opt_state
         ),
     )
-    # the host's part of placement, timed: padding, the sorted table, and the
-    # copies onto the devices (a row shard each on a `data` mesh)
+    # the host's part of placement, timed: padding, the sorted table(s), and
+    # the copies onto the devices (a row shard each on a `data` mesh)
     with default_tracer().span("trainer.gnn.place", data=dp, model=mesh.shape[meshlib.MODEL_AXIS]):
         g = pad_graph(g, meshlib.pad_to_multiple(g.node_feats.shape[0], dp))
-        g = g._replace(by_dst=_edges_by_destination(state, g, mesh))
+        by_dst, _ = gather_vjp_tables(np.asarray(g.neighbors), *_gathered_states(state), mesh)
+        g = g._replace(by_dst=by_dst)
         state = jax.device_put(state, state_sh)
-        g_sh = TopoGraph(
-            *meshlib.graph_shardings(mesh),
-            by_dst=jax.tree.map(lambda _: meshlib.replicated(mesh), g.by_dst),
-        )
+        # per-shard tables are stacked by shard; one device's table lies as it always has
+        table_sh = meshlib.replicated(mesh) if dp == 1 else meshlib.batch_sharding(mesh)
+        g_sh = TopoGraph(*meshlib.graph_shardings(mesh), by_dst=jax.tree.map(lambda _: table_sh, g.by_dst))
         g = jax.block_until_ready(jax.device_put(_as_jnp_graph(g), g_sh))
     return state, state_sh, g, g_sh
 
@@ -137,18 +143,6 @@ def _gathered_states(state: train_state.TrainState) -> tuple[int, Any]:
     whose `apply` the state holds (0, None: not a model of ours)."""
     model = getattr(state.apply_fn, "__self__", None)
     return getattr(model, "hidden", 0), getattr(model, "dtype", None)
-
-
-def _edges_by_destination(state: train_state.TrainState, g: TopoGraph, mesh: Mesh):
-    """`g.by_dst`: the neighbor table's slots sorted by destination, once, on
-    the host, where the kernel that sums the gather's VJP over them will run:
-    one TPU chip (GSPMD cannot partition a `pallas_call`) and states it adds
-    up exactly. Elsewhere None, and the VJP stays `jnp.take`'s."""
-    if mesh.size != 1 or mesh.devices.flat[0].platform != "tpu":
-        return None
-    from dragonfly2_tpu.ops.neighbor_agg_pallas import edges_by_destination
-
-    return edges_by_destination(np.asarray(g.neighbors), *_gathered_states(state))
 
 
 def pad_graph(g: TopoGraph, n_padded: int) -> TopoGraph:
@@ -222,22 +216,15 @@ def _placement(mesh: Mesh, decision: dict, state: Any, g: TopoGraph, batch_size:
     a caller's own), the Dense kernels the tensor-parallel rule shards and
     the graph's node rows, both read back from the placed arrays, the rows of
     one pair batch each device is constrained to inside the step, and which
-    VJP the gather takes."""
+    VJP the gather takes: the table(s) placement hung on the graph, or the
+    rule's reason there is none (`neighbor_agg_pallas.gather_vjp_report`)."""
+    from dragonfly2_tpu.ops.neighbor_agg_pallas import gather_vjp_report
+
     kernels = [
         leaf for leaf in jax.tree.leaves(state.params)
         if leaf.ndim == 2 and meshlib.MODEL_AXIS in leaf.sharding.spec
     ]
     batch_size = meshlib.pad_to_multiple(batch_size, mesh.shape[meshlib.DATA_AXIS])
-    gather_vjp = {"path": "derived"}
-    if g.by_dst is not None:  # built for this state's model alone: the kernel runs
-        from dragonfly2_tpu.ops.neighbor_agg_pallas import SLOT_ORDER
-
-        blocks, per_block = g.by_dst.perm.shape
-        width, dtype = _gathered_states(state)
-        gather_vjp = {
-            "path": "sorted_kernel", "slot_order": SLOT_ORDER, "blocks": blocks,
-            "block_bytes": per_block * width * jnp.dtype(dtype).itemsize,
-        }
     return {
         "mesh": {k: int(v) for k, v in mesh.shape.items()},
         "decision": decision,
@@ -245,7 +232,7 @@ def _placement(mesh: Mesh, decision: dict, state: Any, g: TopoGraph, batch_size:
         "graph": meshlib.placement_report(g._replace(by_dst=None)),
         "batch_rows_per_device": meshlib.batch_sharding(mesh).shard_shape((batch_size,))[0],
         "gather_vjp": {
-            **gather_vjp,
+            **gather_vjp_report(g.by_dst, g.neighbors.shape, *_gathered_states(state), mesh),
             "slots": int(g.neighbors.size),
             "max_in_degree": int(np.bincount(np.asarray(g.neighbors).ravel()).max()),
         },

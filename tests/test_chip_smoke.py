@@ -107,18 +107,35 @@ def test_platform_phase_refuses_a_device_child_that_fell_back():
 
 @pytest.fixture(scope="module")
 def kernel_leg():
-    """The device child's kernel leg as it runs off the chip: interpreted."""
-    return chip_smoke._pallas_check(False)
+    """The device child's kernel leg as it runs off the chip: interpreted. On
+    four of the virtual devices it also takes the per-shard case, for which
+    the test, not the program, says these CPU devices get tables."""
+    from dragonfly2_tpu.ops import neighbor_agg_pallas as pk
+    from dragonfly2_tpu.parallel import mesh as meshlib
+
+    four = jax.devices()[:4]
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(pk, "PLATFORM", "cpu")
+        patch.setattr(meshlib, "mesh_for_run", lambda run=meshlib.mesh_for_run: run(four))
+        return chip_smoke._pallas_check(False, n_dev=4)
 
 
-@pytest.mark.parametrize("shape", chip_smoke.KERNEL_SHAPES, ids=lambda s: "x".join(map(str, s[:2])))
+@pytest.mark.parametrize("shape", [*chip_smoke.KERNEL_SHAPES, (*chip_smoke.KERNEL_SHAPES[-1], 4)],
+                         ids=["1024x256", "4096x512", "4096x512_over_4"])
 def test_kernel_leg_checks_the_steps_kernel_interpreted_on_cpu(kernel_leg, shape):
     """The kernel the training step runs (`sum_by_destination` over a seeded
     table), interpreted, is within bfloat16 of `jnp.take`'s own VJP at both of
-    the smoke's shapes; the second has a hub row and two source blocks. A
+    the smoke's shapes; the second has a hub row and two source blocks, and
+    runs once more with its rows over four devices, a table a row shard. A
     kernel summing rows into the wrong place fails it."""
-    n, width, hub = shape
-    assert len(kernel_leg) == len(chip_smoke.KERNEL_SHAPES)
+    n, width, hub, *shards = shape
+    assert len(kernel_leg) == len(chip_smoke.KERNEL_SHAPES) + 1
+    if shards:
+        result = kernel_leg[f"{n}x16x{width}/4"]
+        assert result["ok"] and result["compiled"] is False and result["forward_exact"]
+        assert (result["shards"], result["blocks"]) == (4, 1) and 0 < result["max_err"] <= 2.0 ** -6
+        assert result["live_windows"]["least"] <= result["live_windows"]["most"]
+        return
     result = kernel_leg[f"{n}x16x{width}"]
     assert result["ok"] and result["compiled"] is False
     assert result["blocks"] == (2 if hub else 1) and 0 < result["max_err"] <= 2.0 ** -7

@@ -3,8 +3,8 @@
 in plain numpy, the segmented-sum kernel interpreted on the CPU, the
 `custom_vjp` in a training step, the run manifest's word on it.
 
-SEVEN tier-1 tests, most of them loops over cases, in a file of their own, on
-purpose (an eighth is marked `slow`: it loads the TPU's compiler).
+FOURTEEN tier-1 tests, most of them loops over cases, in a file of their own,
+on purpose (a fifteenth is marked `slow`: it loads the TPU's compiler).
 Tier-1 hands files to its workers by their number of tests, largest first, two
 at a time (xdist's loadfile), so a file's count decides when it runs and what
 every file after it runs beside. The scheduler plane's host-timing assertions
@@ -14,8 +14,9 @@ they run early, before the JAX-heavy files. As parametrised cases these tests
 made a large file: it started with the run, took a worker for 20 s and pushed
 those assertions into the JAX-heavy middle, where 6 of 13 whole runs failed
 one (ROADMAP D10); a light file of 42 cases alone still moved
-test_bench_contract.py behind an 11 s file. With seven tests this file ranks
-after every timing-sensitive one, runs near the end, and leaves the order of
+test_bench_contract.py behind an 11 s file. Under fifteen tests this file (and
+tests/test_mesh_decision.py, which holds the mesh's side of the same VJP)
+ranks after every timing-sensitive one and leaves the order of
 all files before it as the parent has it. A failure names its case."""
 
 import os
@@ -86,32 +87,39 @@ def test_table_sorts_every_slot_once(monkeypatch):
     permutation of each block's slots in destination order, the slots counted
     K-major (slot k*N + n is neighbors[n, k]), and the kernel's windows count
     every slot exactly once, in its tile. Where the shapes do not tile there
-    is no table."""
+    is no table. A row shard's table (`shards` > 1: its rows of the graph,
+    pointing into all N) sorts the shard's slots the same way, over the tiles
+    of all N destinations."""
     for n, k in [(512, 16), (256, 24), (1024, 6), (100, 7), (96, 5)]:
         for kind in ["hub", "one_row", "uniform"]:
-            for blocks in [1, 4]:
-                case = (n, k, kind, blocks)
-                nbr = _table(kind, n, k)
-                t = _table_in_blocks(monkeypatch, nbr, 128, jnp.bfloat16, blocks)
-                if n % pk.TILE_DST:
-                    assert t is None, case
+            for blocks, shards in [(1, 1), (4, 1), (1, 4), (2, 2)]:
+                case = (n, k, kind, blocks, shards)
+                whole = _table(kind, n, k)
+                if n % shards:
                     continue
-                assert t.perm.shape == (blocks, n * k // blocks), case
-                per_block = t.perm.shape[1]
-                assert (np.sort(t.perm, axis=1) == np.arange(per_block)).all(), case
-                dst = np.take_along_axis(nbr.T.reshape(blocks, -1), t.perm, axis=1)
-                assert (np.diff(dst, axis=1) >= 0).all(), case
-                slot_k, slot_n = np.divmod(t.perm + np.arange(blocks)[:, None] * per_block, n)
-                assert (nbr[slot_n, slot_k] == dst).all(), case
-                live = t.live[0]
-                tile, block, start, flags = t.items[:, :live]
-                # by tile, every tile written
-                assert (np.diff(tile) >= 0).all() and set(tile) == set(range(n // pk.TILE_DST)), case
-                assert (start % pk.ALIGN == 0).all() and (start + pk.WINDOW <= per_block).all(), case
-                local = t.local[:live, 0]
-                assert (local >= 0).sum() == n * k and local.max() < pk.TILE_DST, case
-                rows = dst[block[:, None], start[:, None] + np.arange(pk.WINDOW)]
-                assert (np.where(local >= 0, rows - tile[:, None] * pk.TILE_DST, -1) == local).all(), case
+                for nbr in np.split(whole, shards):
+                    rows = nbr.shape[0]
+                    monkeypatch.setattr(pk, "BLOCK_BYTES", nbr.size * 128 * 2 // blocks)
+                    t = pk.edges_by_destination(nbr, 128, jnp.bfloat16, n)
+                    if n % pk.TILE_DST:
+                        assert t is None, case
+                        continue
+                    assert t.perm.shape == (blocks, rows * k // blocks), case
+                    per_block = t.perm.shape[1]
+                    assert (np.sort(t.perm, axis=1) == np.arange(per_block)).all(), case
+                    dst = np.take_along_axis(nbr.T.reshape(blocks, -1), t.perm, axis=1)
+                    assert (np.diff(dst, axis=1) >= 0).all(), case
+                    slot_k, slot_n = np.divmod(t.perm + np.arange(blocks)[:, None] * per_block, rows)
+                    assert (nbr[slot_n, slot_k] == dst).all(), case
+                    live = t.live[0]
+                    tile, block, start, flags = t.items[:, :live]
+                    # by tile, every tile of the N destinations written
+                    assert (np.diff(tile) >= 0).all() and set(tile) == set(range(n // pk.TILE_DST)), case
+                    assert (start % pk.ALIGN == 0).all() and (start + pk.WINDOW <= per_block).all(), case
+                    local = t.local[:live, 0]
+                    assert (local >= 0).sum() == rows * k and local.max() < pk.TILE_DST, case
+                    got = dst[block[:, None], start[:, None] + np.arange(pk.WINDOW)]
+                    assert (np.where(local >= 0, got - tile[:, None] * pk.TILE_DST, -1) == local).all(), case
 
 
 def test_work_list_shapes_depend_on_n_and_k_alone(monkeypatch):
@@ -119,6 +127,12 @@ def test_work_list_shapes_depend_on_n_and_k_alone(monkeypatch):
               for kind in ("hub", "one_row", "uniform")]
     shapes = [[x.shape for x in jax.tree.leaves(t)] for t in tables]
     assert shapes[0] == shapes[1] == shapes[2]
+    # a row shard's: on N, N/dp, K and the row bytes alone, so the shards' tables stack; `live` differs
+    shards = [pk.edges_by_destination(rows, 128, jnp.bfloat16, 1024)
+              for kind in ("hub", "uniform") for rows in np.split(_table(kind, 1024, 6), 4)]
+    assert len({tuple(x.shape for x in t) for t in shards}) == 1
+    assert shards[0].perm.shape == (1, 256 * 6) and shards[0].items.shape[1] == 4 + -(-(256 * 6 + 15 * 4) // pk.WINDOW)
+    assert len({int(t.live[0]) for t in shards}) > 1
 
 
 def test_source_blocks_follow_the_cotangents_bytes():
@@ -231,17 +245,13 @@ def test_gather_vjp_with_the_placed_table(monkeypatch):
         np.testing.assert_allclose(got, want, rtol=2.0 ** -8, atol=2.0 ** -8 * np.abs(want).max(), err_msg=str(case))
         assert not got[3 * n // 4:].any(), case  # nobody points there
 
-    # a table of another graph is refused; states the kernel does not sum leave it alone
+    # a table is placement's decision: another graph's, or states the kernel does not sum, are refused
     nbr = _hub_table(512, 12)
     table = _table_in_blocks(monkeypatch, nbr, 128, jnp.bfloat16, 1)
-    try:
-        neighbor_gather(jnp.zeros((256, 128), jnp.bfloat16), nbr, table)
-    except ValueError as e:
-        assert "256 states for a table of 512 rows" in str(e)
-    else:
-        raise AssertionError("a table of 512 rows took 256 states")
-    h = jnp.ones((512, 128), jnp.float32)
-    assert "scatter" in jax.jit(jax.grad(lambda x: jnp.sum(neighbor_gather(x, nbr, table) ** 2))).lower(h).as_text()
+    for states, said in [(jnp.zeros((256, 128), jnp.bfloat16), "256 states for a table of 512 rows"),
+                         (jnp.ones((512, 128), jnp.float32), "float32[128] states for a table the kernel sums")]:
+        with pytest.raises(ValueError, match=re.escape(said)):
+            neighbor_gather(states, nbr, table)
 
 
 def test_a_placed_step_with_the_table(monkeypatch):
@@ -262,12 +272,14 @@ def test_a_placed_step_with_the_table(monkeypatch):
         unplaced, graph, pairs, mesh, batch_size=64, steps_per_call=2)
     assert g.by_dst is None
     counts = {"slots": n * k, "max_in_degree": int(np.bincount(graph.neighbors.ravel()).max())}
-    assert train_gnn._placement(mesh, {"rule": "given"}, state, g, 64)["gather_vjp"] == {"path": "derived", **counts}
+    assert train_gnn._placement(mesh, {"rule": "given"}, state, g, 64)["gather_vjp"] == {
+        "path": "derived", "reason": "cpu devices: the kernel compiles for tpu alone", **counts}
     assert train_gnn._gathered_states(state) == (128, jnp.bfloat16)
     table = _table_in_blocks(monkeypatch, graph.neighbors, 128, jnp.bfloat16, 2)
     placement = train_gnn._placement(mesh, {"rule": "given"}, state, g._replace(by_dst=table), 64)
     assert placement["gather_vjp"] == {
-        "path": "sorted_kernel", "slot_order": "k_major", "blocks": 2, "block_bytes": n * k // 2 * 128 * 2,
+        "path": "sorted_kernel", "slot_order": "k_major", "shards": 1, "blocks": 2,
+        "block_bytes": n * k // 2 * 128 * 2, "live_windows": {"least": int(table.live[0]), "most": int(table.live[0])},
         **counts}
     assert placement["graph"]["leaves"] == 4
 
@@ -281,6 +293,84 @@ def test_a_placed_step_with_the_table(monkeypatch):
     assert any("sum_by_destination" in name and "while" in name for name in backward), sorted(gather)  # the sum
     assert any(name.endswith("/gather") for name in gather - backward), sorted(gather)  # the forward
     assert not any("scatter" in name for name in gather), sorted(gather)
+
+
+def _one_device_mesh():
+    return meshlib.mesh_for_run(jax.devices()[:1])[0]
+
+
+# (reason's words, neighbors' shape, width, dtype, the mesh): each alone keeps `jnp.take`'s VJP
+DERIVED_BY_RULE = {
+    "cpu": ("cpu devices", (512, 16), 128, jnp.bfloat16, _one_device_mesh),
+    "model_axis": ("`model` axis of 2", (512, 16), 128, jnp.bfloat16,
+                   lambda: meshlib.make_mesh(jax.devices()[:4], model_parallel=2)),
+    "float32": ("not float32[128]", (512, 16), 128, jnp.float32, _one_device_mesh),
+    "lanes": ("not bfloat16[96]", (512, 16), 96, jnp.bfloat16, _one_device_mesh),
+    "tiles": ("384 destination rows are not whole tiles of 256", (384, 16), 128, jnp.bfloat16, _one_device_mesh),
+    "max_blocks": ("do not tile into at most 32 blocks", (65536, 16), 1024, jnp.bfloat16, _one_device_mesh),
+}
+
+
+@pytest.mark.parametrize("rule", DERIVED_BY_RULE)
+def test_the_one_rule_says_why_the_vjp_stays_derived(monkeypatch, rule):
+    """`neighbor_agg_pallas.why_derived` is the one place that decides which
+    VJP a placed run's gather takes, from the placed shapes, the states and
+    the mesh; `gather_vjp_tables` returns no table and the rule's reason. The
+    CPU's devices are the first reason; to reach the others the test, not the
+    program, says the kernel compiles for them (`PLATFORM`)."""
+    said, shape, width, dtype, mesh = DERIVED_BY_RULE[rule]
+    mesh = mesh()
+    if rule != "cpu":
+        monkeypatch.setattr(pk, "PLATFORM", "cpu")
+    table, reason = pk.gather_vjp_tables(np.zeros(shape, np.int32), width, dtype, mesh)
+    assert table is None and said in reason, reason
+    assert pk.gather_vjp_report(None, shape, width, dtype, mesh) == {"path": "derived", "reason": reason}
+    if rule == "max_blocks":  # 2 GB of cotangent rows: four row shards hold 16 blocks each (ROADMAP R11)
+        data4 = meshlib.make_mesh(jax.devices()[:4], model_parallel=1)
+        assert pk.why_derived(shape, width, dtype, data4) == ""
+        assert "16384 node rows are not whole row shards of 3" in pk.why_derived(
+            (16384, 16), width, dtype, meshlib.make_mesh(jax.devices()[:3], model_parallel=1))
+
+
+def _parents_gather(h, neighbors, by_dst=None):
+    """`neighbor_gather` as the parent commit had it: the table's `custom_vjp`
+    where the kernel sums the states, else `jnp.take`."""
+    from dragonfly2_tpu.ops import neighbor_agg
+
+    if by_dst is not None and pk.kernel_sums(h.shape[1], h.dtype):
+        return neighbor_agg._gather_sorted_vjp(h, neighbors, by_dst)
+    return jnp.take(h, neighbors, axis=0)
+
+
+def test_one_device_takes_the_table_and_the_program_it_always_had(monkeypatch):
+    """On a mesh of one device the decision returns the table
+    `edges_by_destination` builds over the whole graph, array for array, and
+    the placed `multi_step` lowers (for the TPU, here, with no chip: Mosaic's
+    kernel and all) to the text it has with the parent's `neighbor_gather` in
+    the model: no `shard_map`, no collective, nothing of the mesh's path."""
+    from dragonfly2_tpu.models import graphsage
+
+    monkeypatch.setattr(pk, "PLATFORM", "cpu")  # the test's word, not the program's: these CPU devices take the kernel
+    cfg = train_gnn.GNNTrainConfig(hidden=128, embed_dim=16, num_layers=2, batch_size=64)
+    graph, pairs = _run_inputs(_hub_table(512, 16), cfg)
+    mesh = _one_device_mesh()
+    table, reason = pk.gather_vjp_tables(graph.neighbors, 128, jnp.bfloat16, mesh)
+    assert isinstance(table, pk.EdgesByDst) and reason == ""
+    for got, want in zip(table, pk.edges_by_destination(graph.neighbors, 128, jnp.bfloat16), strict=True):
+        assert got.dtype == want.dtype and got.shape == want.shape and (got == want).all()
+
+    def lowered():
+        state, g, pool, step = train_gnn.shard_for_training_scan(
+            train_gnn.init_state(cfg, graph, 0), graph, pairs, mesh, batch_size=64, steps_per_call=2)
+        assert isinstance(g.by_dst, pk.EdgesByDst) and {len(leaf.sharding.device_set) for leaf in g.by_dst} == {1}
+        return step.trace(state, g, pool, jax.random.PRNGKey(0)).lower(lowering_platforms=("tpu",)).as_text()
+
+    ours = lowered()
+    with monkeypatch.context() as m:
+        m.setattr(graphsage, "neighbor_gather", _parents_gather)
+        parents = lowered()
+    assert ours == parents and "tpu_custom_call" in ours
+    assert "shard_map" not in ours and "all_gather" not in ours and "all_reduce" not in ours
 
 
 @pytest.mark.slow  # loads the TPU's compiler: alone in its process, never under tier-1's workers

@@ -2,22 +2,33 @@
 every device on `data`), and the row-sharded run that gives: the served scan
 program on a `{data: 4}` mesh against the plain float32 reference and against
 the one-device program, what its collectives move, a node count the devices
-do not divide, and the decision in the run manifest."""
+do not divide, and the decision in the run manifest. And the gather's VJP on
+that mesh where the kernel runs (a sorted table per row shard,
+ops.neighbor_agg_pallas.EdgesByShard): these CPU devices get no table from
+the program, so the tests that want one say so (`PLATFORM`) and interpret the
+kernel. (Fourteen tests: tests/test_gather_vjp.py's docstring says why the
+count matters.)"""
 
 from __future__ import annotations
 
 import asyncio
+import copy
 import json
 import re
 import sys
+from functools import partial
 from pathlib import Path
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from dragonfly2_tpu.models.graphsage import TopoGraph, TopoScorer
+from dragonfly2_tpu.ops import neighbor_agg_pallas as pk
+from dragonfly2_tpu.ops.neighbor_agg import neighbor_gather
 from dragonfly2_tpu.parallel import mesh as meshlib
 from dragonfly2_tpu.trainer import synthetic, train_gnn
 from dragonfly2_tpu.trainer.synthetic import PairBatch
@@ -44,17 +55,21 @@ TINY = json.loads((BENCH / "tests" / "data" / "tiny" / "tiny.json").read_text())
 STEPS = TINY["optimizer"]["gnn"]["steps_per_call"]
 
 
-@pytest.fixture(scope="module")
-def dataset() -> dict:
-    cl = TINY["cluster"]
+def _dataset(config: dict) -> dict:
+    cl = config["cluster"]
     records = telemetry_gen.generate_for(cl, 2_147_483_777)
-    return reference.build_dataset(*records, num_neighbors=TINY["model"]["num_neighbors"], uploads=1,
+    return reference.build_dataset(*records, num_neighbors=config["model"]["num_neighbors"], uploads=1,
                                    chunk_rows=cl["chunk_rows"], pool_rows_cap=cl["pool_rows_cap"])
 
 
-def _tiny_inputs(dataset: dict) -> tuple:
+@pytest.fixture(scope="module")
+def dataset() -> dict:
+    return _dataset(TINY)
+
+
+def _tiny_inputs(dataset: dict, config: dict = TINY) -> tuple:
     """(trainer configuration, graph, pair pool) of the tiny cell over the reference's dataset."""
-    m = TINY["model"]
+    m = config["model"]
     cfg = train_gnn.GNNTrainConfig(hidden=m["hidden"], embed_dim=m["embed_dim"], num_layers=m["num_layers"],
                                    batch_size=m["pair_batch"])
     graph = TopoGraph(*(dataset[k] for k in ("node_feats", "neighbors", "mask", "edge_feats")))
@@ -62,11 +77,13 @@ def _tiny_inputs(dataset: dict) -> tuple:
     return cfg, graph, pairs
 
 
-def _program(inputs: tuple, mesh, dtype) -> tuple:
+def _program(inputs: tuple, mesh, dtype, first_rows=None) -> tuple:
     """The served scan program over `inputs` (configuration, graph, pairs) on
     `mesh`, placed and compiled the way `train_async` does; `dtype` is the
     model's compute dtype (the trainer's is bfloat16). Returns (placed graph,
-    compiled step's text, the first call's losses, gradient norms)."""
+    compiled step's text, the first call's losses, gradient norms), and with
+    `first_rows` (the pool rows of the first step's batch) the norm of every
+    leaf of that step's gradient."""
     opt = TINY["optimizer"]["gnn"]
     cfg, graph, pairs = inputs
     state = train_gnn.init_state(cfg, graph, opt["init_seed"])
@@ -74,10 +91,27 @@ def _program(inputs: tuple, mesh, dtype) -> tuple:
     state = state.replace(apply_fn=model.apply)
     state, g, pool, multi_step = train_gnn.shard_for_training_scan(
         state, graph, pairs, mesh, batch_size=cfg.batch_size, steps_per_call=STEPS)
+    leaves = None
+    if first_rows is not None:  # before the call: it donates the state
+        grads = jax.jit(jax.grad(partial(train_gnn.loss_fn, state.apply_fn)))(
+            state.params, g, PairBatch(*(a[first_rows] for a in pool)))
+        leaves = _leaf_norms(grads)
     _, sub = jax.random.split(jax.random.PRNGKey(opt["sample_seed"]))
     text = multi_step.lower(state, g, pool, sub).compile().as_text()
     _, (losses, gnorms) = multi_step(state, g, pool, sub)
-    return g, text, np.asarray(losses, np.float64), np.asarray(gnorms, np.float64)
+    return Run(g, text, np.asarray(losses, np.float64), np.asarray(gnorms, np.float64), leaves)
+
+
+class Run(NamedTuple):
+    g: TopoGraph | None
+    text: str | None
+    losses: np.ndarray
+    gnorms: np.ndarray
+    leaves: np.ndarray | None = None
+
+
+def _leaf_norms(grads) -> np.ndarray:
+    return np.asarray([jnp.linalg.norm(x.astype(jnp.float32)) for x in jax.tree.leaves(grads)], np.float64)
 
 
 @pytest.fixture(scope="module")
@@ -87,16 +121,16 @@ def runs(dataset) -> dict:
     ref = reference.follow_steps(TINY, dataset, STEPS)
     inputs = _tiny_inputs(dataset)
     return {
-        "reference": (np.asarray(ref["loss"]), np.asarray(ref["grad_norm"])),
+        "reference": Run(None, None, np.asarray(ref["loss"]), np.asarray(ref["grad_norm"])),
         "data4.f32": _program(inputs, data4, jnp.float32),
         "one.f32": _program(inputs, one, jnp.float32),
         "data4.bf16": _program(inputs, data4, jnp.bfloat16),
     }
 
 
-def _gaps(got: tuple, want: tuple) -> tuple[float, float]:
-    return (float(np.max(np.abs(got[-2] - want[-2]) / np.abs(want[-2]))),
-            float(np.max(np.abs(got[-1] - want[-1]) / np.abs(want[-1]))))
+def _gaps(got: Run, want: Run) -> tuple[float, float]:
+    return (float(np.max(np.abs(got.losses - want.losses) / np.abs(want.losses))),
+            float(np.max(np.abs(got.gnorms - want.gnorms) / np.abs(want.gnorms))))
 
 
 # float32 on both sides: what is left is the order of summation (a row shard
@@ -122,7 +156,7 @@ def test_ten_steps_on_a_data4_mesh_follow_the_one_device_program(runs):
     g = runs["data4.f32"][0]
     rows = TINY["cluster"]["hosts"] // 4
     assert {s.data.shape[0] for s in g.neighbors.addressable_shards} == {rows}
-    assert g.by_dst is None  # the gather's VJP stays the derived one on a mesh
+    assert g.by_dst is None  # no TPU here, so no table: the gather's VJP is the derived one
 
 
 _COLLECTIVE = re.compile(
@@ -130,22 +164,141 @@ _COLLECTIVE = re.compile(
 _SHAPE = re.compile(r"\w+\[([\d,]*)\]")
 
 
-def test_no_collective_of_the_data4_step_moves_a_message_tensor(runs):
+def test_no_collective_of_the_data4_step_moves_a_message_tensor(runs, kernel_runs):
     """The partitioner all-gathers `u[N, H]` forward and reduces the `[N, H]`
     cotangent backward; an exchange of `[N/dp, K, H]` (the gathered rows or
-    their cotangent) would be K/dp times the bytes."""
-    m, n = TINY["model"], TINY["cluster"]["hosts"]
-    table, message = n * m["hidden"], n // 4 * m["num_neighbors"] * m["hidden"]
-    assert message > table
-    sizes: dict[str, int] = {}
-    for result, kind in _COLLECTIVE.findall(runs["data4.bf16"][1]):
-        for dims in _SHAPE.findall(result):
-            elements = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
-            sizes[kind] = max(sizes.get(kind, 0), elements)
-    assert sizes.get("all-gather", 0) >= table, sizes           # u, whole, on every device
-    assert {"all-reduce", "reduce-scatter"} & set(sizes), sizes   # the cotangent's and the gradients' sums
-    # padding to the partitioner's tiles aside, nothing larger than the [N, H] table moves
-    assert max(sizes.values()) <= 1.1 * table < message, sizes
+    their cotangent) would be K/dp times the bytes. With a table per row shard
+    the program says so itself (`all_gather` under `shard_map`, the shards'
+    `[N, H]` sums added over `data`): a layer still moves `[N, H]` out once
+    and back once. (The CPU's compiler merges all-reduces into tuples and
+    leaves a reduce-scatter as an all-reduce whose rows are sliced; the TPU's
+    fuses the two.)"""
+    for config, run in [(TINY, runs["data4.bf16"]), (KERNEL_TINY, kernel_runs["data4.tables"])]:
+        m, n = config["model"], config["cluster"]["hosts"]
+        table, slots = n * m["hidden"], n // 4 * m["num_neighbors"]
+        moved: dict[str, list[tuple]] = {}
+        # (a long tuple's own `/*index=5*/` comments would end the match of its shapes)
+        for result, kind in _COLLECTIVE.findall(re.sub(r"/\*index=\d+\*/", "", run.text)):
+            moved.setdefault(kind, []).extend(
+                tuple(int(d) for d in dims.split(",") if d) for dims in _SHAPE.findall(result))
+        # a shard's [N/dp, K, H] however it is folded: rows of H, as many as the shard has slots
+        assert not [dims for found in moved.values() for dims in found
+                    if dims[-1:] == (m["hidden"],) and np.prod(dims[:-1]) >= slots], moved
+        gathered = sum(int(np.prod(dims)) >= table for dims in moved["all-gather"])  # u, whole, on every device
+        reduced = sum(int(np.prod(dims)) == table  # the cotangent's sums, among the gradients'
+                      for dims in moved.get("all-reduce", []) + moved.get("reduce-scatter", []))
+        assert gathered >= 1 and reduced >= 1, moved
+        if config is KERNEL_TINY:  # the program's own exchange: [N, H] out once a layer and back once
+            states = (n, m["hidden"])
+            assert moved["all-gather"].count(states) == moved["all-reduce"].count(states) == m["num_layers"], moved
+
+
+# ---- the same run where the kernel sums the gather's VJP: a sorted table per row shard ----
+
+# the tiny cell at the smallest shapes the kernel takes: whole lanes, one tile of destinations
+KERNEL_TINY = copy.deepcopy(TINY)
+KERNEL_TINY["model"].update(hidden=128, embed_dim=64)
+KERNEL_TINY["cluster"].update(hosts=256, probes=6144, downloads=1024)
+# bfloat16 against float32 and against itself in another order of summation:
+# 2^-8 a rounding; the runs read 1.5e-3 / 2.4e-3 (loss, gradient norm) and
+# 2.7e-3 by the worst leaf against the reference, 1.6e-4 / 1.0e-3 / 1.0e-3
+# against each other. A shard's sums left out, or added twice, is O(1) in
+# every `msg_nbr` kernel's and every earlier leaf's norm.
+BF16_TOLERANCE = 1e-2
+SAME_DTYPE_TOLERANCE = 4e-3
+
+
+def _kernel_as_xla_ops():
+    """Pallas's HLO interpreter for the kernel (any truthy value that is not
+    the TPU interpreter's parameters selects it): the grid as a loop of plain
+    XLA ops, which the four virtual devices run under `shard_map` like any
+    other. The TPU interpreter (tests/test_gather_vjp.py) simulates memories
+    through host callbacks, and four devices' callbacks at once hung the CPU
+    client here, most runs."""
+    return pltpu.force_tpu_interpret_mode(True)
+
+
+@pytest.fixture(scope="module")
+def kernel_runs() -> dict:
+    """The placed bfloat16 program at KERNEL_TINY on one device (no table: the
+    CPU) and on `{data: 4}` with the table per row shard a TPU host would get
+    (the test's word for it, `PLATFORM`; the kernel interpreted), and the
+    float32 reference: ten steps, and the first step's gradient leaf by leaf."""
+    dataset = _dataset(KERNEL_TINY)
+    m, opt = KERNEL_TINY["model"], KERNEL_TINY["optimizer"]["gnn"]
+    inputs = _tiny_inputs(dataset, KERNEL_TINY)
+    assert inputs[1].neighbors.shape == (256, 16)
+    rows = reference.batch_indices_of_step(opt["sample_seed"], STEPS, 1, m["pair_batch"], len(dataset["pairs"]["child"]))
+    ref = reference.follow_steps(KERNEL_TINY, dataset, STEPS)
+    ref_grads = jax.grad(reference.loss_fn)(
+        jax.tree.map(jnp.asarray, reference.init_params(m, opt["init_seed"])),
+        {k: jnp.asarray(dataset[k]) for k in ("node_feats", "neighbors", "mask", "edge_feats")},
+        {k: jnp.asarray(v)[rows] for k, v in dataset["pairs"].items()}, num_layers=m["num_layers"])
+    out = {
+        "reference": Run(None, None, np.asarray(ref["loss"]), np.asarray(ref["grad_norm"]), _leaf_norms(ref_grads)),
+        "one.derived": _program(inputs, meshlib.make_mesh(jax.devices()[:1], model_parallel=1), jnp.bfloat16, rows),
+    }
+    with pytest.MonkeyPatch.context() as patch, _kernel_as_xla_ops():
+        patch.setattr(pk, "PLATFORM", "cpu")
+        out["data4.tables"] = _program(
+            inputs, meshlib.make_mesh(jax.devices()[:4], model_parallel=1), jnp.bfloat16, rows)
+    return out
+
+
+def test_ten_steps_with_a_table_per_row_shard_follow_the_reference_leaf_by_leaf(kernel_runs):
+    """A backward that changed hands is judged by every leaf of the first
+    step's gradient, not by the global norm alone (ROADMAP R10): the `{data:
+    4}` program with the kernel's sums against the float32 reference and
+    against the one-device program with `jnp.take`'s VJP."""
+    tables, one, ref = kernel_runs["data4.tables"], kernel_runs["one.derived"], kernel_runs["reference"]
+    assert one.g.by_dst is None and isinstance(tables.g.by_dst, pk.EdgesByShard)
+    perm = tables.g.by_dst.tables.perm  # stacked by shard, a chip its own: 64 rows x 16 slots in one block
+    assert perm.shape == (4, 1, 1024) and {s.data.shape for s in perm.addressable_shards} == {(1, 1, 1024)}
+    assert len(ref.leaves) == len(one.leaves) == len(tables.leaves) == 34
+    for got, want, tolerance in [(tables, ref, BF16_TOLERANCE), (one, ref, BF16_TOLERANCE),
+                                 (tables, one, SAME_DTYPE_TOLERANCE)]:
+        loss_gap, gnorm_gap = _gaps(got, want)
+        leaf_gap = float(np.max(np.abs(got.leaves - want.leaves) / want.leaves))
+        assert max(loss_gap, gnorm_gap, leaf_gap) < tolerance, (loss_gap, gnorm_gap, leaf_gap)
+    # the tolerances are tight enough that a float32 program's readings differ from bfloat16's
+    assert _gaps(tables, ref)[0] > 5 * F32_TOLERANCE
+
+
+def test_the_per_shard_vjp_on_a_four_device_mesh_is_jnp_takes(monkeypatch):
+    """`neighbor_gather` over a table per row shard against `jnp.take` on one
+    device, for a graph with a hub (a quarter of all slots point at row 3),
+    padded slots at row 0 and a tile of destinations nobody points at: the
+    forward bit for bit, the VJP within bfloat16's order of summation (a
+    chip's float32 sums rounded once, the four chips' added in bfloat16),
+    rows and all sharded over `data` as they came."""
+    from test_gather_vjp import _hub_table
+
+    monkeypatch.setattr(pk, "PLATFORM", "cpu")  # the test's word: these CPU devices take the (interpreted) kernel
+    mesh = meshlib.make_mesh(jax.devices()[:4], model_parallel=1)
+    rows = meshlib.batch_sharding(mesh)
+    for n, k, width, blocks in [(1024, 12, 128, 1), (512, 16, 256, 2)]:
+        case = (n, k, width, blocks)
+        monkeypatch.setattr(pk, "BLOCK_BYTES", n // 4 * k * width * 2 // blocks)
+        nbr = _hub_table(n, k, seed=4)
+        rng = np.random.default_rng(5)
+        h = jnp.asarray(rng.normal(size=(n, width)), jnp.bfloat16)
+        g = jnp.asarray(rng.normal(size=(n, k, width)), jnp.bfloat16)
+        tables, reason = pk.gather_vjp_tables(nbr, width, h.dtype, mesh)
+        assert reason == "" and tables.tables.perm.shape == (4, blocks, n // 4 * k // blocks), case
+        report = pk.gather_vjp_report(tables, nbr.shape, width, h.dtype, mesh)
+        assert report["path"] == "sorted_kernel" and report["shards"] == 4 and report["blocks"] == blocks, case
+        live = np.asarray(tables.tables.live).ravel()
+        assert report["live_windows"] == {"least": live.min(), "most": live.max()}, case
+        placed = jax.device_put((h, jnp.asarray(nbr), tables, g), (rows, rows, jax.tree.map(lambda _: rows, tables), rows))
+        with _kernel_as_xla_ops():
+            out, vjp = jax.vjp(lambda x: neighbor_gather(x, *placed[1:3]), placed[0])
+            got = vjp(placed[3])[0]
+        assert out.sharding.is_equivalent_to(rows, 3) and got.sharding.is_equivalent_to(rows, 2), case
+        np.testing.assert_array_equal(np.asarray(out, np.float32), np.asarray(jnp.take(h, nbr, axis=0), np.float32))
+        want = np.asarray(jax.vjp(lambda x: jnp.take(x, nbr, axis=0), h.astype(jnp.float32))[1](g.astype(jnp.float32))[0])
+        got = np.asarray(got.astype(jnp.float32))
+        np.testing.assert_allclose(got, want, rtol=2.0 ** -6, atol=2.0 ** -6 * np.abs(want).max(), err_msg=str(case))
+        assert not got[3 * n // 4:].any(), case  # nobody points there
 
 
 def test_padding_rows_are_copies_of_node_zero():
@@ -230,7 +383,8 @@ def test_the_run_manifest_names_the_decision(tmp_path):
     assert all(np.isfinite(gnn["evaluation"][k]) for k in ("final_loss",))
     graph = placement["graph"]
     assert len(graph["per_device_bytes"]) == n and max(graph["per_device_bytes"]) * n == graph["bytes"]
-    assert placement["gather_vjp"]["path"] == "derived"
+    assert placement["gather_vjp"]["path"] == "derived"  # what the one rule returned, and why
+    assert placement["gather_vjp"]["reason"] == "cpu devices: the kernel compiles for tpu alone"
     # the export reads parameters that live on every device and a graph that was never placed
     artifact = Path(gnn["artifact"])
     assert {"params.msgpack", "graph.npz", "config.json"} <= {p.name for p in artifact.iterdir()}
