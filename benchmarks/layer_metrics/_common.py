@@ -3,6 +3,8 @@ window of a steady run, the cycles of a retrain window, the idle share."""
 
 from __future__ import annotations
 
+import statistics
+
 
 def scan_calls(ctx: dict) -> list | None:
     """Executions of the scan program that START inside the traced window
@@ -44,11 +46,20 @@ def window_runs(ctx: dict) -> list[tuple[dict, dict]] | None:
     return list(zip(uploads, manifests))
 
 
-def mean(values: list[float]) -> float | None:
+def median(values: list[float]) -> float | None:
+    """The median over the window's cycles, as `retrain_s` is of their whole
+    times: a stage's reading and the end-to-end reading then leave the same
+    paused cycle out."""
     values = [v for v in values if v is not None]
-    return sum(values) / len(values) if values else None
+    return statistics.median(values) if values else None
+
+
+def evaluation(manifest: dict, model: str) -> dict:
+    """`models.<model>.evaluation` of a run manifest, empty where the run
+    trained no such model."""
+    return (manifest["models"].get(model) or {}).get("evaluation") or {}
 
 
 def train_seconds(manifest: dict, model: str) -> float | None:
     """`<model>.evaluation.train_seconds` of a run manifest."""
-    return ((manifest["models"].get(model) or {}).get("evaluation") or {}).get("train_seconds")
+    return evaluation(manifest, model).get("train_seconds")

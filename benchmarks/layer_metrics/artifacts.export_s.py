@@ -1,8 +1,10 @@
-"""What is left of the run's wall time after dataset build, MLP and GNN
-training: saving both artifacts, the embedding pass, scorer.dfsc, the
-digests (export has no span of its own yet). Mean over the window's runs."""
+"""Run manifest `models.<m>.evaluation.export_seconds`, both models' summed:
+what `service._export` timed around each artifact's save (weights, graph,
+sketch, the embedding pass and scorer.dfsc, the digest). In the manifest
+since PR 25; the remainder of the wall time is no longer read. Median over
+the window's cycles."""
 
-from _common import mean, train_seconds, window_runs
+from _common import evaluation, median, window_runs
 
 
 def read(ctx):
@@ -11,8 +13,6 @@ def read(ctx):
         return None
     out = []
     for _, m in runs:
-        stages = m["dataset"]["build_seconds"]
-        for model in ("mlp", "gnn"):
-            stages += train_seconds(m, model) or 0.0
-        out.append(m["wall_s"] - stages)
-    return mean(out)
+        exports = [evaluation(m, model).get("export_seconds") for model in ("mlp", "gnn")]
+        out.append(None if None in exports else sum(exports))
+    return median(out)

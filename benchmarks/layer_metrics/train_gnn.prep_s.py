@@ -1,9 +1,9 @@
 """From the start of `train_gnn.train_async` to its first completed scan
 call: init, placement, re-trace, compile or cache load, and the first call
 itself. Manifest `gnn.evaluation.train_seconds` less the time between the
-run's first and last step report. Mean over the window's runs."""
+run's first and last step report. Median over the window's runs."""
 
-from _common import mean, train_seconds, window_runs
+from _common import median, train_seconds, window_runs
 
 
 def read(ctx):
@@ -17,7 +17,7 @@ def read(ctx):
     spans = [reports[k] for k in sorted(reports)][-len(runs):]
     if len(spans) != len(runs):
         return None
-    return mean([
+    return median([
         (train_seconds(m, "gnn") or 0.0) - (ts[-1] - ts[0])
         for (_, m), ts in zip(runs, spans)
     ])

@@ -9,6 +9,7 @@ process), about 20 s each.
 
 import json
 import os
+import statistics
 import subprocess
 import sys
 from pathlib import Path
@@ -24,15 +25,10 @@ RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 @pytest.fixture(scope="module")
 def tiny(tmp_path_factory):
-    """The repository's BENCHMARK.json, with the retrain cell's entries that
-    are held back from it (held_back/gnn-32k-512.retrain.json) laid in, and its
-    cells swapped for two tiny ones (64 hosts, hidden 32) under the same
-    traffic mixes, metrics and readers; their limits are the ones kept in
-    data/tiny/limits."""
+    """The repository's BENCHMARK.json with its cells swapped for two tiny ones
+    (64 hosts, hidden 32) under the same traffic mixes, metrics and readers;
+    their limits are the ones kept in data/tiny/limits."""
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    held = json.loads((BENCH / "held_back" / "gnn-32k-512.retrain.json").read_text())
-    for section in ("end_to_end", "per_layer"):
-        bench[section] += held[section]
     bench["configs"] = [{"name": "tiny", "source": "benchmarks/tests", "reduced": ["gnn_steps", "mlp_steps"],
                          "file": str((TINY / "tiny.json").relative_to(REPO)), "why": "CPU rehearsal"}]
     bench["workloads"] = [{"name": f"tiny.{t}", "config": "tiny", "traffic": t, "chips": 1, "why": "rehearsal"}
@@ -71,6 +67,14 @@ def test_rehearsal_prints_the_contracts_line_and_no_device_metric(tiny, workload
     # the numbers compared, each beside its limit, close the error stream
     last = list(result["compared"])[-1]
     assert f"compared {last}" in p.stderr.splitlines()[-2]
+    if workload == "tiny.retrain":
+        # --seconds 1 has passed after one cycle: the window still holds the
+        # mix's `min_runs`, and the reading is their median, every cycle kept
+        cycles = result["detail"]["cycles_s"]
+        min_runs = json.loads((BENCH / "traffic" / "retrain.json").read_text())["min_runs"]
+        assert len(cycles) == min_runs >= 3 and result["attempted"] == len(cycles) + 1
+        assert result["rehearsal"]["read"]["retrain_s"]["value"] == statistics.median(cycles)
+        assert result["detail"]["cycles_over_5pct"] == sum(c > 1.05 * statistics.median(cycles) for c in cycles)
 
 
 def test_no_chip_is_an_error_not_a_fallback(tiny):

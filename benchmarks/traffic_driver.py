@@ -6,8 +6,12 @@ window. A mix is parameters only:
                   training run it starts, from one completed scan call to a
                   later one (metric: train_steps_per_s);
                   "runs": whole upload-to-published-model cycles, back to back
-                  until the window's seconds have passed, the one in flight
-                  finished and counted (metric: retrain_s)
+                  until the window's seconds have passed and `min_runs` cycles
+                  are done, the one in flight finished and counted (metric:
+                  retrain_s, the median cycle)
+  min_runs        ("runs") the fewest cycles a window holds, however long they
+                  take: a median over fewer than three is one cycle's reading,
+                  and over five it leaves two slow cycles out
   runs_in_setup   whole cycles completed before the window opens (the cold one)
   warm_calls      scan calls of the measured run that belong to set-up
   mlp_steps       `--mlp-steps` for the server, null for what ships
@@ -193,7 +197,7 @@ class Driver:
             runs.append(up)
             if self.trace is not None and len(runs) == self.traffic["trace_runs"]:
                 self._trace_stop()
-            if up["t_done"] - t_start >= self.seconds:
+            if up["t_done"] - t_start >= self.seconds and len(runs) >= self.traffic["min_runs"]:
                 break
         events = self.trainer.ctl("steps", since=0)["events"]
         return {
