@@ -94,7 +94,7 @@ def as_reports(ref: dict, steps: list[int]) -> list[tuple]:
     return [(n, ref["loss"][n - 1], ref["grad_norm"][n - 1]) for n in steps]
 
 
-def readings_for(spec: dict, reference, records) -> tuple[dict, dict]:
+def readings_for(spec: dict, reference, feeders: list) -> tuple[dict, dict]:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -105,17 +105,18 @@ def readings_for(spec: dict, reference, records) -> tuple[dict, dict]:
     timing: dict = {}
     out: dict = {}
 
-    # the run that is checked in full is the last one; it trained on the
-    # pool as it stood after that many uploads of the same records
-    run = next((r for r in reversed(program["runs"]) if (r.get("models") or {}).get("gnn")), None)
-    if run is None:
+    # the window says which run is checked in full, and which feeders'
+    # commits, in their order, the pool it trained on held
+    checked = program["checked"]
+    if checked is None:
         return {"error": "no run published a gnn model"}, timing
+    run = program["runs"][checked["run"]]
     artifact = Path(run["models"]["gnn"]["artifact"])
     cl = config["cluster"]
     t = time.monotonic()
-    dataset = reference.build_dataset(
-        *records, num_neighbors=model["num_neighbors"], uploads=program["runs"].index(run) + 1,
-        chunk_rows=cl["chunk_rows"], pool_rows_cap=cl["pool_rows_cap"])
+    commits = [(feeders[i]["downloads"], feeders[i]["probes"]) for i in checked["commits"]]
+    dataset = reference.build_dataset(commits=commits, num_neighbors=model["num_neighbors"],
+                                      chunk_rows=cl["chunk_rows"], pool_rows_cap=cl["pool_rows_cap"])
     timing["reference_dataset_s"] = time.monotonic() - t
 
     # ---- dataset build ----
@@ -141,11 +142,11 @@ def readings_for(spec: dict, reference, records) -> tuple[dict, dict]:
     t = time.monotonic()
     ref = reference.follow_steps(config, dataset, spc)
     timing["reference_steps_s"] = time.monotonic() - t
-    # every training run of the window that trained on the same pool is held
-    # to the same first call (at the cell's size the pool is full after one
-    # upload, so that is every run)
-    trained = [r for r in program["runs"] if (r.get("models") or {}).get("gnn")]
-    same_pool = {i for i, r in enumerate(trained) if r["dataset"]["pairs"] == run["dataset"]["pairs"]}
+    # every training run that the window says trained on the same pool is
+    # held to the same first call
+    trained_at = [i for i, r in enumerate(program["runs"]) if (r.get("models") or {}).get("gnn")]
+    trained = [program["runs"][i] for i in trained_at]
+    same_pool = {k for k, i in enumerate(trained_at) if i in checked["same_pool"]}
     reports = [r for i, r in enumerate(step_reports(program, "gnn", spc)) if i in same_pool]
     out.update(loss_gap_max=None, gnorm_gap_first=None, gnorm_gap_max=None)
     if reports and all([n for n, _, _ in r] == list(range(1, spc + 1)) for r in reports):
@@ -263,11 +264,11 @@ def main(argv: list[str]) -> int:
         return 1
 
     import reference
-    import telemetry_gen
     import trace_reduce
+    from traffic_driver import find_named, load_file
 
-    records = telemetry_gen.generate_for(spec["config"]["cluster"], spec["seed"])
-    readings, timing = readings_for(spec, reference, records)
+    generator = load_file(find_named(spec["roots"], "generators", f"{spec['config']['generator']}.py"))
+    readings, timing = readings_for(spec, reference, generator.generate(spec["config"]["cluster"], spec["seed"]))
     trace = None
     if spec["trace_dir"]:
         t = time.monotonic()

@@ -37,7 +37,9 @@ configuration states); everything else stays as it is.
 
 from __future__ import annotations
 
+import collections
 import json
+import math
 import struct
 from functools import partial
 from pathlib import Path
@@ -62,42 +64,49 @@ def _intern(seq: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return uniq[order], rank[inv]
 
 
-def pool_chunks_kept(uploads: int, chunk_pairs: list[int], cap: int) -> int:
-    """How many of the newest pair chunks the trainer's rolling pool holds
-    after `uploads` uploads of the same chunks: after every upload the oldest
-    whole chunks go while the rest alone still covers `cap` rows (0: no cap)."""
-    pool: list[int] = []
-    for _ in range(uploads):
-        pool += chunk_pairs
-        while cap > 0 and len(pool) > 1 and sum(pool) - pool[0] >= cap:
-            pool.pop(0)
-    return len(pool)
+def _cat(arrays: list) -> np.ndarray:
+    return arrays[0] if len(arrays) == 1 else np.concatenate(arrays)
 
 
-def build_dataset(downloads: np.ndarray, probes: np.ndarray, *, num_neighbors: int,
-                  uploads: int = 1, chunk_rows: int | None = None, pool_rows_cap: int = 0) -> dict:
-    """Raw records -> host table, padded neighbour graph, pair pool, as they
-    stand after the same records were uploaded `uploads` times.
+def build_dataset(downloads: np.ndarray | None = None, probes: np.ndarray | None = None, *, num_neighbors: int,
+                  uploads: int = 1, commits: list | None = None, chunk_rows: int | None = None,
+                  pool_rows_cap: int = 0) -> dict:
+    """Raw records -> host table, padded neighbour graph, pair pool, as the
+    trainer's pool stands after `commits`, a list of (downloads, probes) in
+    the order they were committed; `uploads=n` of one pair of arrays is that
+    pair committed n times.
 
-    Hosts are numbered by first occurrence over the upload as it is streamed:
-    child then parent of every successful download that has a parent, then
-    source then destination of every probe. Probe rows of one (src, dst) are
-    averaged; each source keeps its `num_neighbors` lowest mean RTTs (ties by
-    arrival). Node features 1 and 5 are a parent's upload success rate and
-    mean normalised bandwidth over all its download rows; pairs are the
-    successful downloads, labelled min(1, bandwidth / GiB)."""
+    Hosts are numbered by first occurrence over the commits as they are
+    streamed: child then parent of every successful download that has a
+    parent, then source then destination of every probe, commit by commit.
+    Probe rows of one (src, dst) are averaged; each source keeps its
+    `num_neighbors` lowest mean RTTs (ties by arrival). Node features 1 and 5
+    are a parent's upload success rate and mean normalised bandwidth over all
+    its download rows; pairs are the successful downloads, labelled
+    min(1, bandwidth / GiB). Commits of the same arrays are read once and
+    weighted by how often they came, less the common factor: n commits of one
+    upload have the means and rates of one."""
     k = num_neighbors
+    commits = [(downloads, probes)] * uploads if commits is None else commits
+    times = collections.Counter((id(d), id(p)) for d, p in commits)
+    common = math.gcd(*times.values())
+    parts = list({(id(d), id(p)): (d, p, times[id(d), id(p)] // common) for d, p in commits}.values())
+    downloads, probes = _cat([d for d, _, _ in parts]), _cat([p for _, p, _ in parts])
+    w_down = _cat([np.full(len(d), w, np.float64) for d, _, w in parts])
+    w_probe = _cat([np.full(len(p), w, np.float64) for _, p, w in parts])
     ok = downloads["success"] & (downloads["parent_host_id"] != b"")
-    ids = np.empty(2 * int(ok.sum()) + 2 * len(probes), dtype=downloads["child_host_id"].dtype)
-    n_pair_ids = 2 * int(ok.sum())
-    ids[0:n_pair_ids:2] = downloads["child_host_id"][ok]
-    ids[1:n_pair_ids:2] = downloads["parent_host_id"][ok]
-    ids[n_pair_ids::2] = probes["src_host_id"]
-    ids[n_pair_ids + 1 :: 2] = probes["dst_host_id"]
-    hosts, codes = _intern(ids)
+    ids, is_probe, at = [], [], 0
+    for d, p, _ in parts:
+        part_ok = ok[at : at + len(d)]
+        at += len(d)
+        ids += [np.stack([d["child_host_id"][part_ok], d["parent_host_id"][part_ok]], 1).reshape(-1),
+                np.stack([p["src_host_id"], p["dst_host_id"]], 1).reshape(-1)]
+        is_probe += [np.zeros(len(ids[-2]), bool), np.ones(len(ids[-1]), bool)]
+    hosts, codes = _intern(_cat(ids))
+    is_probe = _cat(is_probe)
     n = max(len(hosts), 8)
-    child, parent = codes[0:n_pair_ids:2], codes[1:n_pair_ids:2]
-    src, dst = codes[n_pair_ids::2], codes[n_pair_ids + 1 :: 2]
+    pair_codes, probe_codes = codes[~is_probe], codes[is_probe]
+    child, parent, src, dst = pair_codes[0::2], pair_codes[1::2], probe_codes[0::2], probe_codes[1::2]
 
     # edges: mean of each statistic per (src, dst), first-occurrence order
     _, edge_of_row = _intern((src << 32) | dst)
@@ -105,8 +114,8 @@ def build_dataset(downloads: np.ndarray, probes: np.ndarray, *, num_neighbors: i
     stats = np.stack([
         probes["rtt_mean_ms"], probes["rtt_std_ms"], probes["rtt_min_ms"], probes["probe_count"],
     ], axis=1).astype(np.float64)
-    count = np.bincount(edge_of_row, minlength=m)
-    mean = np.stack([np.bincount(edge_of_row, weights=stats[:, c], minlength=m) for c in range(4)], 1)
+    count = np.bincount(edge_of_row, weights=w_probe, minlength=m)
+    mean = np.stack([np.bincount(edge_of_row, weights=stats[:, c] * w_probe, minlength=m) for c in range(4)], 1)
     mean /= np.maximum(count, 1)[:, None]
     first_row = np.full(m, len(edge_of_row), np.int64)
     np.minimum.at(first_row, edge_of_row, np.arange(len(edge_of_row)))
@@ -138,10 +147,11 @@ def build_dataset(downloads: np.ndarray, probes: np.ndarray, *, num_neighbors: i
     known = sorted_hosts[pos] == parent_ids
     pcode = sorter[pos][known]
     succ = downloads["success"][has_parent][known]
+    weight = w_down[has_parent][known]
     bw = np.minimum(1.0, downloads["bandwidth_bps"][has_parent][known].astype(np.float64) / GIB)
-    total = np.bincount(pcode, minlength=n).astype(np.float64)
-    n_succ = np.bincount(pcode[succ], minlength=n).astype(np.float64)
-    bw_sum = np.bincount(pcode[succ], weights=bw[succ], minlength=n)
+    total = np.bincount(pcode, weights=weight, minlength=n)
+    n_succ = np.bincount(pcode[succ], weights=weight[succ], minlength=n)
+    bw_sum = np.bincount(pcode[succ], weights=(bw * weight)[succ], minlength=n)
     served = total > 0
     node_feats[served, 1] = n_succ[served] / total[served]
     node_feats[served, 5] = bw_sum[served] / total[served]
@@ -151,16 +161,24 @@ def build_dataset(downloads: np.ndarray, probes: np.ndarray, *, num_neighbors: i
         "child": child.astype(np.int32), "parent": parent.astype(np.int32),
         "feats": downloads["pair_features"][ok].astype(np.float32), "label": label,
     }
-    # the rolling pair pool: a re-sent upload appends the same chunks again
-    # (means and rates of the graph do not move: sums and counts grow alike)
-    chunk_rows = chunk_rows or len(downloads)
-    chunk_pairs = [int(ok[i : i + chunk_rows].sum()) for i in range(0, len(downloads), chunk_rows)]
-    chunk_pairs = [c for c in chunk_pairs if c]
-    kept = pool_chunks_kept(uploads, chunk_pairs, pool_rows_cap)
-    whole, part = divmod(kept, len(chunk_pairs))
-    tail = sum(chunk_pairs[len(chunk_pairs) - part :]) if part else 0
-    n_pairs = len(label)
-    pairs = {k: np.concatenate([v[n_pairs - tail :]] + [v] * whole) for k, v in pairs.items()}
+    # the rolling pair pool: a commit appends its chunks' pair rows, then the
+    # oldest whole chunks go while the rest alone still covers the cap (0: none)
+    chunks_of, at_row, at_pair = {}, 0, 0
+    for d, p, _ in parts:
+        rows = chunk_rows or len(d)
+        sizes = [int(ok[at_row + i : at_row + min(i + rows, len(d))].sum()) for i in range(0, len(d), rows)]
+        edges = at_pair + np.cumsum([0] + [s for s in sizes if s])
+        chunks_of[id(d), id(p)] = list(zip(edges[:-1], edges[1:]))
+        at_row, at_pair = at_row + len(d), edges[-1]
+    pool, held = [], 0
+    for d, p in commits:
+        pool += chunks_of[id(d), id(p)]
+        held += sum(b - a for a, b in chunks_of[id(d), id(p)])
+        while pool_rows_cap > 0 and len(pool) > 1 and held - (pool[0][1] - pool[0][0]) >= pool_rows_cap:
+            held -= pool[0][1] - pool[0][0]
+            pool.pop(0)
+    kept = _cat([np.arange(a, b) for a, b in pool])
+    pairs = {k: v[kept] for k, v in pairs.items()}
     return {
         "hosts": hosts, "node_feats": node_feats, "neighbors": neighbors, "mask": mask,
         "edge_feats": edge_feats, "pairs": pairs,

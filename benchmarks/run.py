@@ -10,8 +10,16 @@ surface, times the window, stops it, and then has post_child.py run the plain
 float32 reference (and, with --trace 1, reduce the profiler's trace). The last
 line of stdout is the result object; nothing else is printed there.
 
-What belongs to one cell is data found by name: configs/<config>.json,
-traffic/<traffic>.json, limits/<workload>.json, layer_metrics/<metric>.py.
+What belongs to one cell is data found by name: configs/<config>.json (sizes,
+and under "generator" the name of what makes its records),
+generators/<generator>.py (`generate(cluster, seed)` -> the feeders: who sends
+which downloads and probes), traffic/<traffic>.json (parameters, and under
+"window" the name of its kind of window), windows/<window>.py (how the window
+drives the feeders, what it reads end to end, its part of set-up, the traced
+stretch, and which commits the checked run's pool held: traffic_driver.py
+lists what a module provides), limits/<workload>.json,
+layer_metrics/<metric>.py. No name has a default: a missing key or file is an
+error that names what was looked for (exit 1, no result line).
 No chip, a device kind without published peaks, or fewer chips than the cell
 asks for is an error (exit 1, no result line), unless --cpu-rehearsal is
 given: then the run is a rehearsal of the control flow, says so, and prints no
@@ -26,11 +34,9 @@ T_PROCESS_START = time.monotonic()
 
 import argparse  # noqa: E402
 import asyncio  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
-import statistics  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -39,6 +45,8 @@ HERE = Path(__file__).resolve().parent
 REPO = HERE.parent
 sys.path.insert(0, str(HERE))
 sys.path.insert(1, str(REPO))
+
+from traffic_driver import Driver, find_named, load_file, server_step_flags  # noqa: E402  (no jax there)
 
 # one run may take 360 s (1200 s the first time in a checkout, which compiles)
 RUN_BUDGET_S = 1100.0
@@ -60,14 +68,18 @@ def load_cell(benchmark_json: Path, workload: str) -> dict:
         raise SystemExit(f"no workload {workload!r} in {benchmark_json}")
     cfg_entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     here = REPO / bench["paths"][0]
-    # the tests' tiny cells keep their limits beside their own BENCHMARK.json
-    own_limits = benchmark_json.resolve().parent / "limits"
-    limits_dir = own_limits if own_limits.is_dir() else here / "limits"
+    # the tests' cells keep their limits, and a test deployment its mix,
+    # generator and window, beside their own BENCHMARK.json
+    roots = [benchmark_json.resolve().parent, here]
+    config = json.loads((REPO / cfg_entry["file"]).read_text())
+    traffic = json.loads(find_named(roots, "traffic", f"{cell['traffic']}.json").read_text())
+    if "generator" not in config:
+        raise SystemExit(f"{cfg_entry['file']} names no \"generator\" (a file of generators/)")
     return {
-        "bench": bench, "cell": cell,
-        "config": json.loads((REPO / cfg_entry["file"]).read_text()),
-        "traffic": json.loads((here / "traffic" / f"{cell['traffic']}.json").read_text()),
-        "limits": json.loads((limits_dir / f"{workload}.json").read_text()),
+        "bench": bench, "cell": cell, "config": config, "traffic": traffic, "roots": [str(r) for r in roots],
+        "generator": load_file(find_named(roots, "generators", f"{config['generator']}.py")),
+        "window": load_file(find_named(roots, "windows", f"{traffic['window']}.py")),
+        "limits": json.loads(find_named(roots, "limits", f"{workload}.json").read_text()),
         "layer_dir": here / "layer_metrics",
     }
 
@@ -86,11 +98,7 @@ def metrics_of(bench: dict, section: str, workload: str, reported_e2e: set[str] 
 
 
 def read_layer_metric(layer_dir: Path, name: str, ctx: dict):
-    path = layer_dir / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"layer_metric_{abs(hash(name))}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read(ctx)
+    return load_file(layer_dir / f"{name}.py").read(ctx)
 
 
 def run_post_child(work: Path, spec: dict, timeout: float) -> dict:
@@ -121,10 +129,8 @@ def run_post_child(work: Path, spec: dict, timeout: float) -> dict:
     raise RuntimeError(f"post child rc={proc.returncode} gave no result:\n{tail}")
 
 
-async def drive(trainer, address: str, cell: dict, records, args, trace_dir, deadline) -> dict:
+async def drive(trainer, address: str, cell: dict, feeders, args, trace_dir, deadline) -> dict:
     from dragonfly2_tpu.rpc.trainer import RemoteTrainerClient
-
-    import traffic_driver
 
     client = RemoteTrainerClient(address)
     try:
@@ -138,30 +144,26 @@ async def drive(trainer, address: str, cell: dict, records, args, trace_dir, dea
                 raise RuntimeError(f"no published peaks for device kind {device['kind']!r}")
             if device["count"] < cell["cell"]["chips"]:
                 raise RuntimeError(f"{device['count']} chips, the cell asks for {cell['cell']['chips']}")
-        driver = traffic_driver.Driver(
-            client, trainer, cell["config"], cell["traffic"], records,
-            seconds=args.seconds, trace_dir=trace_dir, deadline=deadline,
-        )
-        window = await driver.run()
-        history = await client.train_history(limit=64)
+        driver = Driver(client, trainer, cell["config"], cell["traffic"], feeders,
+                        seconds=args.seconds, trace_dir=trace_dir, deadline=deadline)
+        window = await driver.run(cell["window"])
+        runs = (await client.train_history(limit=64))["runs"][::-1]
     finally:
         await client.close()
-    return {"device": device, "window": window, "runs": history["runs"][::-1]}
+    return {"device": device, "window": window, "runs": runs, "checked": cell["window"].checked(window, runs)}
 
 
 def measure(args, cell: dict, work: Path) -> dict:
     """Generate the records, start the trainer, drive the window, stop the
     trainer. Returns what the window saw, with the set-up's timeline."""
-    import telemetry_gen
-    import traffic_driver
     from serverproc import TrainerProcess
 
     config, traffic = cell["config"], cell["traffic"]
     deadline = T_PROCESS_START + RUN_BUDGET_S
     t0 = time.monotonic()
-    records = telemetry_gen.generate_for(config["cluster"], args.seed)
+    feeders = cell["generator"].generate(config["cluster"], args.seed)
     t_generated = time.monotonic()
-    gnn_steps, mlp_steps = traffic_driver.server_step_flags(config, traffic, args.seconds)
+    gnn_steps, mlp_steps = server_step_flags(config, traffic, args.seconds)
     flags = ["--port", "0", "--model-dir", str(work / "models"),
              "--gnn-steps", str(gnn_steps), "--mlp-steps", str(mlp_steps), *config["server_flags"]]
     env = {}
@@ -178,7 +180,7 @@ def measure(args, cell: dict, work: Path) -> dict:
     try:
         address = trainer.wait_ready(deadline)
         t_ready = time.monotonic()
-        out = asyncio.run(drive(trainer, address, cell, records, args,
+        out = asyncio.run(drive(trainer, address, cell, feeders, args,
                                 work / "trace" if args.trace else None, deadline))
         out["compiles"] = trainer.ctl("compiles")["events"]
     except Exception:
@@ -192,42 +194,17 @@ def measure(args, cell: dict, work: Path) -> dict:
     peaks_seen = [r["device_peak_bytes"] for r in runs if r.get("device_peak_bytes")]
     out["device"]["memory_peak_bytes"] = max(peaks_seen) if peaks_seen else None
 
-    split = {"python_start_s": t0 - T_PROCESS_START, "generate_s": t_generated - t0,
-             "server_ready_s": t_ready - t_generated}
-    first_up = window["uploads"][0]
-    gnn_reports = [e[0] for e in window["step_events"] if e[2] == "gnn"]
-    if window["kind"] == "scan_calls" and gnn_reports:
-        # upload; then dataset build, MLP stage, GNN init + placement + compile
-        # or cache load + first scan call; then the other warm calls
-        split.update(
-            upload_s=first_up["t_closed"] - first_up["t_open"],
-            close_to_first_scan_call_s=gnn_reports[0] - first_up["t_closed"],
-            warm_calls_s=window["window_start"] - gnn_reports[0],
-        )
-    elif window["kind"] == "runs":
-        split["cold_cycles_s"] = window["window_start"] - t_ready
-    out["setup_split"] = split
+    out["setup_split"] = {"python_start_s": t0 - T_PROCESS_START, "generate_s": t_generated - t0,
+                          "server_ready_s": t_ready - t_generated, **cell["window"].setup_split(window, t_ready)}
     return out
 
 
-def end_to_end(window: dict, traffic: dict, runs: list) -> tuple[dict, dict, int, int]:
-    """(end-to-end metrics by the host's clock, detail, attempted, failed)."""
-    e2e = {"setup_s": window["window_start"] - T_PROCESS_START}
-    detail = {"window_s": window["window_s"]}
-    if window["kind"] == "scan_calls":
-        e2e["train_steps_per_s"] = window["steps"] / window["window_s"]
-        detail["steps"] = window["steps"]
-        attempted = len(window["uploads"])
-    else:
-        # the median cycle, each from its `train_open` to the poll that saw its
-        # model published: one cycle that the machine paused (PERF.md, section
-        # 6) is then not the window's reading; every cycle stays in `detail`
-        cycles = [u["t_done"] - u["t_open"] for u in window["uploads"]]
-        e2e["retrain_s"] = statistics.median(cycles)
-        detail.update(cycles_s=cycles, ingest_s=[u["t_closed"] - u["t_open"] for u in window["uploads"]],
-                      cycles_over_5pct=sum(1 for c in cycles if c > 1.05 * e2e["retrain_s"]),
-                      mean_cycle_s=window["window_s"] / len(cycles))
-        attempted = len(window["uploads"]) + traffic["runs_in_setup"]
+def end_to_end(kind, window: dict, traffic: dict, runs: list) -> tuple[dict, dict, int, int]:
+    """(end-to-end metrics by the host's clock, detail, attempted, failed);
+    `kind` is the window's module, which says what the window reads."""
+    read, more, attempted = kind.end_to_end(window, traffic)
+    e2e = {"setup_s": window["window_start"] - T_PROCESS_START, **read}
+    detail = {"window_s": window["window_s"], **more}
     failed = sum(1 for r in runs if r["status"] != "ok") + max(0, attempted - len(runs))
 
     def of_model(r: dict, m: str, key: str):
@@ -253,7 +230,8 @@ def per_layer(cell: dict, workload: str, e2e_names: set, out: dict, post: dict, 
     if post.get("trace"):
         compact = json.loads(Path(post["trace"]["compact"]).read_text())
         spans = trace_reduce.read_spans(work / "spans.jsonl")
-        view = trace_reduce.view_for(compact, config, traffic, out["window"], spans)
+        stretch = cell["window"].traced_stretch(out["window"], config, traffic)
+        view = trace_reduce.view_for(compact, stretch, out["window"], spans)
         device["busy_s"] = view.busy_s()
         device["window_s"] = view.window_s
         breakdown = {"device_ops": view.top_ops(), "idle_gaps": view.top_gaps()}
@@ -306,18 +284,18 @@ def main(argv: list[str] | None = None) -> int:
 
 def one_run(args, cell: dict, work: Path) -> dict:
     import correct as correctlib
-    import traffic_driver
 
     out = measure(args, cell, work)
     window, runs, device = out["window"], out["runs"], out["device"]
-    e2e, detail, attempted, failed = end_to_end(window, cell["traffic"], runs)
+    e2e, detail, attempted, failed = end_to_end(cell["window"], window, cell["traffic"], runs)
 
     # the reference, and the trace's reduction, once the trainer has gone
-    (work / "program.json").write_text(json.dumps({"runs": runs, "step_events": window["step_events"]}))
+    (work / "program.json").write_text(json.dumps(
+        {"runs": runs, "step_events": window["step_events"], "checked": out["checked"]}))
     post = run_post_child(work, {
         "work": str(work), "config": cell["config"], "traffic": cell["traffic"], "seed": args.seed,
-        "trace_dir": str(work / "trace") if args.trace else None,
-        "mlp_steps": traffic_driver.server_step_flags(cell["config"], cell["traffic"], args.seconds)[1],
+        "roots": cell["roots"], "trace_dir": str(work / "trace") if args.trace else None,
+        "mlp_steps": server_step_flags(cell["config"], cell["traffic"], args.seconds)[1],
         "control": args.control, "cpu_rehearsal": args.cpu_rehearsal,
     }, timeout=max(60.0, T_PROCESS_START + RUN_BUDGET_S - time.monotonic()))
     compared, correct = correctlib.judge(post["readings"], cell["limits"])

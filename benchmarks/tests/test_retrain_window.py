@@ -18,6 +18,7 @@ import run as harness  # noqa: E402
 import traffic_driver  # noqa: E402
 
 TRAFFIC = {"window": "runs", "runs_in_setup": 1, "min_runs": 3, "trace_runs": 1}
+RUNS = traffic_driver.load_file(BENCH / "windows" / "runs.py")
 
 
 def window_of(cycles_s, ingest_s=1.0, gap_s=0.0):
@@ -44,7 +45,7 @@ def manifest(stall_ms=0.0):
 def test_retrain_s_is_the_median_cycle_and_detail_keeps_every_cycle(cycles_s, slow):
     window = window_of(cycles_s, gap_s=0.5)
     runs = [manifest() for _ in range(len(cycles_s) + 1)]
-    e2e, detail, attempted, failed = harness.end_to_end(window, TRAFFIC, runs)
+    e2e, detail, attempted, failed = harness.end_to_end(RUNS, window, TRAFFIC, runs)
     sound = sorted(cycles_s)[: len(cycles_s) - slow]
     assert sound[0] - 1e-9 <= e2e["retrain_s"] <= sound[-1] + 1e-9
     assert e2e["retrain_s"] == pytest.approx(statistics.median(cycles_s))
@@ -110,10 +111,10 @@ class FakeTrainer:
 def test_a_runs_window_does_not_close_before_min_runs_nor_before_its_seconds(seconds, min_runs, cycles):
     client = FakeClient(cycle_s=0.03)
     config = {"cluster": {"chunk_rows": 4}}
+    feeder = {"hostname": "feeder", "scheduler_id": 0, "downloads": list(range(8)), "probes": list(range(8))}
     driver = traffic_driver.Driver(client, FakeTrainer(), config, {**TRAFFIC, "runs_in_setup": 0, "min_runs": min_runs},
-                                   (list(range(8)), list(range(8))), seconds=seconds, trace_dir=None,
-                                   deadline=time.monotonic() + 60.0)
-    window = asyncio.run(driver.run())
+                                   [feeder], seconds=seconds, trace_dir=None, deadline=time.monotonic() + 60.0)
+    window = asyncio.run(driver.run(RUNS))
     assert window["kind"] == "runs" and len(window["uploads"]) >= min_runs
     assert window["window_s"] >= seconds
     if cycles is not None:

@@ -42,10 +42,10 @@ def test_trace_stop_alone_is_given_the_budgets_limit_and_its_time_is_kept():
 
     trainer = FakeTrainer()
     deadline = time.monotonic() + 900.0
-    driver = traffic_driver.Driver(None, trainer, {}, {}, (None, None), seconds=1.0,
+    driver = traffic_driver.Driver(None, trainer, {}, {}, [], seconds=1.0,
                                    trace_dir=Path("/nowhere"), deadline=deadline)
-    driver._trace_start()
-    driver._trace_stop()
+    driver.trace_start()
+    driver.trace_stop()
     (start, start_kw), (stop, stop_kw) = trainer.asked
     assert (start, stop) == ("trace_start", "trace_stop")
     assert "timeout" not in start_kw            # `ctl`'s own default: a question that is answered at once

@@ -269,8 +269,9 @@ def reduce_run(spec: dict) -> dict | None:
     return {"compact": str(out_path)}
 
 
-def view_for(compact: dict, config: dict, traffic: dict, window: dict, spans: list) -> TraceView:
-    """The TraceView of a run's traced window (parent's half)."""
+def view_for(compact: dict, stretch: tuple, window: dict, spans: list) -> TraceView:
+    """The TraceView of a run's traced window (parent's half); `stretch` is
+    what the window's module says of it: `window_of`'s program name and host window."""
     trace = window["trace"]
     clock = {}
     if compact.get("marker_ns") is not None:
@@ -283,9 +284,5 @@ def view_for(compact: dict, config: dict, traffic: dict, window: dict, spans: li
          "duration_ms": (u["t_closed"] - u["t_open"]) * 1e3}
         for u in window["uploads"]
     ]
-    if traffic["window"] == "scan_calls":
-        a, b = window_of(compact, trace, config["scan_program"], None)
-    else:
-        run = window["uploads"][traffic["trace_runs"] - 1]
-        a, b = window_of(compact, trace, None, (window["uploads"][0]["t_open"], run["t_done"]))
+    a, b = window_of(compact, trace, *stretch)
     return TraceView(compact, a, b, spans=spans, clock=clock)

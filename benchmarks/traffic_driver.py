@@ -1,18 +1,35 @@
-"""The one general traffic driver: reads a traffic mix (benchmarks/traffic/*.json)
-and a configuration, feeds the trainer over its RPC surface and times the
-window. A mix is parameters only:
+"""What every window shares: the RPC surface as a scheduler uses it (one
+feeder's records, chunked as the announcer chunks them, cluster.chunk_rows
+rows a trip), the wait for a run's end, the trace's start and stop, the
+server's step flags, and how a cell's files are found by name and loaded.
 
-  window          "scan_calls": one upload, and the window lies inside the
-                  training run it starts, from one completed scan call to a
-                  later one (metric: train_steps_per_s);
-                  "runs": whole upload-to-published-model cycles, back to back
-                  until the window's seconds have passed and `min_runs` cycles
-                  are done, the one in flight finished and counted (metric:
-                  retrain_s, the median cycle)
+A deployment brings, in the order a `model_config` builder needs them:
+
+  generators/<config's "generator">.py    `generate(cluster, seed)` -> the
+      feeders, a list of {"hostname", "scheduler_id", "downloads", "probes"}
+      (the RPC surface's record arrays): what is sent, and by whom. No jax,
+      nothing of the program: the feeder never opens the accelerator, and the
+      reference reads the same records. Every count that sets a compiled shape
+      is fixed by the configuration, never drawn (telemetry_gen.py says why).
+  traffic/<traffic>.json                  a mix: parameters only (below).
+  windows/<mix's "window">.py             all the harness knows of one kind of
+      window: `drive(driver)` feeds the trainer through a Driver, opens and
+      closes the window and returns it ("kind", "window_start", "window_stop",
+      "window_s", "uploads", "step_events", "trace"); `end_to_end(window,
+      traffic)` -> (the metric it reads, detail, attempted);
+      `setup_split(window, t_ready)` -> its part of set-up's timeline;
+      `traced_stretch(window, config, traffic)` -> (scan program's name, or
+      None and the host's (t0, t1)): the traced run's window;
+      `checked(window, runs)` -> {"run", "commits", "same_pool"}: the run the
+      reference follows, the feeders whose commits its pool held, in the order
+      they were committed, and the runs that trained on the same pool.
+
+A mix's parameters (each window's module says which it reads):
+
   min_runs        ("runs") the fewest cycles a window holds, however long they
                   take: a median over fewer than three is one cycle's reading,
                   and over five it leaves two slow cycles out
-  runs_in_setup   whole cycles completed before the window opens (the cold one)
+  runs_in_setup   whole cycles of feeder 0 completed before the window opens
   warm_calls      scan calls of the measured run that belong to set-up
   mlp_steps       `--mlp-steps` for the server, null for what ships
   gnn_steps       `--gnn-steps`: null for what ships (configuration's
@@ -21,14 +38,12 @@ window. A mix is parameters only:
                   window after the warm calls, whatever the program's speed (a
                   run that ends early closes the window on its last call)
   trace_seconds / trace_runs   how much of the window a --trace 1 run traces
-
-Every upload sends the same seeded records, chunked as the scheduler's
-announcer chunks them (cluster.chunk_rows rows a trip).
 """
 
 from __future__ import annotations
 
 import asyncio
+import importlib.util
 import math
 import time
 from pathlib import Path
@@ -45,6 +60,36 @@ TRACE_STOP_FLOOR_S = 120.0
 def trace_stop_limit_s(deadline: float, now: float) -> float:
     """Seconds the trainer's child may take to answer `trace_stop`."""
     return max(TRACE_STOP_FLOOR_S, deadline - now - TRACE_STOP_RESERVE_S)
+
+
+def find_named(roots: list, kind: str, file_name: str) -> Path:
+    """`<kind>/<file_name>` under the first of `roots` that has it (a test's
+    own deployment beside its BENCHMARK.json, then the benchmark's)."""
+    for path in (Path(root) / kind / file_name for root in roots):
+        if path.is_file():
+            return path
+    raise SystemExit(f"no {kind}/{file_name} under {' or '.join(map(str, roots))}")
+
+
+def load_file(path: Path):
+    """The module a cell names (a generator, a window, a metric's reader)."""
+    spec = importlib.util.spec_from_file_location(f"{path.parent.name}_{abs(hash(str(path)))}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def resent_commits(window: dict, runs: list) -> dict | None:
+    """`checked` of a window that sends feeder 0's records again and again:
+    the reference follows the last run that published a GNN; its pool held one
+    commit of feeder 0 for every run up to it; the runs with as many pair rows
+    trained on the same pool (at the cells' sizes one upload fills it: all)."""
+    trained = [i for i, r in enumerate(runs) if (r.get("models") or {}).get("gnn")]
+    if not trained:
+        return None
+    pairs = runs[trained[-1]]["dataset"]["pairs"]
+    return {"run": trained[-1], "commits": [0] * (trained[-1] + 1),
+            "same_pool": [i for i in trained if runs[i]["dataset"]["pairs"] == pairs]}
 
 
 def server_step_flags(config: dict, traffic: dict, seconds: float) -> tuple[int, int]:
@@ -65,24 +110,27 @@ def server_step_flags(config: dict, traffic: dict, seconds: float) -> tuple[int,
 
 
 class Driver:
-    def __init__(self, client, trainer, config: dict, traffic: dict, records, *,
+    def __init__(self, client, trainer, config: dict, traffic: dict, feeders: list, *,
                  seconds: float, trace_dir: Path | None, deadline: float):
         self.client, self.trainer = client, trainer
-        self.config, self.traffic = config, traffic
-        self.downloads, self.probes = records
+        self.config, self.traffic, self.feeders = config, traffic, feeders
         self.seconds, self.trace_dir, self.deadline = seconds, trace_dir, deadline
         self.uploads = 0
         self.trace: dict | None = None
 
     # ---- the RPC surface, as a scheduler uses it ----
 
-    async def upload(self) -> dict:
+    async def send(self, token: str, feeder: dict) -> None:
         rows = self.config["cluster"]["chunk_rows"]
+        for kind in ("downloads", "probes"):
+            for start in range(0, len(feeder[kind]), rows):
+                await self.client.train_chunk(token, kind, feeder[kind][start : start + rows])
+
+    async def upload(self, feeder: dict | None = None) -> dict:
+        feeder = feeder or self.feeders[0]
         t_open = time.monotonic()
-        token = await self.client.train_open("benchmark-feeder", 0)
-        for kind, arr in (("downloads", self.downloads), ("probes", self.probes)):
-            for start in range(0, len(arr), rows):
-                await self.client.train_chunk(token, kind, arr[start : start + rows])
+        token = await self.client.train_open(feeder["hostname"], feeder["scheduler_id"])
+        await self.send(token, feeder)
         await self.client.train_close(token)
         self.uploads += 1
         return {"t_open": t_open, "t_closed": time.monotonic()}
@@ -99,109 +147,22 @@ class Driver:
 
     # ---- tracing ----
 
-    def _trace_start(self) -> None:
+    def trace_start(self) -> None:
         out = self.trainer.ctl("trace_start", dir=str(self.trace_dir))
         self.trace = {"started_monotonic": time.monotonic(), **out}
 
-    def _trace_stop(self) -> None:
+    def trace_stop(self) -> None:
         t = time.monotonic()
         limit = trace_stop_limit_s(self.deadline, t)
         self.trace.update(self.trainer.ctl("trace_stop", timeout=limit))
         self.trace.update(stop_s=time.monotonic() - t, stop_limit_s=limit)
 
-    # ---- windows ----
+    # ---- set-up's cycles, then the mix's window ----
 
-    async def run(self) -> dict:
+    async def run(self, window) -> dict:
         for i in range(self.traffic["runs_in_setup"]):
             await self.upload()
             status = await self.wait_run_done(i + 1)
             if (status["last_result"] or {}).get("error"):
                 raise RuntimeError(f"set-up run failed: {status['last_result']}")
-        if self.traffic["window"] == "scan_calls":
-            return await self._window_scan_calls()
-        if self.traffic["window"] == "runs":
-            return await self._window_runs()
-        raise ValueError(f"unknown window kind {self.traffic['window']!r}")
-
-    def _completed_calls(self, events: list) -> list:
-        """Completed scan calls of the newest GNN run: its reports at a whole
-        number of calls (time, run, model, steps so far, loss, gradient norm)."""
-        spc = self.config["optimizer"]["gnn"]["steps_per_call"]
-        gnn = [e for e in events if e[2] == "gnn"]
-        return [e for e in gnn if e[1] == gnn[-1][1] and e[3] % spc == 0] if gnn else []
-
-    async def _window_scan_calls(self) -> dict:
-        warm_steps = self.traffic["warm_calls"] * self.config["optimizer"]["gnn"]["steps_per_call"]
-        up = await self.upload()
-        events: list = []
-        start = stop = trace_stop_at = None
-        next_status = 0.0
-        while stop is None:
-            events += self.trainer.ctl("steps", since=len(events))["events"]
-            calls = self._completed_calls(events)
-            now = time.monotonic()
-            if start is None:
-                start = next((e for e in calls if e[3] >= warm_steps), None)
-                if start is not None:
-                    # inside the window the trainer is left alone: no status
-                    # poll until the window may close
-                    next_status = start[0] + self.seconds - 0.2
-                    if self.trace_dir is not None:
-                        self._trace_start()
-                        trace_stop_at = time.monotonic() + self.traffic["trace_seconds"]
-            else:
-                stop = next((e for e in calls if e[0] >= start[0] + self.seconds), None)
-            if trace_stop_at is not None and now >= trace_stop_at:
-                self._trace_stop()
-                trace_stop_at = None
-            if stop is None and now >= next_status:
-                next_status = now + 2.0
-                status = await self.client.status()
-                if status["trains_started"] >= self.uploads and not status["training"]:
-                    # the run ended before the window's seconds had passed: the
-                    # window closes on its last completed call
-                    events += self.trainer.ctl("steps", since=len(events))["events"]
-                    calls = self._completed_calls(events)
-                    if start is None or not calls or calls[-1][3] <= start[3]:
-                        raise RuntimeError(f"the run ended before a window could open; status {status}")
-                    stop = calls[-1]
-                elif now > self.deadline:
-                    raise RuntimeError("the window did not close in time")
-            if stop is None:
-                # the next look comes when the trace has to stop or the window
-                # may close (then every 50 ms, a status poll every 2 s)
-                wake = now + 0.05
-                if start is not None:
-                    wake = max(wake, min(start[0] + self.seconds - 0.2, trace_stop_at or math.inf))
-                await asyncio.sleep(wake - now)
-        if trace_stop_at is not None:
-            self._trace_stop()
-        await self.wait_run_done(self.uploads)
-        events += self.trainer.ctl("steps", since=len(events))["events"]
-        return {
-            "kind": "scan_calls", "window_start": start[0], "window_stop": stop[0],
-            "steps": stop[3] - start[3], "window_s": stop[0] - start[0],
-            "uploads": [up], "step_events": events, "trace": self.trace,
-        }
-
-    async def _window_runs(self) -> dict:
-        t_start = time.monotonic()
-        runs = []
-        while True:
-            if self.trace_dir is not None and not runs:
-                self._trace_start()
-            up = await self.upload()
-            status = await self.wait_run_done(self.uploads)
-            up["t_done"] = time.monotonic()
-            up["error"] = (status["last_result"] or {}).get("error")
-            runs.append(up)
-            if self.trace is not None and len(runs) == self.traffic["trace_runs"]:
-                self._trace_stop()
-            if up["t_done"] - t_start >= self.seconds and len(runs) >= self.traffic["min_runs"]:
-                break
-        events = self.trainer.ctl("steps", since=0)["events"]
-        return {
-            "kind": "runs", "window_start": t_start, "window_stop": runs[-1]["t_done"],
-            "window_s": runs[-1]["t_done"] - t_start, "uploads": runs,
-            "step_events": events, "trace": self.trace,
-        }
+        return await window.drive(self)
