@@ -19,10 +19,14 @@ the entry points a user would call, at the widths the repo ships as default:
             GNNScorer.
   device    (child, opens the chip) tpuvm/staging.py: a safetensors file from
             a seed is staged unsharded and under a NamedSharding over all
-            local devices, pulled back and compared bit for bit. On more than
-            one device it also drives train_async under {data: n} so node
-            rows and the pair batch are seen to span them. And the kernel the
-            training step runs, the gather's VJP (`sum_by_destination`),
+            local devices, pulled back and compared bit for bit. It also
+            drives train_async three times on the mesh the program chooses
+            ({data: n}: node rows and the pair batch are seen to span the
+            devices): the first run builds the scan program, the second
+            (another graph of the same shapes) and the third (the first's
+            again) must be served the kept one (`calls.traced` 0) and the
+            third must return the first's losses bit for bit. And the kernel
+            the training step runs, the gather's VJP (`sum_by_destination`),
             against `jnp.take`'s at two shapes.
   platform  the trainer process AND the device child must both report
             platform "tpu" with the same device kind and count, and Mosaic
@@ -301,8 +305,8 @@ def _child_scorer(artifact: str) -> dict:
 
 
 def _child_device(tmp: str, stage_mib: int) -> dict:
-    """Opens the accelerator: staging round trip, and on several devices the
-    data-parallel GNN run."""
+    """Opens the accelerator: staging round trip, the kernel, and the served
+    scan's runs."""
     from dragonfly2_tpu.utils import jaxenv
 
     cache = jaxenv.enable_compile_cache()
@@ -351,11 +355,8 @@ def _child_device(tmp: str, stage_mib: int) -> dict:
     out["staged_bytes"] = int(sum(t.nbytes for t in tensors.values()))
     out["mismatched"] = mismatched
     out["pallas"] = _pallas_check(out["platform"] == "tpu", n_dev)
-    ok = not mismatched and all(r["ok"] for r in out["pallas"].values())
-
-    if n_dev > 1:
-        out["data_parallel"] = _data_parallel_run(n_dev, out["platform"] == "tpu")
-        ok = ok and out["data_parallel"]["ok"]
+    out["served_scan"] = _served_scan_runs(n_dev, out["platform"] == "tpu")
+    ok = not mismatched and all(r["ok"] for r in out["pallas"].values()) and out["served_scan"]["ok"]
     return {"ok": ok, **out}
 
 
@@ -441,34 +442,52 @@ def _pallas_check(compiled: bool, n_dev: int = 1) -> dict:
     return out
 
 
-def _data_parallel_run(n_dev: int, on_tpu: bool) -> dict:
+def _served_scan_runs(n_dev: int, on_tpu: bool) -> dict:
     """train_async on the mesh the program decides for itself (no mesh given:
-    `parallel.mesh.mesh_for_run`, `{data: n}`): node rows and the pair batch
-    must span the devices, a 1/n share each, and on TPU chips the gather's VJP
-    must be the kernel's, over a sorted table a row shard."""
+    `parallel.mesh.mesh_for_run`, `{data: n}`), three runs of one
+    configuration and shapes. Node rows and the pair batch must span the
+    devices, a 1/n share each, and on TPU chips the gather's VJP must be the
+    kernel's, over a sorted table a row shard. The first run builds the scan
+    program; the second, on another graph, and the third, on the first's
+    again, must be served the kept one (`calls.traced` 0), and the third's
+    losses must be the first's bit for bit: the kept executable holds nothing
+    of the run that built it (the sorted table is an argument like the graph)."""
     import numpy as np
 
     from dragonfly2_tpu.trainer import synthetic, train_gnn
     from dragonfly2_tpu.trainer.metrics import TrainRunTelemetry
 
     cfg = train_gnn.GNNTrainConfig()
-    cluster = synthetic.make_cluster(num_nodes=1024, num_neighbors=16, num_pairs=65536, seed=SEED)
-    tel = TrainRunTelemetry("gnn", batch_size=cfg.batch_size)
-    _state, losses = asyncio.run(train_gnn.train_async(
-        cfg, cluster.graph, cluster.pairs, steps=20, telemetry=tel,
-    ))
-    p = tel.placement
+    sizes = dict(num_nodes=1024, num_neighbors=16, num_pairs=65536)
+    first, other = (synthetic.make_cluster(**sizes, seed=seed) for seed in (SEED, SEED + 1))
+    losses, placements, calls = [], [], []
+    for cluster, steps in ((first, 20), (other, 10), (first, 10)):
+        tel = TrainRunTelemetry("gnn", batch_size=cfg.batch_size)
+        _state, run_losses = asyncio.run(train_gnn.train_async(
+            cfg, cluster.graph, cluster.pairs, steps=steps, telemetry=tel,
+        ))
+        losses.append(run_losses)
+        placements.append(tel.placement)
+        calls.append(tel.summary()["calls"])
+    p = placements[0]
     graph, rows, vjp = p["graph"], p["batch_rows_per_device"], p["gather_vjp"]
+    traced = [c["traced"] for c in calls]
     ok = (
-        all(np.isfinite(losses))
-        and p["decision"] == {"rule": "rows_over_data", "devices": n_dev}
+        all(np.isfinite(run_losses).all() for run_losses in losses)
+        and p["decision"] == {"rule": "one_device" if n_dev == 1 else "rows_over_data", "devices": n_dev}
         and p["mesh"] == {"data": n_dev, "model": 1}
         and len(graph["per_device_bytes"]) == n_dev
         and all(b * n_dev == graph["bytes"] for b in graph["per_device_bytes"])
         and rows * n_dev == cfg.batch_size
         and (not on_tpu or (vjp["path"] == "sorted_kernel" and vjp["shards"] == n_dev))
+        and all(placement["gather_vjp"]["path"] == vjp["path"] for placement in placements)
+        and traced == [1, 0, 0]
+        and losses[2] == losses[0][:10] != losses[1]
     )
-    return {"ok": bool(ok), "placement": p, "steps": len(losses), "final_loss": losses[-1]}
+    return {
+        "ok": bool(ok), "placement": p, "steps": len(losses[0]), "final_loss": losses[0][-1], "traced": traced,
+        "first_ms": [c["first_ms"] for c in calls], "period_ms_p50": calls[0]["period_ms_p50"],
+    }
 
 
 def _child_main(argv: list[str]) -> int:

@@ -238,9 +238,11 @@ async def _train(args: argparse.Namespace) -> int:
             )
             calls = info.get("calls")
             if calls:
-                # the scan loop's pacing: what the host held the chip back
+                # the scan loop's pacing: what the host held the chip back, and
+                # whether the run built its program (traced=1) or was served the kept one
                 line += (
-                    f" calls={calls.get('count')} period_p50={calls.get('period_ms_p50')}ms"
+                    f" calls={calls.get('count')} traced={calls.get('traced')} first={calls.get('first_ms')}ms"
+                    f" period_p50={calls.get('period_ms_p50')}ms"
                     f" turn_max={calls.get('turn_ms_max')}ms stall={calls.get('stall_ms')}ms"
                 )
             print(line)

@@ -151,21 +151,26 @@ class TrainRunTelemetry:
         with self._lock:
             self.placement = placement
 
-    def on_calls(self, calls: list[tuple[float, float]]) -> None:
+    def on_calls(self, calls: list[tuple[float, float]], *, traced: int) -> None:
         """Record, once at the run's end, how the host paced the run's scan
         calls: `calls` holds each call's (start, end) on one monotonic clock.
         A period runs from one call's start to the next one's, a turn from
         one call's end to the next one's start (reports, the log line, the
-        thread hand-off). The first call compiles or loads the program, so
-        periods count from the second; `stall_ms` is the time by which
-        periods exceeded 1.5 x their median: what the host held the chip
-        back, in a run nobody traced."""
+        thread hand-off). The first call compiles or loads the program
+        unless the trainer kept it from an earlier run, so periods count from
+        the second and the first call's start to end is `first_ms`; `traced`
+        is how often the run traced the scan program (1 where it built it, 0
+        where the kept one served: `first_ms` is then a period like any
+        other). `stall_ms` is the time by which periods exceeded 1.5 x their
+        median: what the host held the chip back, in a run nobody traced."""
         starts = [a for a, _ in calls]
         periods = [(b - a) * 1e3 for a, b in zip(starts[1:], starts[2:])]
         turns = [(nxt - end) * 1e3 for (_, end), nxt in zip(calls, starts[1:])]
         p50 = statistics.median(periods) if periods else None
         summary = {
             "count": len(calls),
+            "traced": traced,
+            "first_ms": _round3((calls[0][1] - calls[0][0]) * 1e3 if calls else None),
             "period_ms_p50": _round3(p50),
             "period_ms_max": _round3(max(periods, default=None)),
             "turn_ms_p50": _round3(statistics.median(turns) if turns else None),

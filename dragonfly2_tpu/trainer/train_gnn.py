@@ -4,9 +4,19 @@ One jitted program over a ("data", "model") mesh, `multi_step`: a `lax.scan`
 of optimizer steps that samples its minibatches on the device
 (`shard_for_training_scan` places a run and builds it, `train_async` drives
 it). Graph node rows and the sampled pair batch are sharded over "data", Dense
-kernels over "model"; XLA inserts the neighbor-gather all-gathers and the
-gradient psum from the sharding annotations alone (no hand-written
-collectives — pjit style, per the scaling-book recipe).
+kernels over "model"; the gradient psum, and off the TPU the neighbor gather's
+all-gather, are XLA's, from the sharding annotations. On TPU chips the
+gather's VJP is a `custom_vjp` over a table of the slots sorted by destination
+that placement builds once a run (`ops/neighbor_agg_pallas`): one Pallas
+segmented sum on one chip; on a `data` mesh under `shard_map`, a table per row
+shard, with the gather's own `all_gather` of the states.
+
+The compiled step is kept across runs of the same shapes: model and optimizer
+transform are made once per configuration, so two runs' states have one tree
+structure, and the `jax.jit` of `multi_step` outlives the run that built it
+(one program at a time: `shard_for_training_scan`). A warm trainer's retrain
+starts with a call like any other; the run manifest's `calls.traced` says
+whether it did.
 
 Replaces the reference's never-implemented trainer loop (trainer/ is
 config+metrics only; the Train RPC at pkg/rpc/trainer/server/server.go:59
@@ -19,7 +29,7 @@ import asyncio
 import contextlib
 import time
 from dataclasses import dataclass
-from functools import partial
+from functools import cache, partial
 from typing import Any, Callable
 
 import jax
@@ -47,8 +57,32 @@ class GNNTrainConfig:
     warmup_steps: int = 100
 
 
+# Model and transform are functions of the configuration's values alone, and a
+# `TrainState` carries both as static fields of its tree (a module's bound
+# `apply` and a transform's closures compare by identity): made once per
+# distinct values, so that the states of two runs of one configuration have
+# equal tree structures and a kept `multi_step` does not trace again. A few
+# numbers in, a few small objects out: nothing here grows with a run.
+@cache
+def _model(hidden: int, embed_dim: int, num_layers: int) -> TopoScorer:
+    return TopoScorer(hidden=hidden, embed_dim=embed_dim, num_layers=num_layers)
+
+
+@cache
+def _transform(learning_rate: float, weight_decay: float, warmup_steps: int) -> optax.GradientTransformation:
+    return optax.chain(
+        optax.clip_by_global_norm(1.0),
+        optax.adamw(
+            optax.warmup_cosine_decay_schedule(
+                0.0, learning_rate, warmup_steps, 20_000, learning_rate * 0.05
+            ),
+            weight_decay=weight_decay,
+        ),
+    )
+
+
 def make_model(cfg: GNNTrainConfig) -> TopoScorer:
-    return TopoScorer(hidden=cfg.hidden, embed_dim=cfg.embed_dim, num_layers=cfg.num_layers)
+    return _model(cfg.hidden, cfg.embed_dim, cfg.num_layers)
 
 
 def init_state(
@@ -62,15 +96,7 @@ def init_state(
     params = model.init(
         jax.random.PRNGKey(rng_seed), _as_jnp_graph(graph), dummy_idx, dummy_idx, dummy_feats
     )
-    tx = optax.chain(
-        optax.clip_by_global_norm(1.0),
-        optax.adamw(
-            optax.warmup_cosine_decay_schedule(
-                0.0, cfg.learning_rate, cfg.warmup_steps, 20_000, cfg.learning_rate * 0.05
-            ),
-            weight_decay=cfg.weight_decay,
-        ),
-    )
+    tx = _transform(cfg.learning_rate, cfg.weight_decay, cfg.warmup_steps)
     return train_state.TrainState.create(apply_fn=model.apply, params=params, tx=tx)
 
 
@@ -157,6 +183,15 @@ def pad_graph(g: TopoGraph, n_padded: int) -> TopoGraph:
     return TopoGraph(*(np.concatenate([a, np.repeat(np.asarray(a[:1]), pad, axis=0)]) for a in g[:4]))
 
 
+# The one scan program this process keeps: what it was built from, and the
+# jitted `multi_step`. `_traces` counts how often `multi_step`'s Python body
+# has run. Plain module state: the trainer's drainer starts its runs one after
+# the other. Nothing data-sized hangs on it: shapes and shardings in the
+# first, sizes and the step body in the second's closure.
+_kept: tuple[tuple, Callable] | None = None
+_traces = 0
+
+
 def shard_for_training_scan(
     state: train_state.TrainState,
     g: TopoGraph,
@@ -175,17 +210,35 @@ def shard_for_training_scan(
     scaling-book rule: don't bounce to the host between steps. Returns
     (state, g, pairs, multi_step) where ``multi_step(state, g, pairs, key) ->
     (state, (losses[steps_per_call], grad_norms[steps_per_call]))``.
+
+    The jitted `multi_step` outlives the run: the last one built is kept
+    under everything it was built from or closes over (`_kept`), and a run
+    placed to the same gets it back, its first call a hit of `jax.jit`'s
+    own cache. Any other run lets the kept one go and builds its own.
     """
+    global _kept
     batch_size = meshlib.pad_to_multiple(batch_size, mesh.shape[meshlib.DATA_AXIS])
     state, state_sh, g, g_sh = _place_sharded(state, g, mesh)
     # the full pool is small (MBs) and replicated; sampled rows get
     # constrained onto the data axis inside the step
     pool_sh = PairBatch(*([meshlib.replicated(mesh)] * 4))
     pairs = jax.device_put(PairBatch(*(jnp.asarray(a) for a in pairs)), pool_sh)
+    # what the program is built from: model and transform are static fields
+    # of the state's tree (so the configuration's values are in `structure`),
+    # a graph with and without the table are different trees, and every shape
+    # of the table follows from N, K and the block count
+    shardings, structure = jax.tree.flatten((state_sh, g_sh, pool_sh))
+    shapes = [(a.shape, a.dtype) for a in jax.tree.leaves((state, g, pairs))]
+    built_from = (mesh, batch_size, steps_per_call, structure, shardings, shapes)
+    if _kept is not None and _kept[0] == built_from:
+        return state, g, pairs, _kept[1]
+    _kept = None  # one program at a time: the old one goes before the new one is built
     batch_sh = meshlib.batch_sharding(mesh)
     step = make_train_step()
 
     def multi_step(st, gg, pool, key):
+        global _traces
+        _traces += 1  # Python runs this body only when jax traces it
         n_pool = pool.child.shape[0]
 
         def one(carry, k):
@@ -207,6 +260,7 @@ def shard_for_training_scan(
         out_shardings=(state_sh, meshlib.replicated(mesh)),
         donate_argnums=(0,),
     )
+    _kept = (built_from, jitted)
     return state, g, pairs, jitted
 
 
@@ -266,7 +320,8 @@ async def train_async(
     grad-norm land in the dragonfly_train_* families after every call. Both
     ride the scan's ys and are pulled every call, so the compiled program is
     the same with and without it. It also gets every call's start and end
-    once, at the run's end.
+    once, at the run's end, with how often this run traced `multi_step` (1
+    where it built the program, 0 where the kept one served it).
 
     The mesh, when the caller gives none, is `parallel.mesh.mesh_for_run`'s
     (every device on `data`); the run manifest's `placement.decision` says so.
@@ -284,6 +339,7 @@ async def train_async(
         mesh, decision = meshlib.mesh_for_run()
     steps_per_call = max(1, min(steps_per_call, steps))
     calls = -(-steps // steps_per_call)
+    traces_before = _traces
 
     tracer = default_tracer()
 
@@ -337,5 +393,5 @@ async def train_async(
                 f"({done / (time.perf_counter() - t0):.2f} steps/s)"
             )
     if telemetry is not None:
-        telemetry.on_calls(call_times)
+        telemetry.on_calls(call_times, traced=_traces - traces_before)
     return state, losses

@@ -24,7 +24,7 @@ REPO = Path(__file__).resolve().parents[1]
 
 def _env(**extra: str) -> dict:
     # conftest's 8 virtual devices must not leak into children: the smoke's
-    # data-parallel leg would run the full-width GNN over them
+    # served-scan leg would run the full-width GNN over a mesh of them
     env = {k: v for k, v in os.environ.items() if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
     return {**env, **extra}
 
@@ -60,6 +60,10 @@ def test_chip_smoke_on_cpu_fails_only_on_platform(tmp_path):
     # off-chip the kernel only interprets, and the gate says so
     assert not any(r["compiled"] for r in summary["detail"]["device"]["pallas"].values())
     assert any("Pallas kernel not compiled" in p for p in platform["problems"])
+    # the served scan: the first run built the program, the other two were served the kept one
+    served = summary["detail"]["device"]["served_scan"]
+    assert served["ok"] and served["traced"] == [1, 0, 0] and served["placement"]["gather_vjp"]["path"] == "derived"
+    assert max(served["first_ms"][1:]) < served["first_ms"][0]
     # the scorer child was pinned to the host CPU; the cache went where the
     # environment said, not into the checkout
     assert summary["detail"]["scorer"]["platform"] == "cpu"

@@ -75,6 +75,40 @@ def test_mesh_shape_invariance_small():
     assert trajectories[0][-1] < trajectories[0][0]
 
 
+@pytest.mark.parametrize("devices", [1, 4])
+def test_a_placement_with_the_sorted_table_is_served_the_kept_program(monkeypatch, devices):
+    """With the table TPU chips get (the test's word, `PLATFORM`; the kernel
+    interpreted): one `EdgesByDst` on one device, an `EdgesByShard` of a table
+    a row shard on a `data` mesh made anew by `mesh_for_run`. The table's
+    shapes follow from N and K and its data is the run's, so a second graph
+    of the same shapes is placed to the program the first one built, with no
+    trace, and the first graph again gets its first losses back bit for bit."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from dragonfly2_tpu.ops import neighbor_agg_pallas as pk
+
+    monkeypatch.setattr(pk, "PLATFORM", "cpu")
+    monkeypatch.setattr(train_gnn, "_kept", None)
+    cfg = train_gnn.GNNTrainConfig(hidden=128, embed_dim=16, num_layers=2, batch_size=64)
+    programs, losses, traces = [], [], []
+    for seed in (1, 2, 1):
+        cluster = synthetic.make_cluster(num_nodes=1024, num_neighbors=16, num_pairs=512, seed=seed)
+        mesh, _ = meshlib.mesh_for_run(jax.devices()[:devices])
+        before = train_gnn._traces
+        state, g, pool, multi_step = train_gnn.shard_for_training_scan(
+            train_gnn.init_state(cfg, cluster.graph, 0), cluster.graph, cluster.pairs, mesh,
+            batch_size=cfg.batch_size, steps_per_call=2)
+        assert isinstance(g.by_dst, pk.EdgesByDst if devices == 1 else pk.EdgesByShard)
+        # under a mesh Pallas's HLO interpreter: the TPU interpreter's callbacks hang there
+        with pltpu.force_tpu_interpret_mode(True) if devices > 1 else pltpu.force_tpu_interpret_mode():
+            _, (ls, _) = multi_step(state, g, pool, jax.random.PRNGKey(0))
+            losses.append(np.asarray(ls).tolist())
+        programs.append(multi_step)
+        traces.append(train_gnn._traces - before)
+    assert traces == [1, 0, 0] and programs[0] is programs[1] is programs[2] and programs[0]._cache_size() == 1
+    assert losses[2] == losses[0] != losses[1] and np.isfinite(losses).all()
+
+
 @pytest.mark.slow
 def test_dryrun_16_devices_subprocess():
     """16-device variant in a fresh process (device count is frozen at

@@ -161,6 +161,90 @@ def test_train_async_without_telemetry_returns_the_same_losses(served_runs):
     assert sink.steps == 6 and sink.last_loss == with_sink[-1] and sink.last_grad_norm > 0
 
 
+@pytest.fixture
+def nothing_kept():
+    """A process that has kept no scan program, before the test and after it."""
+    train_gnn._kept = None
+    yield
+    train_gnn._kept = None
+
+
+KEPT_CFG = dict(hidden=32, embed_dim=16, num_layers=2, batch_size=64, warmup_steps=2)
+
+
+def _served(cluster, *, mesh, steps_per_call=3, **cfg):
+    """One `train_async` run of 6 steps: (losses, the run manifest's `calls`)."""
+    from dragonfly2_tpu.trainer.metrics import TrainRunTelemetry
+
+    sink = TrainRunTelemetry("gnn")
+    _, losses = asyncio.run(train_gnn.train_async(
+        train_gnn.GNNTrainConfig(**{**KEPT_CFG, **cfg}), cluster.graph, cluster.pairs,
+        steps=6, steps_per_call=steps_per_call, telemetry=sink, mesh=mesh))
+    return losses, sink.summary()["calls"]
+
+
+def test_a_run_of_the_kept_shapes_is_served_the_kept_program(nothing_kept):
+    """The second run of one configuration and shapes (another graph, fresh
+    weights) traces nothing: its first call hits the kept function's one
+    cache entry. What it computes is what a process that kept nothing does.
+    On a caller's mesh of one device, made anew for every run, and on the
+    program's own (`mesh_for_run`: a `data` mesh of every device, anew too)."""
+    first = synthetic.make_cluster(num_nodes=64, num_neighbors=4, num_pairs=512, seed=1)
+    second = synthetic.make_cluster(num_nodes=64, num_neighbors=4, num_pairs=512, seed=2)
+    assert not np.array_equal(first.graph.neighbors, second.graph.neighbors)
+    for mesh in (lambda: meshlib.make_mesh(jax.devices()[:1]), lambda: None):
+        _, calls = _served(first, mesh=mesh())
+        assert calls["traced"] == 1 and calls["count"] == 2 and calls["first_ms"] > 0
+        program = train_gnn._kept[1]
+        kept_losses, calls = _served(second, mesh=mesh())
+        assert calls["traced"] == 0 and train_gnn._kept[1] is program and program._cache_size() == 1
+        train_gnn._kept = None
+        fresh_losses, calls = _served(second, mesh=mesh())
+        assert calls["traced"] == 1 and train_gnn._kept[1] is not program
+        assert kept_losses == fresh_losses and len(kept_losses) == 6
+
+
+# two runs a case, each other than the one before it in one thing the program is built from
+OTHER_RUNS = {
+    "nodes": [dict(num_nodes=128), dict(num_nodes=128, num_pairs=256)],  # node rows; the pool's rows
+    "steps_per_call": [dict(steps_per_call=2), dict(steps_per_call=2, batch_size=32)],
+    "hidden": [dict(hidden=64), dict(hidden=64, learning_rate=1e-3)],  # the model, the transform: the state's tree
+}
+
+
+@pytest.mark.parametrize("others", OTHER_RUNS)
+def test_a_run_of_other_shapes_builds_its_own_and_one_program_is_kept(nothing_kept, others):
+    """Whatever the program was built from is part of what it is kept under:
+    a run that differs in any of it traces its own, and the one it replaces
+    is let go (nothing holds it: the weak reference dies)."""
+    import gc
+    import weakref
+
+    mesh = meshlib.make_mesh(jax.devices()[:1])
+    sizes = dict(num_nodes=64, num_neighbors=4, num_pairs=512, seed=1)
+    for other in [{}, *OTHER_RUNS[others]]:
+        replaced = weakref.ref(train_gnn._kept[1]) if train_gnn._kept else None
+        cluster = synthetic.make_cluster(**{**sizes, **{k: v for k, v in other.items() if k in sizes}})
+        _, calls = _served(cluster, mesh=mesh, **{k: v for k, v in other.items() if k not in sizes})
+        assert calls["traced"] == 1 and train_gnn._kept[1]._cache_size() == 1, other
+        gc.collect()
+        assert replaced is None or replaced() is None, other
+
+
+def test_two_states_of_one_configuration_have_one_tree_structure():
+    """Model and transform are made once per distinct values: `apply_fn` and
+    `tx` are static fields of the state's tree, and a jitted function kept
+    for one state traces again for a state whose structure is another."""
+    cluster = synthetic.make_cluster(num_nodes=64, num_neighbors=4, num_pairs=64, seed=2)
+    one, same = (train_gnn.init_state(train_gnn.GNNTrainConfig(**KEPT_CFG), cluster.graph, seed) for seed in (0, 1))
+    assert one.apply_fn == same.apply_fn and one.tx is same.tx
+    assert jax.tree.structure(one) == jax.tree.structure(same)
+    assert train_gnn.make_model(train_gnn.GNNTrainConfig(**KEPT_CFG)) is one.apply_fn.__self__
+    for other in (dict(hidden=64), dict(weight_decay=0.0), dict(warmup_steps=3)):
+        state = train_gnn.init_state(train_gnn.GNNTrainConfig(**{**KEPT_CFG, **other}), cluster.graph)
+        assert jax.tree.structure(state) != jax.tree.structure(one), other
+
+
 def test_the_body_and_the_scan_agree():
     """`make_train_step()` is what `multi_step` scans: jitted alone on the
     batch a one-step scan samples, it gives that scan's loss and gradient

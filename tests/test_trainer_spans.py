@@ -19,6 +19,15 @@ from test_trainer_service import _fill_telemetry
 GNN_STEPS, STEPS_PER_CALL = 12, 4
 
 
+async def _upload(svc, store):
+    """One upload of the store's records through the service, and the run it starts."""
+    token = (await svc.train_open({"hostname": "s"}))["token"]
+    await svc.train_chunk({"token": token, "kind": "downloads", "data": pack_records(store.downloads.load_all())})
+    await svc.train_chunk({"token": token, "kind": "probes", "data": pack_records(store.probes.load_all())})
+    await svc.train_close({"token": token})
+    await svc.wait_idle()
+
+
 @pytest.fixture(scope="module")
 def trained(tmp_path_factory):
     """One tiny run through TrainerService (MLP + GNN, no manager): its
@@ -26,6 +35,7 @@ def trained(tmp_path_factory):
     import asyncio
 
     tmp_path = tmp_path_factory.mktemp("spans")
+    train_gnn._kept = None  # whatever ran in this process before: the run builds its scan program
     svc = TrainerService(TrainerConfig(
         model_dir=str(tmp_path / "models"),
         mlp=train_mlp.MLPTrainConfig(hidden=(16, 16), steps=20, batch_size=64),
@@ -35,14 +45,7 @@ def trained(tmp_path_factory):
     store = TelemetryStorage(tmp_path / "telemetry")
     _fill_telemetry(store, n_hosts=10, n_rows=200)
 
-    async def body():
-        token = (await svc.train_open({"hostname": "s"}))["token"]
-        await svc.train_chunk({"token": token, "kind": "downloads", "data": pack_records(store.downloads.load_all())})
-        await svc.train_chunk({"token": token, "kind": "probes", "data": pack_records(store.probes.load_all())})
-        await svc.train_close({"token": token})
-        await svc.wait_idle()
-
-    asyncio.run(body())
+    asyncio.run(_upload(svc, store))
     assert svc.trains_succeeded == 1, svc.last_result
     spans = [s.to_dict() for s in tracing.default_tracer().finished()]
     root = [s for s in spans if s["name"] == "trainer.train_run"][-1]
@@ -80,8 +83,11 @@ def test_a_training_run_leaves_the_span_tree(trained):
 def test_the_manifest_counts_the_calls_and_times_the_export(trained):
     manifest, spans = trained
     calls = manifest["models"]["gnn"]["calls"]
-    assert set(calls) == {"count", "period_ms_p50", "period_ms_max", "turn_ms_p50", "turn_ms_max", "stall_ms"}
+    assert set(calls) == {
+        "count", "traced", "first_ms", "period_ms_p50", "period_ms_max", "turn_ms_p50", "turn_ms_max", "stall_ms"}
     assert calls["count"] == GNN_STEPS // STEPS_PER_CALL
+    # the run built its scan program: one trace, and a first call (trace, compile, steps) longer than any period
+    assert calls["traced"] == 1 and calls["first_ms"] > calls["period_ms_max"]
     assert calls["stall_ms"] >= 0 and 0 < calls["turn_ms_p50"] <= calls["turn_ms_max"] < calls["period_ms_max"]
     assert manifest["models"]["mlp"]["calls"] is None  # the MLP loop makes no scan calls
     for model in ("mlp", "gnn"):
@@ -90,20 +96,53 @@ def test_the_manifest_counts_the_calls_and_times_the_export(trained):
         assert seconds == pytest.approx(span["duration_ms"] / 1e3, abs=0.05) and seconds > 0
 
 
-@pytest.mark.parametrize("calls,expected", [
-    # the first call compiles: periods count from the second call's start
-    ([(0.0, 1.0), (1.1, 2.1), (2.2, 3.2), (5.2, 6.2), (6.3, 7.3)],
-     {"count": 5, "period_ms_p50": 1100.0, "period_ms_max": 3000.0, "turn_ms_p50": 100.0,
-      "turn_ms_max": 2000.0, "stall_ms": 1350.0}),
-    ([(0.0, 9.0), (9.5, 10.0)],
-     {"count": 2, "period_ms_p50": None, "period_ms_max": None, "turn_ms_p50": 500.0,
-      "turn_ms_max": 500.0, "stall_ms": 0.0}),
-    ([], {"count": 0, "period_ms_p50": None, "period_ms_max": None, "turn_ms_p50": None,
-          "turn_ms_max": None, "stall_ms": 0.0}),
+def test_a_warm_service_retrains_on_the_scan_program_it_kept(tmp_path):
+    """Two uploads of the same records into one service whose pair pool is at
+    its cap (as the benchmark's retrain cell sends them): the second run's
+    manifest says it traced nothing, and its first call is no longer the
+    long one."""
+    import asyncio
+
+    train_gnn._kept = None
+    svc = TrainerService(TrainerConfig(
+        model_dir=str(tmp_path / "models"), pool_rows=64,
+        mlp=train_mlp.MLPTrainConfig(hidden=(16, 16), steps=4, batch_size=64),
+        gnn=train_gnn.GNNTrainConfig(hidden=16, embed_dim=8, num_layers=2, batch_size=64, warmup_steps=2),
+        gnn_steps=GNN_STEPS, gnn_steps_per_call=STEPS_PER_CALL,
+    ))
+    store = TelemetryStorage(tmp_path / "telemetry")
+    _fill_telemetry(store, n_hosts=10, n_rows=200)
+
+    async def body():
+        await _upload(svc, store)
+        await _upload(svc, store)
+
+    asyncio.run(body())
+    assert svc.trains_succeeded == 2, svc.last_result
+    cold, warm = (m["models"]["gnn"] for m in list(svc.run_history)[-2:])
+    assert cold["placement"]["graph"] == warm["placement"]["graph"] and cold["steps"] == warm["steps"] == GNN_STEPS
+    assert cold["calls"]["traced"] == 1 and warm["calls"]["traced"] == 0
+    assert warm["calls"]["first_ms"] < cold["calls"]["first_ms"] / 2
+
+
+@pytest.mark.parametrize("calls,traced,expected", [
+    # the first call of a run that builds its program compiles: periods count
+    # from the second call's start, the first call's start to end is first_ms
+    ([(0.0, 1.0), (1.1, 2.1), (2.2, 3.2), (5.2, 6.2), (6.3, 7.3)], 0,
+     {"count": 5, "traced": 0, "first_ms": 1000.0, "period_ms_p50": 1100.0, "period_ms_max": 3000.0,
+      "turn_ms_p50": 100.0, "turn_ms_max": 2000.0, "stall_ms": 1350.0}),
+    ([(0.0, 9.0), (9.5, 10.0)], 1,
+     {"count": 2, "traced": 1, "first_ms": 9000.0, "period_ms_p50": None, "period_ms_max": None,
+      "turn_ms_p50": 500.0, "turn_ms_max": 500.0, "stall_ms": 0.0}),
+    ([(2.0, 2.25)], 1,
+     {"count": 1, "traced": 1, "first_ms": 250.0, "period_ms_p50": None, "period_ms_max": None,
+      "turn_ms_p50": None, "turn_ms_max": None, "stall_ms": 0.0}),
+    ([], 0, {"count": 0, "traced": 0, "first_ms": None, "period_ms_p50": None, "period_ms_max": None,
+             "turn_ms_p50": None, "turn_ms_max": None, "stall_ms": 0.0}),
 ])
-def test_call_summary_arithmetic(calls, expected):
+def test_call_summary_arithmetic(calls, traced, expected):
     sink = train_metrics.TrainRunTelemetry("gnn")
-    sink.on_calls(calls)
+    sink.on_calls(calls, traced=traced)
     assert sink.summary()["calls"] == pytest.approx(expected)
 
 
