@@ -23,7 +23,8 @@ the entry points a user would call, at the widths the repo ships as default:
             drives train_async three times on the mesh the program chooses
             ({data: n}: node rows and the pair batch are seen to span the
             devices): the first run builds the scan program, the second
-            (another graph of the same shapes) and the third (the first's
+            (another cluster, of 1,000 hosts: a count off the grid, placed
+            at the first's rung of 1,024 rows) and the third (the first's
             again) must be served the kept one (`calls.traced` 0) and the
             third must return the first's losses bit for bit. And the kernel
             the training step runs, the gather's VJP (`sum_by_destination`),
@@ -448,7 +449,9 @@ def _served_scan_runs(n_dev: int, on_tpu: bool) -> dict:
     configuration and shapes. Node rows and the pair batch must span the
     devices, a 1/n share each, and on TPU chips the gather's VJP must be the
     kernel's, over a sorted table a row shard. The first run builds the scan
-    program; the second, on another graph, and the third, on the first's
+    program; the second, on another cluster whose 1,000 hosts are no whole
+    tiles and are placed at the first's rung (`placed_rows`: 1,024 rows, the
+    sorted kernel all the same), and the third, on the first's
     again, must be served the kept one (`calls.traced` 0), and the third's
     losses must be the first's bit for bit: the kept executable holds nothing
     of the run that built it (the sorted table is an argument like the graph)."""
@@ -458,8 +461,9 @@ def _served_scan_runs(n_dev: int, on_tpu: bool) -> dict:
     from dragonfly2_tpu.trainer.metrics import TrainRunTelemetry
 
     cfg = train_gnn.GNNTrainConfig()
-    sizes = dict(num_nodes=1024, num_neighbors=16, num_pairs=65536)
-    first, other = (synthetic.make_cluster(**sizes, seed=seed) for seed in (SEED, SEED + 1))
+    sizes = dict(num_neighbors=16, num_pairs=65536)
+    first = synthetic.make_cluster(num_nodes=1024, **sizes, seed=SEED)
+    other = synthetic.make_cluster(num_nodes=1000, **sizes, seed=SEED + 1)
     losses, placements, calls = [], [], []
     for cluster, steps in ((first, 20), (other, 10), (first, 10)):
         tel = TrainRunTelemetry("gnn", batch_size=cfg.batch_size)
@@ -469,12 +473,14 @@ def _served_scan_runs(n_dev: int, on_tpu: bool) -> dict:
         losses.append(run_losses)
         placements.append(tel.placement)
         calls.append(tel.summary()["calls"])
-    p = placements[0]
+    p, off_grid = placements[0], placements[1]
     graph, rows, vjp = p["graph"], p["batch_rows_per_device"], p["gather_vjp"]
     traced = [c["traced"] for c in calls]
+    decided = {"rule": "one_device" if n_dev == 1 else "rows_over_data", "devices": n_dev}
     ok = (
         all(np.isfinite(run_losses).all() for run_losses in losses)
-        and p["decision"] == {"rule": "one_device" if n_dev == 1 else "rows_over_data", "devices": n_dev}
+        and p["decision"] == {**decided, "hosts": 1024, "rows": 1024, "pad_pct": 0.0}
+        and off_grid["decision"] == {**decided, "hosts": 1000, "rows": 1024, "pad_pct": 2.4}
         and p["mesh"] == {"data": n_dev, "model": 1}
         and len(graph["per_device_bytes"]) == n_dev
         and all(b * n_dev == graph["bytes"] for b in graph["per_device_bytes"])
@@ -486,6 +492,7 @@ def _served_scan_runs(n_dev: int, on_tpu: bool) -> dict:
     )
     return {
         "ok": bool(ok), "placement": p, "steps": len(losses[0]), "final_loss": losses[0][-1], "traced": traced,
+        "off_grid": {"decision": off_grid["decision"], "gather_vjp.path": off_grid["gather_vjp"]["path"]},
         "first_ms": [c["first_ms"] for c in calls], "period_ms_p50": calls[0]["period_ms_p50"],
     }
 

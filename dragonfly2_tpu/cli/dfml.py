@@ -245,6 +245,10 @@ async def _train(args: argparse.Namespace) -> int:
                     f" period_p50={calls.get('period_ms_p50')}ms"
                     f" turn_max={calls.get('turn_ms_max')}ms stall={calls.get('stall_ms')}ms"
                 )
+            placed = (info.get("placement") or {}).get("decision") or {}
+            if "rows" in placed:
+                # the hosts the run was given and the rows it placed them at (a rung of the ladder)
+                line += f" hosts={placed['hosts']} rows={placed['rows']} pad={placed['pad_pct']}%"
             print(line)
             curve = info.get("curve") or []
             if curve and not args.no_curves:

@@ -32,6 +32,7 @@ PERF.md), so past MAX_BLOCKS there is no table.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from functools import partial
 from typing import NamedTuple
@@ -43,7 +44,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dragonfly2_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+from dragonfly2_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, pad_to_multiple
 
 BLOCK_BYTES = 32 << 20
 MAX_BLOCKS = 32
@@ -123,6 +124,33 @@ def _table_blocks(slots: int, dst_rows: int, width: int, dtype) -> tuple[int, st
         return 0, (f"{slots} slots of {width * 2} bytes do not tile into at most {MAX_BLOCKS} "
                    f"blocks of {BLOCK_BYTES >> 20} MB and whole windows of {WINDOW} rows")
     return blocks, ""
+
+
+def placed_rows(hosts: int, shards: int = 1) -> int:
+    """The node rows a cluster of `hosts` is placed at over `shards` row
+    shards: the smallest rung at or above `hosts` of THE ladder, which nothing
+    else knows. A rung is whole tiles of TILE_DST, whole row shards, and whole
+    sixteenths of the power of two at or above it: eight rungs an octave, so
+    at most an eighth of padding (from 8 * TILE_DST hosts up), and a rung is
+    placed as it is (32,768 stays 32,768; 40,000 goes to 40,960). It is here,
+    beside the rule it serves: a host count is whatever the scheduler saw
+    that week, and `why_derived` takes whole tiles alone, so placement hands
+    it rungs, which it takes at the widths the trainer ships (256 and 512, up
+    to MAX_BLOCKS blocks a shard; from 32,768 rows a shard on, a rung of 512-wide
+    rows is whole blocks of BLOCK_BYTES). And what is compiled for the placed
+    rows (the scan step, the eager init, the export's forward) is compiled for
+    a rung: a host count that moves inside one finds its programs again.
+    Padding is paid in every step, a crossing once: sixteen rungs an octave
+    would halve the first and double the second."""
+    unit = math.lcm(TILE_DST, shards)
+    rows = max(hosts, 1)
+    while True:
+        octave = 1 << (rows - 1).bit_length()
+        step = max(octave // 16 // unit, 1) * unit
+        rung = pad_to_multiple(rows, step)
+        if rung == rows:  # (a turn more than two only where `shards` is no power of two)
+            return rows
+        rows = rung
 
 
 def why_derived(shape: tuple[int, int], width: int, dtype, mesh: Mesh) -> str:

@@ -14,6 +14,7 @@ any of them tampering fails verify_artifact before attach.
 from __future__ import annotations
 
 import json
+from functools import partial
 from pathlib import Path
 from typing import Any
 
@@ -168,11 +169,22 @@ def save_native(directory: str | Path, model: TopoScorer, params: Any, graph: An
     """Export the native serving artifact beside the flax one: compute the
     cached node embeddings once in JAX, then flatten head weights + embeddings
     into the C++ scorer's binary format (native/scorer.cc; replaces the
-    reference's TF-Serving hop, tfserving/client_v1.go:82-102)."""
+    reference's TF-Serving hop, tfserving/client_v1.go:82-102). The forward
+    runs over the rows a training run places (`placed_rows`: what it compiles
+    is a rung's, not this host count's) and the cluster's own rows are
+    written: a padding row is a copy of node 0 that no neighbour slot names."""
     from dragonfly2_tpu.native import export_scorer_artifact
+    from dragonfly2_tpu.ops.neighbor_agg_pallas import placed_rows
+    from dragonfly2_tpu.trainer.train_gnn import pad_graph
 
-    z = np.asarray(jax.jit(lambda p, g: model.apply(p, g, method=model.embed))(params, graph))
+    hosts = graph.node_feats.shape[0]
+    z = np.asarray(_embed(model, params, pad_graph(graph, placed_rows(hosts))))[:hosts]
     return export_scorer_artifact(params, z, Path(directory) / "scorer.dfsc")
+
+
+@partial(jax.jit, static_argnums=0)  # a model is its few numbers: one program a model and a rung
+def _embed(model: TopoScorer, params: Any, graph: Any) -> jnp.ndarray:
+    return model.apply(params, graph, method=model.embed)
 
 
 def load_native(directory: str | Path):

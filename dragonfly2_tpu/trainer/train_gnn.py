@@ -16,7 +16,10 @@ transform are made once per configuration, so two runs' states have one tree
 structure, and the `jax.jit` of `multi_step` outlives the run that built it
 (one program at a time: `shard_for_training_scan`). A warm trainer's retrain
 starts with a call like any other; the run manifest's `calls.traced` says
-whether it did.
+whether it did. The node rows are placed at a rung of a ladder of row counts
+(`ops/neighbor_agg_pallas.placed_rows`), padded with copies of node 0, so a
+cluster of any size takes the sorted VJP and a host count that moves inside a
+rung keeps the program; `placement.decision` says `hosts`, `rows`, `pad_pct`.
 
 Replaces the reference's never-implemented trainer loop (trainer/ is
 config+metrics only; the Train RPC at pkg/rpc/trainer/server/server.go:59
@@ -129,7 +132,8 @@ def make_train_step() -> Callable:
 def _place_sharded(
     state: train_state.TrainState, g: TopoGraph, mesh: Mesh
 ) -> tuple[train_state.TrainState, Any, TopoGraph, TopoGraph]:
-    """Shared placement: pad node rows to the dp size, kernels over "model",
+    """Shared placement: pad node rows to their rung (whole tiles and whole
+    row shards: `neighbor_agg_pallas.placed_rows`), kernels over "model",
     node rows over "data". The neighbor table is fixed from here to the run's
     end, so its transpose is fixed here too, once, on the host, where the
     kernel that sums the gather's VJP over it will run
@@ -137,9 +141,11 @@ def _place_sharded(
     device, a table per row shard on a `data` mesh, each beside its shard.
     Elsewhere None, and the VJP stays `jnp.take`'s.
     Returns (state, state_sharding, g, g_sharding)."""
-    from dragonfly2_tpu.ops.neighbor_agg_pallas import gather_vjp_tables
+    from dragonfly2_tpu.ops.neighbor_agg_pallas import gather_vjp_tables, placed_rows
 
     dp = mesh.shape[meshlib.DATA_AXIS]
+    hosts = g.node_feats.shape[0]
+    rows = placed_rows(hosts, dp)
     param_sh = meshlib.infer_param_sharding(state.params, mesh)
     state_sh = train_state.TrainState(
         step=meshlib.replicated(mesh),
@@ -152,8 +158,10 @@ def _place_sharded(
     )
     # the host's part of placement, timed: padding, the sorted table(s), and
     # the copies onto the devices (a row shard each on a `data` mesh)
-    with default_tracer().span("trainer.gnn.place", data=dp, model=mesh.shape[meshlib.MODEL_AXIS]):
-        g = pad_graph(g, meshlib.pad_to_multiple(g.node_feats.shape[0], dp))
+    with default_tracer().span(
+        "trainer.gnn.place", data=dp, model=mesh.shape[meshlib.MODEL_AXIS], hosts=hosts, rows=rows
+    ):
+        g = pad_graph(g, rows)
         by_dst, _ = gather_vjp_tables(np.asarray(g.neighbors), *_gathered_states(state), mesh)
         g = g._replace(by_dst=by_dst)
         state = jax.device_put(state, state_sh)
@@ -172,7 +180,7 @@ def _gathered_states(state: train_state.TrainState) -> tuple[int, Any]:
 
 
 def pad_graph(g: TopoGraph, n_padded: int) -> TopoGraph:
-    """Pad node dim to n_padded (static shapes, whole row shards) with copies
+    """Pad node dim to n_padded (the rung a host count is placed at) with copies
     of node 0. No neighbour slot and no pair names a padding row, so it moves
     no loss and no gradient; a copy, and not a row of zeros, because a node
     whose state is all zero has an all-zero embedding, and the embedding's L2
@@ -264,10 +272,12 @@ def shard_for_training_scan(
     return state, g, pairs, jitted
 
 
-def _placement(mesh: Mesh, decision: dict, state: Any, g: TopoGraph, batch_size: int) -> dict:
+def _placement(mesh: Mesh, decision: dict, hosts: int, state: Any, g: TopoGraph, batch_size: int) -> dict:
     """What the placed run occupies, for the run manifest: the mesh and who
     chose it (`parallel.mesh.mesh_for_run`'s record; `{"rule": "given"}` for
-    a caller's own), the Dense kernels the tensor-parallel rule shards and
+    a caller's own), the hosts the run was given and the rows they were
+    placed at (`pad_pct`: the rows above the cluster's own, of its own, which
+    every step pays for), the Dense kernels the tensor-parallel rule shards and
     the graph's node rows, both read back from the placed arrays, the rows of
     one pair batch each device is constrained to inside the step, and which
     VJP the gather takes: the table(s) placement hung on the graph, or the
@@ -279,16 +289,17 @@ def _placement(mesh: Mesh, decision: dict, state: Any, g: TopoGraph, batch_size:
         if leaf.ndim == 2 and meshlib.MODEL_AXIS in leaf.sharding.spec
     ]
     batch_size = meshlib.pad_to_multiple(batch_size, mesh.shape[meshlib.DATA_AXIS])
+    rows = g.node_feats.shape[0]
     return {
         "mesh": {k: int(v) for k, v in mesh.shape.items()},
-        "decision": decision,
+        "decision": {**decision, "hosts": hosts, "rows": rows, "pad_pct": round(100.0 * (rows - hosts) / hosts, 2)},
         "kernels": meshlib.placement_report(kernels),
         "graph": meshlib.placement_report(g._replace(by_dst=None)),
         "batch_rows_per_device": meshlib.batch_sharding(mesh).shard_shape((batch_size,))[0],
         "gather_vjp": {
             **gather_vjp_report(g.by_dst, g.neighbors.shape, *_gathered_states(state), mesh),
             "slots": int(g.neighbors.size),
-            "max_in_degree": int(np.bincount(np.asarray(g.neighbors).ravel()).max()),
+            "max_in_degree": int(np.bincount(np.asarray(g.neighbors)[:hosts].ravel()).max()),
         },
     }
 
@@ -324,7 +335,8 @@ async def train_async(
     where it built the program, 0 where the kept one served it).
 
     The mesh, when the caller gives none, is `parallel.mesh.mesh_for_run`'s
-    (every device on `data`); the run manifest's `placement.decision` says so.
+    (every device on `data`); the run manifest's `placement.decision` says so,
+    with the hosts given and the rows placed.
 
     Spans (children of the caller's current span; to_thread copies the
     context): `trainer.gnn.setup` around init + placement + building the
@@ -340,12 +352,18 @@ async def train_async(
     steps_per_call = max(1, min(steps_per_call, steps))
     calls = -(-steps // steps_per_call)
     traces_before = _traces
+    hosts = graph.node_feats.shape[0]
 
     tracer = default_tracer()
 
     def _setup():
+        from dragonfly2_tpu.ops.neighbor_agg_pallas import placed_rows
+
         with tracer.span("trainer.gnn.setup"):
-            state = init_state(cfg, graph, seed)
+            # the eager init runs the model over the rows placement will place:
+            # what it compiles is a rung's, like the step, not this host count's
+            rows = placed_rows(hosts, mesh.shape[meshlib.DATA_AXIS])
+            state = init_state(cfg, pad_graph(graph, rows), seed)
             return shard_for_training_scan(
                 state, graph, pairs, mesh,
                 batch_size=cfg.batch_size, steps_per_call=steps_per_call,
@@ -353,7 +371,7 @@ async def train_async(
 
     state, g, pool, multi_step = await asyncio.to_thread(_setup)
     if telemetry is not None:
-        telemetry.on_placed(_placement(mesh, decision, state, g, cfg.batch_size))
+        telemetry.on_placed(_placement(mesh, decision, hosts, state, g, cfg.batch_size))
     key = jax.random.PRNGKey(seed)
 
     # each call's (start, end) in the worker, always on: two clock reads a

@@ -273,11 +273,11 @@ def test_a_placed_step_with_the_table(monkeypatch):
         unplaced, graph, pairs, mesh, batch_size=64, steps_per_call=2)
     assert g.by_dst is None
     counts = {"slots": n * k, "max_in_degree": int(np.bincount(graph.neighbors.ravel()).max())}
-    assert train_gnn._placement(mesh, {"rule": "given"}, state, g, 64)["gather_vjp"] == {
+    assert train_gnn._placement(mesh, {"rule": "given"}, n, state, g, 64)["gather_vjp"] == {
         "path": "derived", "reason": "cpu devices: the kernel compiles for tpu alone", **counts}
     assert train_gnn._gathered_states(state) == (128, jnp.bfloat16)
     table = _table_in_blocks(monkeypatch, graph.neighbors, 128, jnp.bfloat16, 2)
-    placement = train_gnn._placement(mesh, {"rule": "given"}, state, g._replace(by_dst=table), 64)
+    placement = train_gnn._placement(mesh, {"rule": "given"}, n, state, g._replace(by_dst=table), 64)
     assert placement["gather_vjp"] == {
         "path": "sorted_kernel", "slot_order": "k_major", "shards": 1, "blocks": 2,
         "block_bytes": n * k // 2 * 128 * 2, "live_windows": {"least": int(table.live[0]), "most": int(table.live[0])},

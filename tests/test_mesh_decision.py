@@ -154,7 +154,7 @@ def test_ten_steps_on_a_data4_mesh_follow_the_one_device_program(runs):
     loss_gap, gnorm_gap = _gaps(runs["data4.f32"], runs["one.f32"])
     assert loss_gap < F32_TOLERANCE and gnorm_gap < 10 * F32_TOLERANCE, (loss_gap, gnorm_gap)
     g = runs["data4.f32"][0]
-    rows = TINY["cluster"]["hosts"] // 4
+    rows = pk.placed_rows(TINY["cluster"]["hosts"], 4) // 4  # a shard of the rung the 64 hosts are placed at
     assert {s.data.shape[0] for s in g.neighbors.addressable_shards} == {rows}
     assert g.by_dst is None  # no TPU here, so no table: the gather's VJP is the derived one
 
@@ -174,7 +174,7 @@ def test_no_collective_of_the_data4_step_moves_a_message_tensor(runs, kernel_run
     leaves a reduce-scatter as an all-reduce whose rows are sliced; the TPU's
     fuses the two.)"""
     for config, run in [(TINY, runs["data4.bf16"]), (KERNEL_TINY, kernel_runs["data4.tables"])]:
-        m, n = config["model"], config["cluster"]["hosts"]
+        m, n = config["model"], pk.placed_rows(config["cluster"]["hosts"], 4)  # the placed rows
         table, slots = n * m["hidden"], n // 4 * m["num_neighbors"]
         moved: dict[str, list[tuple]] = {}
         # (a long tuple's own `/*index=5*/` comments would end the match of its shapes)
@@ -325,7 +325,7 @@ def test_a_node_count_the_devices_do_not_divide_trains_as_on_one_device(hosts, n
     inputs = (cfg, cluster.graph, cluster.pairs)
     sharded = _program(inputs, meshlib.mesh_for_run(jax.devices()[:n_devices])[0], jnp.float32)
     one = _program(inputs, meshlib.mesh_for_run(jax.devices()[:1])[0], jnp.float32)
-    assert sharded[0].neighbors.shape[0] == meshlib.pad_to_multiple(hosts, n_devices) > hosts
+    assert sharded[0].neighbors.shape[0] == one[0].neighbors.shape[0] == pk.placed_rows(hosts, n_devices) > hosts
     assert np.all(np.isfinite(sharded[2])) and np.all(np.isfinite(sharded[3]))
     loss_gap, gnorm_gap = _gaps(sharded, one)
     assert loss_gap < F32_TOLERANCE and gnorm_gap < 10 * F32_TOLERANCE, (loss_gap, gnorm_gap)
@@ -380,7 +380,9 @@ def test_the_run_manifest_names_the_decision(tmp_path):
     placement = gnn["placement"]
     n = len(jax.devices())
     assert placement["mesh"] == {"data": n, "model": 1}
-    assert placement["decision"] == {"rule": "rows_over_data", "devices": n}
+    hosts = svc.run_history[-1]["dataset"]["nodes"]
+    assert placement["decision"] == {"rule": "rows_over_data", "devices": n, "hosts": hosts, "rows": 256,
+                                     "pad_pct": round(100 * (256 - hosts) / hosts, 2)}
     assert all(np.isfinite(gnn["evaluation"][k]) for k in ("final_loss",))
     graph = placement["graph"]
     assert len(graph["per_device_bytes"]) == n and max(graph["per_device_bytes"]) * n == graph["bytes"]

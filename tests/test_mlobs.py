@@ -707,6 +707,15 @@ class TestDfml:
                                   "curve": [(1, 0.9), (10, 0.1)],
                                   "examples": 100},
                 },
+                "gnn": {
+                    "artifact": "/tmp/g", "digest": "f" * 32,
+                    "evaluation": {"final_loss": 0.2},
+                    "telemetry": {"steps": 20, "final_loss": 0.2, "grad_norm": 0.1, "steps_per_sec": 17.7,
+                                  "curve": [(10, 0.3), (20, 0.2)], "examples": 100,
+                                  "calls": {"count": 2, "traced": 0, "first_ms": 550.0},
+                                  "placement": {"decision": {"rule": "one_device", "devices": 1, "hosts": 40000,
+                                                             "rows": 40960, "pad_pct": 2.4}}},
+                },
             }, 1_000.0, 1.0,
         )
 
@@ -725,3 +734,5 @@ class TestDfml:
         assert run(go()) == 0
         out = capsys.readouterr().out
         assert "v5-1" in out and "mlp" in out and "steps=10" in out
+        # the GNN's line says whether the run traced its program and what placement placed
+        assert "traced=0 first=550.0ms" in out and "hosts=40000 rows=40960 pad=2.4%" in out

@@ -206,7 +206,7 @@ def test_a_run_of_the_kept_shapes_is_served_the_kept_program(nothing_kept):
 
 # two runs a case, each other than the one before it in one thing the program is built from
 OTHER_RUNS = {
-    "nodes": [dict(num_nodes=128), dict(num_nodes=128, num_pairs=256)],  # node rows; the pool's rows
+    "nodes": [dict(num_nodes=300), dict(num_nodes=300, num_pairs=256)],  # node rows of another rung; the pool's rows
     "steps_per_call": [dict(steps_per_call=2), dict(steps_per_call=2, batch_size=32)],
     "hidden": [dict(hidden=64), dict(hidden=64, learning_rate=1e-3)],  # the model, the transform: the state's tree
 }

@@ -77,6 +77,10 @@ def test_a_training_run_leaves_the_span_tree(trained):
     call_spans = sorted((s for s in spans if s["name"] == "trainer.gnn.call"), key=lambda s: s["attrs"]["index"])
     assert [s["attrs"]["index"] for s in call_spans] == list(range(calls))
     assert {s["attrs"]["steps"] for s in call_spans} == {STEPS_PER_CALL}
+    # placement says the hosts it was given and the rows it placed them at (a rung of the ladder)
+    (place,) = (s["attrs"] for s in spans if s["name"] == "trainer.gnn.place")
+    nodes = next(s["attrs"]["nodes"] for s in spans if s["name"] == "trainer.train_gnn")
+    assert (place["hosts"], place["rows"]) == (nodes, 256) and nodes < 256
     assert sorted(s["attrs"]["model"] for s in spans if s["name"] == "trainer.export") == ["gnn", "mlp"]
 
 
