@@ -297,18 +297,16 @@ def bench_mlp_train(steps: int = 200) -> tuple[float, float]:
     cluster = synthetic.make_cluster(num_nodes=512, num_neighbors=16, num_pairs=32768, seed=7)
     cfg = train_mlp.MLPTrainConfig(steps=steps, batch_size=2048)
     with jax.default_device(jax.local_devices(backend="cpu")[0]):
-        # Each train() call builds a fresh optax transform, which is a static
-        # jit arg of _train_step — so EVERY call pays one trace + compile and
-        # a warmup call cannot pre-compile the timed one in memory.
-        # Difference of two runs cancels that (equal) cost: steps/s over the
-        # extra steps of the long run is the steady-state rate. Equal only if
-        # both runs meet the persistent compile cache in the same state, so
-        # one untimed run fills it first (without it the short run compiled,
-        # the long run loaded from the cache, and the difference went
-        # negative — found on the chip in PR 21).
+        # Difference of two runs cancels what both pay alike (the index draw,
+        # the pairs' upload, init, the last pull): steps/s over the extra
+        # steps of the long run is the steady-state rate. Equal only if
+        # neither timed run compiles: a run is scan calls of at most
+        # `STEPS_PER_CALL` steps, a program a chunk length, so one untimed run
+        # meets both lengths first (the whole chunks' and the short run's).
         short_steps = 3
         short_cfg = train_mlp.MLPTrainConfig(steps=short_steps, batch_size=2048)
-        train_mlp.train(short_cfg, cluster.pairs, seed=7)
+        warm_cfg = train_mlp.MLPTrainConfig(steps=train_mlp.STEPS_PER_CALL + short_steps, batch_size=2048)
+        train_mlp.train(warm_cfg, cluster.pairs, seed=7)
         t0 = time.perf_counter()
         train_mlp.train(short_cfg, cluster.pairs, seed=7)
         t_short = time.perf_counter() - t0

@@ -100,8 +100,8 @@ class TrainRunTelemetry:
         # mesh + per-device bytes of the placed run (train_gnn._placement);
         # None for a trainer that places nothing (the MLP)
         self.placement: dict | None = None
-        # the scan loop's call periods and turns (on_calls); None for a
-        # trainer whose loop makes no such calls (the MLP)
+        # the scan calls' periods and turns, and whether the run traced its
+        # program (on_calls: both trainers report it at the run's end)
         self.calls: dict | None = None
         # steps/s anchors at the FIRST report, not construction: the gap
         # between them is XLA setup + first-call compile (5-30 s on CPU),

@@ -35,7 +35,9 @@ def trained(tmp_path_factory):
     import asyncio
 
     tmp_path = tmp_path_factory.mktemp("spans")
-    train_gnn._kept = None  # whatever ran in this process before: the run builds its scan program
+    # whatever ran in this process before: the run builds its scan programs
+    train_gnn._kept = None
+    train_mlp._scan_steps.clear_cache()
     svc = TrainerService(TrainerConfig(
         model_dir=str(tmp_path / "models"),
         mlp=train_mlp.MLPTrainConfig(hidden=(16, 16), steps=20, batch_size=64),
@@ -93,7 +95,10 @@ def test_the_manifest_counts_the_calls_and_times_the_export(trained):
     # the run built its scan program: one trace, and a first call (trace, compile, steps) longer than any period
     assert calls["traced"] == 1 and calls["first_ms"] > calls["period_ms_max"]
     assert calls["stall_ms"] >= 0 and 0 < calls["turn_ms_p50"] <= calls["turn_ms_max"] < calls["period_ms_max"]
-    assert manifest["models"]["mlp"]["calls"] is None  # the MLP loop makes no scan calls
+    # the MLP loop's 20 steps are one scan call, which this run traced: nothing to pace
+    mlp_calls = manifest["models"]["mlp"]["calls"]
+    assert set(mlp_calls) == set(calls) and (mlp_calls["count"], mlp_calls["traced"]) == (1, 1)
+    assert mlp_calls["first_ms"] > 0 and mlp_calls["period_ms_p50"] is None and mlp_calls["stall_ms"] == 0
     for model in ("mlp", "gnn"):
         seconds = manifest["models"][model]["evaluation"]["export_seconds"]
         span = next(s for s in spans if s["name"] == "trainer.export" and s["attrs"]["model"] == model)
@@ -108,6 +113,7 @@ def test_a_warm_service_retrains_on_the_scan_program_it_kept(tmp_path):
     import asyncio
 
     train_gnn._kept = None
+    train_mlp._scan_steps.clear_cache()
     svc = TrainerService(TrainerConfig(
         model_dir=str(tmp_path / "models"), pool_rows=64,
         mlp=train_mlp.MLPTrainConfig(hidden=(16, 16), steps=4, batch_size=64),
@@ -127,6 +133,10 @@ def test_a_warm_service_retrains_on_the_scan_program_it_kept(tmp_path):
     assert cold["placement"]["graph"] == warm["placement"]["graph"] and cold["steps"] == warm["steps"] == GNN_STEPS
     assert cold["calls"]["traced"] == 1 and warm["calls"]["traced"] == 0
     assert warm["calls"]["first_ms"] < cold["calls"]["first_ms"] / 2
+    # and so does the MLP's loop: the pool's rows stayed, `jax.jit`'s own cache has the scan
+    cold, warm = (m["models"]["mlp"]["calls"] for m in list(svc.run_history)[-2:])
+    assert (cold["count"], cold["traced"]) == (1, 1) and (warm["count"], warm["traced"]) == (1, 0)
+    assert warm["first_ms"] < cold["first_ms"] / 2
 
 
 @pytest.mark.parametrize("calls,traced,expected", [
