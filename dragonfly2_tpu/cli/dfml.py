@@ -224,11 +224,23 @@ async def _train(args: argparse.Namespace) -> int:
         return 0
     for r in runs:
         ds = r.get("dataset") or {}
+        gc_run = r.get("gc") or {}
         print(
             f"  {r['run_id']:<22} {r.get('status', '?'):<8} "
             f"pairs={ds.get('pairs', 0):<8} nodes={ds.get('nodes', 0):<7} "
             f"wall={r.get('wall_s', 0.0):>7.2f}s"
+            + (f" gc={gc_run['ms']}ms (max {gc_run['max_ms']}ms, by generation {gc_run['collections']})" if gc_run else "")
         )
+        ingest = r.get("ingest")
+        if ingest and ingest.get("chunks"):
+            # what the upload(s) this run trains on cost the server: decode,
+            # the accumulator's fold, the close's merge, and waiting on the wire
+            print(
+                f"    ingest: sessions={ingest['sessions']} chunks={ingest['chunks']} "
+                f"MB={ingest['bytes'] / 1e6:.1f} rows={ingest['rows']} decode={ingest['decode_s']}s "
+                f"fold={ingest['fold_s']}s merge={ingest['merge_s']}s wait={ingest['wait_s']}s "
+                f"open_to_close={ingest['open_to_close_s']}s"
+            )
         for m, info in sorted((r.get("models") or {}).items()):
             line = (
                 f"    {m}: steps={info.get('steps', 0)} "
@@ -244,6 +256,8 @@ async def _train(args: argparse.Namespace) -> int:
                     f" calls={calls.get('count')} traced={calls.get('traced')} first={calls.get('first_ms')}ms"
                     f" period_p50={calls.get('period_ms_p50')}ms"
                     f" turn_max={calls.get('turn_ms_max')}ms stall={calls.get('stall_ms')}ms"
+                    f" dispatch_max={calls.get('dispatch_ms_max')}ms pull_max={calls.get('pull_ms_max')}ms"
+                    f" gc={calls.get('gc_ms')}ms"
                 )
             placed = (info.get("placement") or {}).get("decision") or {}
             if "rows" in placed:

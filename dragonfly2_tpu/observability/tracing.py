@@ -156,15 +156,25 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current_span.set(self)
+        return self.begin()
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._token is not None:
+            _current_span.reset(self._token)
+        self.finish(exc_type, exc, tb)
+
+    def begin(self) -> "Span":
+        """Start the span WITHOUT making it current: for a span that outlives
+        the call that opens it (a session over several RPCs). Its children
+        name it (`parent=span.context`); `finish` ends it, on the thread
+        that began it where the tracer annotates."""
         annotate = self._tracer.annotate
         if annotate is not None and self.sampled:
             self._annotation = annotate(self.name)
             self._annotation.__enter__()
         return self
 
-    def __exit__(self, exc_type, exc, tb) -> None:
-        if self._token is not None:
-            _current_span.reset(self._token)
+    def finish(self, exc_type=None, exc=None, tb=None) -> None:
         if not self.sampled:
             return
         elapsed = time.perf_counter() - self._t0
@@ -286,7 +296,9 @@ class Tracer:
     rng: Any = None  # random.random-compatible draw source (tests seed it)
     annotate: Optional[Callable[[str], ContextManager]] = None
     _ring: deque = field(default_factory=lambda: deque(maxlen=2048), repr=False)
-    _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
+    # re-entrant: a span can end inside a garbage collection that began
+    # while this thread held the lock (observability/gcwatch.py)
+    _lock: threading.RLock = field(default_factory=threading.RLock, repr=False)
     _fh: Any = field(default=None, repr=False)
     _otlp_fh: Any = field(default=None, repr=False)
     _otlp_buf: list = field(default_factory=list, repr=False)

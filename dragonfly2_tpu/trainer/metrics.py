@@ -103,6 +103,8 @@ class TrainRunTelemetry:
         # the scan calls' periods and turns, and whether the run traced its
         # program (on_calls: both trainers report it at the run's end)
         self.calls: dict | None = None
+        # the run's rate from its calls (on_calls), once it has them
+        self._calls_rate: float | None = None
         # steps/s anchors at the FIRST report, not construction: the gap
         # between them is XLA setup + first-call compile (5-30 s on CPU),
         # which would understate a short run's throughput 10x+. The first
@@ -151,40 +153,62 @@ class TrainRunTelemetry:
         with self._lock:
             self.placement = placement
 
-    def on_calls(self, calls: list[tuple[float, float]], *, traced: int) -> None:
+    def on_calls(
+        self,
+        calls: list[tuple[float, float, float]],
+        *,
+        traced: int,
+        first_steps: int,
+        gc_ms: float | None = None,
+    ) -> None:
         """Record, once at the run's end, how the host paced the run's scan
-        calls: `calls` holds each call's (start, end) on one monotonic clock.
-        A period runs from one call's start to the next one's, a turn from
-        one call's end to the next one's start (reports, the log line, the
-        thread hand-off). The first call compiles or loads the program
-        unless the trainer kept it from an earlier run, so periods count from
-        the second and the first call's start to end is `first_ms`; `traced`
-        is how often the run traced the scan program (1 where it built it, 0
-        where the kept one served: `first_ms` is then a period like any
-        other). `stall_ms` is the time by which periods exceeded 1.5 x their
-        median: what the host held the chip back, in a run nobody traced."""
-        starts = [a for a, _ in calls]
+        calls: `calls` holds each call's (start, enqueued, end) on one
+        monotonic clock, the enqueue being the jitted call's return and the
+        rest the D2H pull. A period runs from one call's start to the next
+        one's, a turn from one call's end to the next one's start (reports,
+        the log line, the thread hand-off). The first call compiles or loads
+        the program unless the trainer kept it from an earlier run, so
+        periods, dispatches and pulls count from the second and the first
+        call's start to end is `first_ms`; `traced` is how often the run
+        traced the scan program (1 where it built it, 0 where the kept one
+        served: `first_ms` is then a period like any other). `stall_ms` is the
+        time by which periods exceeded 1.5 x their median: what the host held
+        the chip back, in a run nobody traced; `dispatch_ms_max` and
+        `pull_ms_max` say on which side of the enqueue the longest wait fell,
+        `gc_ms` what the collector took while the calls ran (None where no
+        `observability.gcwatch` is installed). The run's rate
+        is the steps after the first call (`first_steps` of them in it) over
+        the second call's start to the last one's end."""
+        starts = [a for a, _, _ in calls]
         periods = [(b - a) * 1e3 for a, b in zip(starts[1:], starts[2:])]
-        turns = [(nxt - end) * 1e3 for (_, end), nxt in zip(calls, starts[1:])]
+        turns = [(nxt - end) * 1e3 for (_, _, end), nxt in zip(calls, starts[1:])]
         p50 = statistics.median(periods) if periods else None
         summary = {
             "count": len(calls),
             "traced": traced,
-            "first_ms": _round3((calls[0][1] - calls[0][0]) * 1e3 if calls else None),
+            "first_ms": _round3((calls[0][2] - calls[0][0]) * 1e3 if calls else None),
             "period_ms_p50": _round3(p50),
             "period_ms_max": _round3(max(periods, default=None)),
             "turn_ms_p50": _round3(statistics.median(turns) if turns else None),
             "turn_ms_max": _round3(max(turns, default=None)),
             "stall_ms": _round3(sum(max(0.0, p - 1.5 * p50) for p in periods)),
+            "dispatch_ms_max": _round3(max(((b - a) * 1e3 for a, b, _ in calls[1:]), default=None)),
+            "pull_ms_max": _round3(max(((c - b) * 1e3 for _, b, c in calls[1:]), default=None)),
+            "gc_ms": gc_ms,
         }
         with self._lock:
             self.calls = summary
+            post = self.steps - first_steps
+            wall = calls[-1][2] - calls[1][0] if len(calls) > 1 else 0.0
+            self._calls_rate = post / wall if post > 0 and wall > 0 else None
 
     def steps_per_sec(self) -> float | None:
         with self._lock:
             return self._steps_per_sec_locked()
 
     def _steps_per_sec_locked(self) -> float | None:
+        if self.calls is not None:
+            return self._calls_rate
         if self._t_first is None:
             return None
         wall = self._t_last - self._t_first

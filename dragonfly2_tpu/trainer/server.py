@@ -131,9 +131,14 @@ def main() -> None:
     # trace's clock (an atomic load while no profiler session runs)
     import jax.profiler
 
+    from dragonfly2_tpu.observability.gcwatch import default_watch
     from dragonfly2_tpu.observability.tracing import configure_default_tracer
 
-    configure_default_tracer(service="trainer").annotate = jax.profiler.TraceAnnotation
+    tracer = configure_default_tracer(service="trainer")
+    tracer.annotate = jax.profiler.TraceAnnotation
+    # the collector's pauses, counted into the run manifests (`gc`,
+    # `calls.gc_ms`), a generation-2 one also a `python.gc` span
+    default_watch().install(tracer)
     asyncio.run(
         run_trainer(
             host=args.host, port=args.port, model_dir=args.model_dir,

@@ -40,12 +40,14 @@ import asyncio
 import collections
 import logging
 import time
-from dataclasses import dataclass, field
+from dataclasses import asdict, dataclass, field, fields
 from pathlib import Path
 from typing import Any, Callable
 
 import numpy as np
 
+from dragonfly2_tpu.observability.gcwatch import default_watch as gc_watch
+from dragonfly2_tpu.observability.tracing import default_tracer
 from dragonfly2_tpu.telemetry.records import unpack_records
 from dragonfly2_tpu.trainer import (
     artifacts,
@@ -63,6 +65,35 @@ RUN_HISTORY_CAP = 64
 
 
 @dataclass
+class IngestCounts:
+    """What an upload cost the server, on its monotonic clock: always on, a
+    few clock reads a handler. `decode_s` is `unpack_records` over the chunks,
+    `fold_s` the accumulator's `add_downloads` / `add_probes`, `merge_s` the
+    close's `merge_from` and rotation check, `wait_s` the time from one
+    handler's end to the next one's start (the server waiting on the feeder
+    and the wire), `open_to_close_s` from `train_open`'s start to
+    `train_close`'s end. A run's manifest carries its sessions' sum under
+    `ingest` (`sessions` > 1 where the drainer coalesced closes)."""
+
+    sessions: int = 1
+    chunks: int = 0
+    bytes: int = 0
+    rows: int = 0
+    decode_s: float = 0.0
+    fold_s: float = 0.0
+    merge_s: float = 0.0
+    wait_s: float = 0.0
+    open_to_close_s: float = 0.0
+
+    def add(self, other: "IngestCounts") -> None:
+        for f in fields(self):
+            setattr(self, f.name, getattr(self, f.name) + getattr(other, f.name))
+
+    def report(self) -> dict:
+        return {k: round(v, 4) if isinstance(v, float) else v for k, v in asdict(self).items()}
+
+
+@dataclass
 class TrainSession:
     token: str
     scheduler_hostname: str = ""
@@ -72,14 +103,25 @@ class TrainSession:
     acc: datasetlib.DatasetAccumulator = field(
         default_factory=datasetlib.DatasetAccumulator
     )
-    rows: int = 0  # running row count — O(1) per chunk, not a per-call re-sum
+    ingest: IngestCounts = field(default_factory=IngestCounts)
     opened_at: float = field(default_factory=time.time)
     last_activity: float = field(default_factory=time.time)
-    # trace context captured at train_close: the background train run
-    # outlives the RPC that queued it, so its spans parent to this context
-    # explicitly (the drainer task's own captured contextvar points at
-    # whichever close FIRST started it — wrong for every later run)
+    # monotonic: the open handler's start, the last handler's end
+    t_open: float = 0.0
+    t_idle: float = 0.0
+    # `trainer.ingest`, open to close: begun at train_open (continuing the
+    # caller's trace, or a root drawn once against the sample rate), never
+    # made current, since it outlives the handler that opens it
+    span: Any = None
+    # the session span's context: chunks and merge are its children, and so
+    # is the background train run, which outlives the RPC that queued it
+    # (the drainer task's own captured contextvar points at whichever close
+    # FIRST started it — wrong for every later run). One upload and its run
+    # are one trace, sampled all or nothing
     trace_ctx: Any = None
+    # what the collector took from train_open to the run's manifest
+    # (observability/gcwatch.py; None where no watch is installed)
+    gc: Any = None
     # multi-source attribution (federation): every scheduler whose session
     # committed into the pool this run trains on — stamped at close time,
     # unioned when the drainer coalesces runs over the same pool
@@ -116,8 +158,6 @@ async def _export(model: str, save: Callable[[], Any]) -> tuple[Any, float]:
     """Run one model's artifact save in a worker thread under the
     `trainer.export` span; returns (what `save` returned, its seconds) —
     the manifest's `evaluation.export_seconds`, read beside the span."""
-    from dragonfly2_tpu.observability.tracing import default_tracer
-
     def run() -> tuple[Any, float]:
         t0 = time.perf_counter()
         with default_tracer().span("trainer.export", model=model):
@@ -163,39 +203,77 @@ class TrainerService:
     # ---- RPC surface (adapter passes payload dicts straight through) ----
 
     async def train_open(self, p: dict) -> dict:
+        t_open = time.perf_counter()
         self._evict_stale()
         self._next += 1
         token = f"sess-{self._next}-{int(time.time())}"
-        self._sessions[token] = TrainSession(
+        hostname = p.get("hostname", "")
+        span = default_tracer().span(  # dflint: disable=DF027 begun here, finished by train_close or eviction
+            "trainer.ingest", scheduler=hostname
+        ).begin()
+        sess = self._sessions[token] = TrainSession(
             token,
-            scheduler_hostname=p.get("hostname", ""),
+            scheduler_hostname=hostname,
             scheduler_id=p.get("scheduler_id", 0),
+            t_open=t_open,
+            span=span,
+            trace_ctx=span.context,
+            gc=gc_watch().open(),
         )
+        sess.t_idle = time.perf_counter()
         return {"token": token}
 
     async def train_chunk(self, p: dict) -> dict:
+        t_start = time.perf_counter()
         sess = self._sessions.get(p["token"])
         if sess is None:
             raise KeyError(f"unknown train session {p['token']!r}")
-        arr = unpack_records(p["data"])
-        if p["kind"] == "downloads":
-            sess.acc.add_downloads(arr)
-        elif p["kind"] == "probes":
-            sess.acc.add_probes(arr)
-        else:
-            raise ValueError(f"unknown dataset kind {p['kind']!r}")
-        sess.rows += len(arr)
+        counts, data, kind = sess.ingest, p["data"], p["kind"]
+        with default_tracer().span("trainer.ingest.chunk", parent=sess.trace_ctx, kind=kind) as sp:
+            arr = unpack_records(data)
+            t_decoded = time.perf_counter()
+            if kind == "downloads":
+                sess.acc.add_downloads(arr)
+            elif kind == "probes":
+                sess.acc.add_probes(arr)
+            else:
+                raise ValueError(f"unknown dataset kind {kind!r}")
+            if sp.sampled:
+                sp.set_attr("rows", len(arr))
+                sp.set_attr("bytes", len(data))
+        t_end = time.perf_counter()
+        counts.chunks += 1
+        counts.bytes += len(data)
+        counts.rows += len(arr)
+        counts.decode_s += t_decoded - t_start
+        counts.fold_s += t_end - t_decoded
+        counts.wait_s += t_start - sess.t_idle
+        sess.t_idle = t_end
         sess.last_activity = time.time()
-        return {"rows": sess.rows}
+        return {"rows": counts.rows}
 
     async def train_close(self, p: dict) -> dict:
+        t_start = time.perf_counter()
         sess = self._sessions.pop(p["token"], None)
         if sess is None:
             raise KeyError(f"unknown train session {p['token']!r}")
-        from dragonfly2_tpu.observability.tracing import Tracer
-
-        sess.trace_ctx = Tracer.current_context()
         self._evict_stale()
+        with default_tracer().span("trainer.ingest.merge", parent=sess.trace_ctx):
+            self._commit(sess)
+        t_end = time.perf_counter()
+        counts = sess.ingest
+        counts.merge_s += t_end - t_start
+        counts.wait_s += t_start - sess.t_idle
+        counts.open_to_close_s += t_end - sess.t_open
+        sess.span.finish()
+        # never await the previous run here: queue the session and let the
+        # drainer serialize training (one run at a time) off this RPC's back
+        self._queue.append(sess)
+        if self._drainer is None or self._drainer.done():
+            self._drainer = asyncio.ensure_future(self._drain())
+        return {"queued": True, "queue_depth": len(self._queue)}
+
+    def _commit(self, sess: TrainSession) -> None:
         if self.cfg.pool_rows > 0:
             # commit the session's aggregates into the shared pool — the
             # ONLY point session data becomes visible to training, so an
@@ -210,13 +288,7 @@ class TrainerService:
             sess.contributors = set(self._pool_contributors)
         else:
             sess.contributors = {(sess.scheduler_id, sess.scheduler_hostname)}
-        # never await the previous run here: queue the session and let the
-        # drainer serialize training (one run at a time) off this RPC's back
-        self._queue.append(sess)
-        if self._drainer is None or self._drainer.done():
-            self._drainer = asyncio.ensure_future(self._drain())
         self._maybe_rotate_pool()
-        return {"queued": True, "queue_depth": len(self._queue)}
 
     async def status(self, p: Any = None) -> dict:
         running = self._drainer is not None and not self._drainer.done()
@@ -290,9 +362,13 @@ class TrainerService:
         for token in stale:
             sess = self._sessions.pop(token)
             self.sessions_evicted += 1
+            gc_watch().close(sess.gc)
+            if sess.span.sampled:
+                sess.span.set_attr("evicted", True)
+            sess.span.finish()
             logger.warning(
                 "evicting stale train session %s from %s (idle %.0fs, %d rows)",
-                token, sess.scheduler_hostname, now - sess.last_activity, sess.rows,
+                token, sess.scheduler_hostname, now - sess.last_activity, sess.ingest.rows,
             )
 
     # ---- training driver ----
@@ -311,17 +387,20 @@ class TrainerService:
             while self._queue and self._queue[0].acc is sess.acc:
                 nxt = self._queue.popleft()
                 nxt.contributors |= sess.contributors
+                # the run pays for every upload it coalesced; the collector's
+                # window runs from the first one's open
+                nxt.ingest.add(sess.ingest)
+                gc_watch().close(nxt.gc)
+                nxt.gc = sess.gc
                 sess = nxt
                 self.trains_coalesced += 1
             self.trains_started += 1
             await self._train(sess)
 
     async def _train(self, sess: TrainSession) -> None:
-        from dragonfly2_tpu.observability.tracing import default_tracer
-
-        # parent = the trace of the train_close that queued this run: the
-        # announcer's upload root continues through ingest into the train
-        # and model publish, even though the RPC returned long ago
+        # parent = the session's `trainer.ingest`: the announcer's upload
+        # continues through ingest into the train and model publish, even
+        # though the RPC returned long ago
         t_run = time.perf_counter()
         started_at = time.time()
         try:
@@ -403,12 +482,12 @@ class TrainerService:
                 "nodes": result.get("num_nodes", 0),
                 "build_seconds": result.get("build_seconds", 0.0),
             },
+            "ingest": sess.ingest.report(),
+            "gc": gc_watch().close(sess.gc),
             "models": models,
         })
 
     async def _run_training(self, sess: TrainSession) -> dict:
-        from dragonfly2_tpu.observability.tracing import default_tracer
-
         acc = sess.acc  # the pool it merged into at close; rotation-safe
         t_build = time.perf_counter()
         # freeze() is a cheap loop-side snapshot; the O(nodes+edges+pairs)
@@ -478,7 +557,6 @@ class TrainerService:
                 "final_loss": losses[-1] if losses else float("nan"),
                 "steps": len(losses),
                 "train_seconds": round(train_seconds, 2),
-                "steps_per_sec": round(len(losses) / max(1e-9, train_seconds), 2),
             }
 
             def _save_gnn() -> tuple[Path, str, str | None]:

@@ -374,9 +374,9 @@ async def train_async(
         telemetry.on_placed(_placement(mesh, decision, hosts, state, g, cfg.batch_size))
     key = jax.random.PRNGKey(seed)
 
-    # each call's (start, end) in the worker, always on: two clock reads a
-    # call, summarized once at the run's end (telemetry.on_calls)
-    call_times: list[tuple[float, float]] = []
+    # each call's (start, enqueued, end) in the worker, always on: three
+    # clock reads a call, summarized once at the run's end (telemetry.on_calls)
+    call_times: list[tuple[float, float, float]] = []
 
     def _one_call(st, k, index):
         t_start = time.perf_counter()
@@ -387,13 +387,21 @@ async def train_async(
                 # persistent cache: under a key that holds its scope names
                 with jaxenv.op_names_in_cache_key() if index == 0 else contextlib.nullcontext():
                     st, (ls, gn) = multi_step(st, g, pool, sub)
+            t_enqueued = time.perf_counter()
             # D2H pull materializes the whole call's chain before returning to
             # the loop
             with tracer.span("trainer.gnn.pull"):
                 ls, gn = np.asarray(ls), np.asarray(gn)
-        call_times.append((t_start, time.perf_counter()))
+        call_times.append((t_start, t_enqueued, time.perf_counter()))
         return st, k, ls, gn
 
+    # imported here, not at the top: a line added above `multi_step` moves
+    # the source lines that key its compiled program, and every cell would
+    # compile its step once more
+    from dragonfly2_tpu.observability.gcwatch import default_watch as gc_watch
+
+    # what the collector takes from the first call's start to the last one's return
+    gc_window = gc_watch().open()
     losses: list[float] = []
     t0 = time.perf_counter()
     for i in range(calls):
@@ -410,6 +418,10 @@ async def train_async(
                 f"step {done}/{calls * steps_per_call} loss={losses[-1]:.5f} "
                 f"({done / (time.perf_counter() - t0):.2f} steps/s)"
             )
+    gc = gc_watch().close(gc_window)
     if telemetry is not None:
-        telemetry.on_calls(call_times, traced=_traces - traces_before)
+        telemetry.on_calls(
+            call_times, traced=_traces - traces_before, first_steps=steps_per_call,
+            gc_ms=None if gc is None else gc["ms"],
+        )
     return state, losses

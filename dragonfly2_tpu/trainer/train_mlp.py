@@ -28,6 +28,7 @@ import numpy as np
 import optax
 
 from dragonfly2_tpu.models.mlp import BandwidthMLP
+from dragonfly2_tpu.observability.gcwatch import default_watch as gc_watch
 from dragonfly2_tpu.trainer.synthetic import PairBatch
 
 
@@ -137,7 +138,8 @@ def train(
     params, opt_state = _init(model, tx, pairs.feats.shape[1], np.uint32(seed))
     feats, label = jax.device_put((pairs.feats, pairs.label))
     traces_before = _traces
-    call_times: list[tuple[float, float]] = []
+    call_times: list[tuple[float, float, float]] = []
+    gc_window = gc_watch().open()
     last_loss = 0.0
     pending = 0
     for start in range(0, cfg.steps, STEPS_PER_CALL):
@@ -145,9 +147,10 @@ def train(
         params, opt_state, losses, gnorms = _scan_steps(
             model, tx, params, opt_state, feats, label, rows[start:start + STEPS_PER_CALL]
         )
+        t_enqueued = time.perf_counter()
         # the D2H pull materializes the whole call's chain
         losses, gnorms = np.asarray(losses), np.asarray(gnorms)
-        call_times.append((t_start, time.perf_counter()))
+        call_times.append((t_start, t_enqueued, time.perf_counter()))
         if telemetry is not None:
             for i, (loss, gnorm) in enumerate(zip(losses, gnorms), start + 1):
                 pending += 1
@@ -158,8 +161,12 @@ def train(
         done = start + len(losses)
         if done % STEPS_PER_CALL == 0:
             log(f"mlp step {done}/{cfg.steps} loss={last_loss:.5f}")
+    gc = gc_watch().close(gc_window)
     if telemetry is not None:
-        telemetry.on_calls(call_times, traced=_traces - traces_before)
+        telemetry.on_calls(
+            call_times, traced=_traces - traces_before, first_steps=min(STEPS_PER_CALL, cfg.steps),
+            gc_ms=None if gc is None else gc["ms"],
+        )
     evaluation = {"train_mse": last_loss}
     if eval_pairs is not None and len(eval_pairs.child):
         evaluation["eval_mse"] = float(_eval_mse(model, params, eval_pairs.feats, eval_pairs.label))

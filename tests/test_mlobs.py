@@ -692,11 +692,16 @@ class TestDfml:
         from dragonfly2_tpu.cli import dfml
         from dragonfly2_tpu.rpc.core import RpcServer
         from dragonfly2_tpu.rpc.trainer import register_trainer
-        from dragonfly2_tpu.trainer.service import TrainerService, TrainSession
+        from dragonfly2_tpu.observability.gcwatch import GcWindow
+        from dragonfly2_tpu.trainer.service import IngestCounts, TrainerService, TrainSession
 
         svc = TrainerService()
+        collected = GcWindow()
+        collected.collections, collected.ms, collected.max_ms = [310, 28, 2], 912.5, 870.25
         svc._note_run(
-            TrainSession("t"), {
+            TrainSession("t", gc=collected, ingest=IngestCounts(
+                chunks=266, bytes=315_000_000, rows=1_081_344, decode_s=0.21, fold_s=1.75, merge_s=0.67,
+                wait_s=0.52, open_to_close_s=3.18)), {
                 "version": "v5-1", "num_pairs": 64, "num_nodes": 12,
                 "build_seconds": 0.01,
                 "mlp": {
@@ -712,7 +717,8 @@ class TestDfml:
                     "evaluation": {"final_loss": 0.2},
                     "telemetry": {"steps": 20, "final_loss": 0.2, "grad_norm": 0.1, "steps_per_sec": 17.7,
                                   "curve": [(10, 0.3), (20, 0.2)], "examples": 100,
-                                  "calls": {"count": 2, "traced": 0, "first_ms": 550.0},
+                                  "calls": {"count": 2, "traced": 0, "first_ms": 550.0, "dispatch_ms_max": 0.8,
+                                            "pull_ms_max": 2310.5, "gc_ms": 870.25},
                                   "placement": {"decision": {"rule": "one_device", "devices": 1, "hosts": 40000,
                                                              "rows": 40960, "pad_pct": 2.4}}},
                 },
@@ -736,3 +742,8 @@ class TestDfml:
         assert "v5-1" in out and "mlp" in out and "steps=10" in out
         # the GNN's line says whether the run traced its program and what placement placed
         assert "traced=0 first=550.0ms" in out and "hosts=40000 rows=40960 pad=2.4%" in out
+        # the upload's cost on the server, the collector's over the cycle and inside the GNN's calls
+        assert ("ingest: sessions=1 chunks=266 MB=315.0 rows=1081344 decode=0.21s fold=1.75s merge=0.67s "
+                "wait=0.52s open_to_close=3.18s") in out
+        assert "gc=912.5ms (max 870.25ms, by generation [310, 28, 2])" in out
+        assert "dispatch_max=0.8ms pull_max=2310.5ms gc=870.25ms" in out
