@@ -1092,10 +1092,14 @@ class PeerTaskConductor:
                         asyncio.ensure_future(self._piece_worker(session, queue))
                         for _ in range(min(self.cfg.piece_workers, len(available)))
                     ]
-                    await queue.join()
-                    for w in workers:
-                        w.cancel()
-                    await asyncio.gather(*workers, return_exceptions=True)
+                    try:
+                        await queue.join()
+                    finally:
+                        # on every exit from the round, so that no worker is
+                        # left to spawn a write after the drain below
+                        for w in workers:
+                            w.cancel()
+                        await asyncio.gather(*workers, return_exceptions=True)
                     # writes the workers deferred must land before the loop
                     # re-reads the bitset, or still-in-flight pieces would look
                     # missing and be refetched
@@ -1751,11 +1755,15 @@ class PeerTaskConductor:
             self.log.debug("piece %d success report failed: %r", idx, e)
 
     async def _drain_writes(self) -> None:
-        """Barrier for deferred store writes (round end / teardown). Write
-        tasks handle their own failures, so gather only shields teardown
-        from surprise cancellation races."""
-        while self._pending_writes:
-            await asyncio.gather(*list(self._pending_writes), return_exceptions=True)
+        """Barrier for deferred store writes (round end / teardown). It runs
+        once the round's workers are gone, so no write can be spawned while
+        it waits, and one gather over a snapshot is the whole barrier. Do not
+        loop until the set is empty: a finished task leaves it by a done
+        callback one loop turn later, and Python 3.12's gather over finished
+        tasks completes without yielding, so such a loop starves that
+        callback forever. Write tasks handle their own failures, so gather
+        only shields teardown from surprise cancellation races."""
+        await asyncio.gather(*self._pending_writes, return_exceptions=True)
 
     # ---- helpers ----
 

@@ -4,6 +4,7 @@ sha256 comparison — without k8s, per SURVEY.md §4 takeaway)."""
 
 import asyncio
 import hashlib
+import inspect
 import time
 
 import pytest
@@ -103,7 +104,8 @@ class Origin:
             self.inflight += 1
             self.max_inflight = max(self.max_inflight, self.inflight)
             try:
-                await asyncio.sleep(delay)
+                # a callable may return an awaitable (a gate) instead of seconds
+                await (delay if inspect.isawaitable(delay) else asyncio.sleep(delay))
             finally:
                 self.inflight -= 1
         rng = rng_header
@@ -334,6 +336,78 @@ class TestE2E:
                     # e2 must have full-verified: its piece digests came from
                     # a parent that was not done at sync time
                     assert verified_tasks.count(ts2.meta.task_id) >= 2  # e1 + e2
+                finally:
+                    await e1.stop()
+                    await e2.stop()
+
+        run(body())
+
+    def test_deferred_writes_landing_at_round_end_do_not_spin_the_drain(
+        self, run, tmp_path, payload, monkeypatch
+    ):
+        """A child of a parent that is still downloading runs one dispatch
+        round per batch of pieces the parent announces, and the rounds after
+        the first are the ones the write-behind governor may defer. A
+        deferred write that finished in the same event-loop turn that ended
+        its round froze the loop: on Python 3.12 `asyncio.gather` over done
+        tasks completes without yielding, so `while pending: await
+        gather(...)` never let the tasks' `discard` callbacks run (the hang of
+        `test_streaming_parent_digests_do_not_skip_verify`, ~1 run in 25).
+        Pinned here with gates, no sleeps: the origin holds piece 2 until the
+        child holds pieces 0-1, writes are deferred, and every store write of
+        the child is released by its round's workers finishing, the turn
+        before the drain looks at the pending set."""
+        from dragonfly2_tpu.daemon.storage import TaskStorage
+
+        orig_write = TaskStorage.write_piece_view
+        # writes land inline (no thread hop): a released write completes in
+        # the very turn its gate opens
+        monkeypatch.setattr(TaskStorage, "_INLINE_HASH_BYTES", 64 << 20)
+        cfg = ConductorConfig(
+            metadata_poll_interval=0.02, piece_timeout=10.0, defer_piece_writes=True
+        )
+        piece2 = asyncio.Event()
+        landed_at_round_end = []
+
+        async def write_at_round_end(self, index, data, *, digest):
+            if "peer2" in self.data_path.parts:
+                workers = [
+                    t for t in asyncio.all_tasks()
+                    if t.get_coro().__qualname__ == "PeerTaskConductor._piece_worker"
+                ]
+                await asyncio.wait(workers)  # the round's workers are gone
+                landed_at_round_end.append(index)
+            d = await orig_write(self, index, data, digest=digest)
+            if "peer2" in self.data_path.parts and self.finished_count() == 2:
+                piece2.set()  # the child holds 0-1: the parent may finish
+            return d
+
+        monkeypatch.setattr(TaskStorage, "write_piece_view", write_at_round_end)
+
+        async def body():
+            client = InProcessSchedulerClient(SchedulerService())
+            last_start = 8 << 20  # piece 2 of the 10 MiB payload
+            gate = lambda r: piece2.wait() if (r is None or r.start >= last_start) else 0
+            async with Origin({"model.bin": payload}, response_delay_s=gate) as origin:
+                e1 = make_engine(tmp_path, client, "peer1")
+                e2 = PeerEngine(
+                    storage_root=tmp_path / "peer2", scheduler=client,
+                    hostname="peer2", conductor_config=cfg,
+                )
+                await e1.start()
+                await e2.start()
+                try:
+                    url = origin.url("model.bin")
+                    t1 = asyncio.create_task(e1.download_task(url))
+                    while not (e1.storage.tasks() and e1.storage.tasks()[0].finished_count() == 2):
+                        await asyncio.sleep(0.01)
+                    out = tmp_path / "dl2.bin"
+                    await e2.download_task(url, output=out)
+                    await t1
+                    assert out.read_bytes() == payload
+                    assert e1.upload.bytes_served == len(payload)  # all p2p
+                    # two rounds, each ended by its writes: 0-1, then 2
+                    assert sorted(landed_at_round_end) == [0, 1, 2]
                 finally:
                     await e1.stop()
                     await e2.stop()
