@@ -241,6 +241,15 @@ async def _train(args: argparse.Namespace) -> int:
                 f"fold={ingest['fold_s']}s merge={ingest['merge_s']}s wait={ingest['wait_s']}s "
                 f"open_to_close={ingest['open_to_close_s']}s"
             )
+        pool = r.get("pool")
+        if pool:
+            # the pool the run trained on: rotations before it, uploads merged
+            # since, and the hosts its newest upload no longer named (stale)
+            print(
+                f"    pool: epoch={pool['epoch']} commits={pool['commits']} hosts={pool['hosts']} "
+                f"edges={pool['edges']} added={pool['hosts_added']} stale={pool['hosts_stale']} "
+                f"rotated={pool['rotated']}"
+            )
         for m, info in sorted((r.get("models") or {}).items()):
             line = (
                 f"    {m}: steps={info.get('steps', 0)} "
@@ -259,6 +268,10 @@ async def _train(args: argparse.Namespace) -> int:
                     f" dispatch_max={calls.get('dispatch_ms_max')}ms pull_max={calls.get('pull_ms_max')}ms"
                     f" gc={calls.get('gc_ms')}ms"
                 )
+            kept = info.get("kept")
+            if kept:
+                # the compiled scan programs the process keeps, and whether one served this run
+                line += f" kept={kept['programs']} served={kept['served']}"
             placed = (info.get("placement") or {}).get("decision") or {}
             if "rows" in placed:
                 # the hosts the run was given and the rows it placed them at (a rung of the ladder)

@@ -100,6 +100,9 @@ class TrainRunTelemetry:
         # mesh + per-device bytes of the placed run (train_gnn._placement);
         # None for a trainer that places nothing (the MLP)
         self.placement: dict | None = None
+        # the scan programs the process keeps after the run, and whether one
+        # of them served it (on_kept; None for the MLP, which keeps none)
+        self.kept: dict | None = None
         # the scan calls' periods and turns, and whether the run traced its
         # program (on_calls: both trainers report it at the run's end)
         self.calls: dict | None = None
@@ -152,6 +155,12 @@ class TrainRunTelemetry:
         """Record where the run's arrays were placed (once, after set-up)."""
         with self._lock:
             self.placement = placement
+
+    def on_kept(self, *, programs: int, served: bool) -> None:
+        """Record, once placed, how many compiled scan programs the process
+        keeps and whether one kept from an earlier run served this one."""
+        with self._lock:
+            self.kept = {"programs": programs, "served": served}
 
     def on_calls(
         self,
@@ -238,6 +247,7 @@ class TrainRunTelemetry:
                 "steps_per_sec": sps,
                 "curve": [(s, round(v, 6)) for s, v in self._curve],
                 "placement": self.placement,
+                "kept": self.kept,
                 "calls": self.calls,
             }
 

@@ -126,6 +126,12 @@ class TrainSession:
     # committed into the pool this run trains on — stamped at close time,
     # unioned when the drainer coalesces runs over the same pool
     contributors: set = field(default_factory=set)
+    # the pool this session committed into, as the commit left it (the run
+    # manifest's `pool`): `epoch` rotations before it, `commits` merged into
+    # it since the last rotation, its `hosts` and `edges`, the `hosts_added`
+    # by this commit, the `hosts_stale` this commit did not name (hosts the
+    # scheduler's host GC has dropped since), and whether the close `rotated`
+    pool: dict | None = None
 
 
 @dataclass
@@ -181,6 +187,8 @@ class TrainerService:
         # schedulers that have committed into the CURRENT pool epoch —
         # cleared on rotation with the pool it describes
         self._pool_contributors: set[tuple[int, str]] = set()
+        # uploads committed into the CURRENT pool epoch
+        self._pool_commits = 0
         self._sessions: dict[str, TrainSession] = {}
         self._next = 0
         self._queue: collections.deque[TrainSession] = collections.deque()  # dflint: disable=DF034 depth is bounded by one pending close per scheduler (the drainer coalesces same-pool entries); a maxlen would silently DROP a committed training run from the far end
@@ -258,8 +266,11 @@ class TrainerService:
         if sess is None:
             raise KeyError(f"unknown train session {p['token']!r}")
         self._evict_stale()
-        with default_tracer().span("trainer.ingest.merge", parent=sess.trace_ctx):
+        with default_tracer().span("trainer.ingest.merge", parent=sess.trace_ctx) as sp:
             self._commit(sess)
+            if sp.sampled:
+                sp.set_attr("hosts_added", sess.pool["hosts_added"])
+                sp.set_attr("rotated", sess.pool["rotated"])
         t_end = time.perf_counter()
         counts = sess.ingest
         counts.merge_s += t_end - t_start
@@ -274,7 +285,9 @@ class TrainerService:
         return {"queued": True, "queue_depth": len(self._queue)}
 
     def _commit(self, sess: TrainSession) -> None:
+        named, hosts_before, commits = sess.acc.num_hosts, 0, 1
         if self.cfg.pool_rows > 0:
+            hosts_before = self._acc.num_hosts
             # commit the session's aggregates into the shared pool — the
             # ONLY point session data becomes visible to training, so an
             # upload that failed mid-stream (and will be retried in full)
@@ -286,9 +299,17 @@ class TrainerService:
             # every scheduler that fed THIS pool epoch, not just the closer
             self._pool_contributors.add((sess.scheduler_id, sess.scheduler_hostname))
             sess.contributors = set(self._pool_contributors)
+            self._pool_commits += 1
+            commits = self._pool_commits
         else:
             sess.contributors = {(sess.scheduler_id, sess.scheduler_hostname)}
-        self._maybe_rotate_pool()
+        pool, epoch = sess.acc, self.pool_rotations
+        rotated = self._maybe_rotate_pool()  # `pool` stays the one this session committed into
+        sess.pool = {
+            "epoch": epoch, "commits": commits, "hosts": pool.num_hosts, "edges": pool.num_edges,
+            "hosts_added": pool.num_hosts - hosts_before, "hosts_stale": pool.num_hosts - named,
+            "rotated": rotated,
+        }
 
     async def status(self, p: Any = None) -> dict:
         running = self._drainer is not None and not self._drainer.done()
@@ -334,10 +355,11 @@ class TrainerService:
 
     # ---- session lifecycle ----
 
-    def _maybe_rotate_pool(self) -> None:
+    def _maybe_rotate_pool(self) -> bool:
         """Aggregates (host table, edge sums, node counters) only grow —
         swap in a fresh pool once host churn blows past the caps. Sessions
-        already queued hold their own reference to the old pool."""
+        already queued hold their own reference to the old pool. Returns
+        whether it rotated."""
         cfg = self.cfg
         over_hosts = cfg.pool_max_hosts > 0 and self._acc.num_hosts > cfg.pool_max_hosts
         over_edges = cfg.pool_max_edges > 0 and self._acc.num_edges > cfg.pool_max_edges
@@ -348,7 +370,9 @@ class TrainerService:
             )
             self._acc = datasetlib.DatasetAccumulator(max_pair_rows=cfg.pool_rows)
             self._pool_contributors = set()
+            self._pool_commits = 0
             self.pool_rotations += 1
+        return over_hosts or over_edges
 
     def _evict_stale(self) -> None:
         """Drop sessions with no traffic for session_ttl. Keyed on
@@ -483,6 +507,7 @@ class TrainerService:
                 "build_seconds": result.get("build_seconds", 0.0),
             },
             "ingest": sess.ingest.report(),
+            "pool": sess.pool,
             "gc": gc_watch().close(sess.gc),
             "models": models,
         })

@@ -14,7 +14,7 @@ shard, with the gather's own `all_gather` of the states.
 The compiled step is kept across runs of the same shapes: model and optimizer
 transform are made once per configuration, so two runs' states have one tree
 structure, and the `jax.jit` of `multi_step` outlives the run that built it
-(one program at a time: `shard_for_training_scan`). A warm trainer's retrain
+(two programs at most: `shard_for_training_scan`). A warm trainer's retrain
 starts with a call like any other; the run manifest's `calls.traced` says
 whether it did. The node rows are placed at a rung of a ladder of row counts
 (`ops/neighbor_agg_pallas.placed_rows`), padded with copies of node 0, so a
@@ -191,12 +191,12 @@ def pad_graph(g: TopoGraph, n_padded: int) -> TopoGraph:
     return TopoGraph(*(np.concatenate([a, np.repeat(np.asarray(a[:1]), pad, axis=0)]) for a in g[:4]))
 
 
-# The one scan program this process keeps: what it was built from, and the
-# jitted `multi_step`. `_traces` counts how often `multi_step`'s Python body
-# has run. Plain module state: the trainer's drainer starts its runs one after
-# the other. Nothing data-sized hangs on it: shapes and shardings in the
-# first, sizes and the step body in the second's closure.
-_kept: tuple[tuple, Callable] | None = None
+# The scan programs this process keeps: what each was built from -> its jitted
+# `multi_step`, the most recently used last; and how often `multi_step`'s body
+# has run. Two: a pool that rotates every second upload alternates between two
+# placements. Runs come one after the other (the drainer); no array hangs on it.
+KEPT_PROGRAMS = 2
+_kept: dict[tuple, Callable] = {}
 _traces = 0
 
 
@@ -219,12 +219,10 @@ def shard_for_training_scan(
     (state, g, pairs, multi_step) where ``multi_step(state, g, pairs, key) ->
     (state, (losses[steps_per_call], grad_norms[steps_per_call]))``.
 
-    The jitted `multi_step` outlives the run: the last one built is kept
-    under everything it was built from or closes over (`_kept`), and a run
-    placed to the same gets it back, its first call a hit of `jax.jit`'s
-    own cache. Any other run lets the kept one go and builds its own.
+    The jitted `multi_step` outlives the run, kept under everything it was
+    built from or closes over (`_kept`): a run placed to the same gets it back,
+    its first call a hit of `jax.jit`'s own cache; any other builds its own.
     """
-    global _kept
     batch_size = meshlib.pad_to_multiple(batch_size, mesh.shape[meshlib.DATA_AXIS])
     state, state_sh, g, g_sh = _place_sharded(state, g, mesh)
     # the full pool is small (MBs) and replicated; sampled rows get
@@ -237,10 +235,12 @@ def shard_for_training_scan(
     # of the table follows from N, K and the block count
     shardings, structure = jax.tree.flatten((state_sh, g_sh, pool_sh))
     shapes = [(a.shape, a.dtype) for a in jax.tree.leaves((state, g, pairs))]
-    built_from = (mesh, batch_size, steps_per_call, structure, shardings, shapes)
-    if _kept is not None and _kept[0] == built_from:
-        return state, g, pairs, _kept[1]
-    _kept = None  # one program at a time: the old one goes before the new one is built
+    built_from = (mesh, batch_size, steps_per_call, structure, tuple(shardings), tuple(shapes))
+    if built_from in _kept:
+        _kept[built_from] = _kept.pop(built_from)  # the most recently used goes last
+        return state, g, pairs, _kept[built_from]
+    for old in list(_kept)[: len(_kept) + 1 - KEPT_PROGRAMS]:  # the least recently used go first
+        del _kept[old]
     batch_sh = meshlib.batch_sharding(mesh)
     step = make_train_step()
 
@@ -268,7 +268,7 @@ def shard_for_training_scan(
         out_shardings=(state_sh, meshlib.replicated(mesh)),
         donate_argnums=(0,),
     )
-    _kept = (built_from, jitted)
+    _kept[built_from] = jitted
     return state, g, pairs, jitted
 
 
@@ -332,7 +332,8 @@ async def train_async(
     ride the scan's ys and are pulled every call, so the compiled program is
     the same with and without it. It also gets every call's start and end
     once, at the run's end, with how often this run traced `multi_step` (1
-    where it built the program, 0 where the kept one served it).
+    where it built the program, 0 where the kept one served it), and once
+    placed, whether a kept program served the run and how many are kept.
 
     The mesh, when the caller gives none, is `parallel.mesh.mesh_for_run`'s
     (every device on `data`); the run manifest's `placement.decision` says so,
@@ -369,9 +370,12 @@ async def train_async(
                 batch_size=cfg.batch_size, steps_per_call=steps_per_call,
             )
 
+    kept_before = list(_kept.values())
     state, g, pool, multi_step = await asyncio.to_thread(_setup)
     if telemetry is not None:
         telemetry.on_placed(_placement(mesh, decision, hosts, state, g, cfg.batch_size))
+        # whether a program kept from an earlier run served this one, and how many are kept now
+        telemetry.on_kept(programs=len(_kept), served=any(f is multi_step for f in kept_before))
     key = jax.random.PRNGKey(seed)
 
     # each call's (start, enqueued, end) in the worker, always on: three

@@ -88,7 +88,7 @@ def test_a_placement_with_the_sorted_table_is_served_the_kept_program(monkeypatc
     from dragonfly2_tpu.ops import neighbor_agg_pallas as pk
 
     monkeypatch.setattr(pk, "PLATFORM", "cpu")
-    monkeypatch.setattr(train_gnn, "_kept", None)
+    monkeypatch.setattr(train_gnn, "_kept", {})
     cfg = train_gnn.GNNTrainConfig(hidden=128, embed_dim=16, num_layers=2, batch_size=64)
     programs, losses, traces = [], [], []
     for seed in (1, 2, 1):

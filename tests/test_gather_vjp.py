@@ -186,7 +186,7 @@ def test_a_step_without_a_table_is_jnp_takes_on_one_device_and_on_four(monkeypat
 
     def lowered(n_devices):
         mesh, _ = meshlib.mesh_for_run(jax.devices()[:n_devices])
-        train_gnn._kept = None  # the test swaps the gather under the program: each text from a build of its own
+        train_gnn._kept.clear()  # the test swaps the gather under the program: each text from a build of its own
         state, g, pool, step = train_gnn.shard_for_training_scan(
             unplaced, graph, pairs, mesh, batch_size=64, steps_per_call=2)
         assert g.by_dst is None
@@ -361,7 +361,7 @@ def test_one_device_takes_the_table_and_the_program_it_always_had(monkeypatch):
         assert got.dtype == want.dtype and got.shape == want.shape and (got == want).all()
 
     def lowered():
-        train_gnn._kept = None  # the test swaps the gather under the program: each text from a build of its own
+        train_gnn._kept.clear()  # the test swaps the gather under the program: each text from a build of its own
         state, g, pool, step = train_gnn.shard_for_training_scan(
             train_gnn.init_state(cfg, graph, 0), graph, pairs, mesh, batch_size=64, steps_per_call=2)
         assert isinstance(g.by_dst, pk.EdgesByDst) and {len(leaf.sharding.device_set) for leaf in g.by_dst} == {1}

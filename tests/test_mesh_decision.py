@@ -340,7 +340,7 @@ def test_one_device_lowers_to_the_program_a_hand_built_mesh_gives(dataset):
     assert decision["rule"] == "one_device" and dict(decided.shape) == {"data": 1, "model": 1}
     texts = []
     for mesh in (decided, meshlib.make_mesh(jax.devices()[:1])):
-        train_gnn._kept = None  # equal meshes would be served one kept program: each text from a build of its own
+        train_gnn._kept.clear()  # equal meshes would be served one kept program: each text from a build of its own
         state, g, pool, multi_step = train_gnn.shard_for_training_scan(
             train_gnn.init_state(cfg, graph, 0), graph, pairs, mesh,
             batch_size=cfg.batch_size, steps_per_call=STEPS)

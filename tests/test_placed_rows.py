@@ -104,7 +104,7 @@ def _float32_run(cluster, mesh, monkeypatch, tmp_path) -> dict:
     model = TopoScorer(hidden=CFG.hidden, embed_dim=CFG.embed_dim, num_layers=CFG.num_layers, dtype=jnp.float32)
     state = train_gnn.init_state(CFG, cluster.graph, 0).replace(apply_fn=model.apply)
     before = jax.tree.map(np.asarray, state.params)
-    train_gnn._kept = None
+    train_gnn._kept.clear()
     state, g, pool, multi_step = train_gnn.shard_for_training_scan(
         state, cluster.graph, cluster.pairs, mesh, batch_size=CFG.batch_size, steps_per_call=STEPS)
     first = PairBatch(*(a[: CFG.batch_size] for a in pool))
@@ -164,3 +164,39 @@ def test_a_host_count_that_moves_inside_a_rung_keeps_the_program(nothing_kept): 
         {"rule": "rows_over_data", "devices": n, "hosts": 420, "rows": 512, "pad_pct": 21.9},
         {"rule": "rows_over_data", "devices": n, "hosts": 600, "rows": 768, "pad_pct": 28.0},
     ]
+
+
+def test_two_placements_that_alternate_are_both_kept_and_a_third_evicts_the_least_recently_used(nothing_kept):  # noqa: F811
+    """A pool that rotates every second upload alternates between two rungs:
+    one upload's hosts (300, 420: 512 rows) and two uploads' (600, 700: 768).
+    Each builds its program once, and from then on both are served; a third
+    rung (900: 1,024 rows) lets the least recently used go (nothing holds it),
+    and that rung's next run builds again. The manifest's `kept` says so."""
+    import gc
+    import weakref
+
+    from dragonfly2_tpu.trainer.metrics import TrainRunTelemetry
+
+    mesh = meshlib.make_mesh(jax.devices()[:1])
+    runs, programs = [], {}
+    for hosts in (300, 600, 420, 700, 900, 600, 300):
+        cluster = synthetic.make_cluster(num_nodes=hosts, num_neighbors=4, num_pairs=512, seed=hosts)
+        sink = TrainRunTelemetry("gnn")
+        asyncio.run(train_gnn.train_async(
+            train_gnn.GNNTrainConfig(**KEPT_CFG), cluster.graph, cluster.pairs, steps=6, steps_per_call=3,
+            telemetry=sink, mesh=mesh))
+        manifest = sink.summary()
+        rows = manifest["placement"]["decision"]["rows"]
+        programs.setdefault(rows, weakref.ref(list(train_gnn._kept.values())[-1]))
+        runs.append((rows, manifest["calls"]["traced"], manifest["kept"]))
+        if hosts == 900:
+            gc.collect()
+            assert programs[512]() is None and programs[768]() is not None  # 512's was the least recently used
+    assert [r for r, _, _ in runs] == [512, 768, 512, 768, 1024, 768, 512]
+    assert [t for _, t, _ in runs] == [1, 1, 0, 0, 1, 0, 1]
+    assert [k for _, _, k in runs] == [
+        {"programs": 1, "served": False}, {"programs": 2, "served": False}, {"programs": 2, "served": True},
+        {"programs": 2, "served": True}, {"programs": 2, "served": False}, {"programs": 2, "served": True},
+        {"programs": 2, "served": False},
+    ]
+    assert len(train_gnn._kept) == train_gnn.KEPT_PROGRAMS

@@ -164,12 +164,17 @@ def test_train_async_without_telemetry_returns_the_same_losses(served_runs):
 @pytest.fixture
 def nothing_kept():
     """A process that has kept no scan program, before the test and after it."""
-    train_gnn._kept = None
+    train_gnn._kept.clear()
     yield
-    train_gnn._kept = None
+    train_gnn._kept.clear()
 
 
 KEPT_CFG = dict(hidden=32, embed_dim=16, num_layers=2, batch_size=64, warmup_steps=2)
+
+
+def newest_kept():
+    """The scan program the last run built or was served (kept most recently used last)."""
+    return list(train_gnn._kept.values())[-1]
 
 
 def _served(cluster, *, mesh, steps_per_call=3, **cfg):
@@ -195,12 +200,12 @@ def test_a_run_of_the_kept_shapes_is_served_the_kept_program(nothing_kept):
     for mesh in (lambda: meshlib.make_mesh(jax.devices()[:1]), lambda: None):
         _, calls = _served(first, mesh=mesh())
         assert calls["traced"] == 1 and calls["count"] == 2 and calls["first_ms"] > 0
-        program = train_gnn._kept[1]
+        program = newest_kept()
         kept_losses, calls = _served(second, mesh=mesh())
-        assert calls["traced"] == 0 and train_gnn._kept[1] is program and program._cache_size() == 1
-        train_gnn._kept = None
+        assert calls["traced"] == 0 and newest_kept() is program and program._cache_size() == 1
+        train_gnn._kept.clear()
         fresh_losses, calls = _served(second, mesh=mesh())
-        assert calls["traced"] == 1 and train_gnn._kept[1] is not program
+        assert calls["traced"] == 1 and newest_kept() is not program
         assert kept_losses == fresh_losses and len(kept_losses) == 6
 
 
@@ -215,20 +220,24 @@ OTHER_RUNS = {
 @pytest.mark.parametrize("others", OTHER_RUNS)
 def test_a_run_of_other_shapes_builds_its_own_and_one_program_is_kept(nothing_kept, others):
     """Whatever the program was built from is part of what it is kept under:
-    a run that differs in any of it traces its own, and the one it replaces
-    is let go (nothing holds it: the weak reference dies)."""
+    a run that differs in any of it traces its own; of the programs built, the
+    newest `KEPT_PROGRAMS` are kept, and the one a new build replaces (the
+    least recently used) is let go (nothing holds it: the weak reference dies)."""
     import gc
     import weakref
 
     mesh = meshlib.make_mesh(jax.devices()[:1])
     sizes = dict(num_nodes=64, num_neighbors=4, num_pairs=512, seed=1)
+    built = []
     for other in [{}, *OTHER_RUNS[others]]:
-        replaced = weakref.ref(train_gnn._kept[1]) if train_gnn._kept else None
         cluster = synthetic.make_cluster(**{**sizes, **{k: v for k, v in other.items() if k in sizes}})
         _, calls = _served(cluster, mesh=mesh, **{k: v for k, v in other.items() if k not in sizes})
-        assert calls["traced"] == 1 and train_gnn._kept[1]._cache_size() == 1, other
+        assert calls["traced"] == 1 and newest_kept()._cache_size() == 1, other
+        built.append(weakref.ref(newest_kept()))
         gc.collect()
-        assert replaced is None or replaced() is None, other
+        assert len(train_gnn._kept) == min(len(built), train_gnn.KEPT_PROGRAMS), other
+        assert all(ref() is None for ref in built[: -train_gnn.KEPT_PROGRAMS]), other
+        assert all(ref() in train_gnn._kept.values() for ref in built[-train_gnn.KEPT_PROGRAMS:]), other
 
 
 def test_two_states_of_one_configuration_have_one_tree_structure():

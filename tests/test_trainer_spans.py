@@ -59,7 +59,7 @@ def trained(tmp_path_factory):
 
     tmp_path = tmp_path_factory.mktemp("spans")
     # whatever ran in this process before: the run builds its scan programs
-    train_gnn._kept = None
+    train_gnn._kept.clear()
     train_mlp._scan_steps.clear_cache()
     svc = _service(tmp_path)
     store = TelemetryStorage(tmp_path / "telemetry")
@@ -153,7 +153,7 @@ def test_a_warm_service_retrains_on_the_scan_program_it_kept(tmp_path):
     long one."""
     import asyncio
 
-    train_gnn._kept = None
+    train_gnn._kept.clear()
     train_mlp._scan_steps.clear_cache()
     svc = TrainerService(TrainerConfig(
         model_dir=str(tmp_path / "models"), pool_rows=64,
