@@ -234,12 +234,16 @@ async def _train(args: argparse.Namespace) -> int:
         ingest = r.get("ingest")
         if ingest and ingest.get("chunks"):
             # what the upload(s) this run trains on cost the server: decode,
-            # the accumulator's fold, the close's merge, and waiting on the wire
+            # the accumulator's fold, the close's merge, and waiting on the
+            # wire; the part that ran while a run trained; whose uploads the
+            # pool holds
             print(
                 f"    ingest: sessions={ingest['sessions']} chunks={ingest['chunks']} "
                 f"MB={ingest['bytes'] / 1e6:.1f} rows={ingest['rows']} decode={ingest['decode_s']}s "
                 f"fold={ingest['fold_s']}s merge={ingest['merge_s']}s wait={ingest['wait_s']}s "
                 f"open_to_close={ingest['open_to_close_s']}s"
+                + (f" in_run={ingest['in_run_s']}s ({ingest['chunks_in_run']} chunks)" if "in_run_s" in ingest else "")
+                + (f" schedulers={','.join(ingest['schedulers'])}" if ingest.get("schedulers") else "")
             )
         pool = r.get("pool")
         if pool:
@@ -268,6 +272,10 @@ async def _train(args: argparse.Namespace) -> int:
                     f" dispatch_max={calls.get('dispatch_ms_max')}ms pull_max={calls.get('pull_ms_max')}ms"
                     f" gc={calls.get('gc_ms')}ms"
                 )
+                if "in_ingest" in calls:
+                    # the gap between calls where ingest shared the loop, and where it did not
+                    line += (f" in_ingest={len(calls['in_ingest'])} gap_in_ingest={calls['gap_ms_in_ingest']}ms"
+                             f" gap_clear={calls['gap_ms_clear']}ms")
             kept = info.get("kept")
             if kept:
                 # the compiled scan programs the process keeps, and whether one served this run

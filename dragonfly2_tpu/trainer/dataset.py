@@ -530,7 +530,10 @@ class DatasetAccumulator:
 
     def freeze(self) -> FrozenIngest:
         """Cheap consistent snapshot (copies only the aggregate arrays; pair
-        chunks are append-only so a shallow tuple copy suffices)."""
+        chunks are append-only so a shallow tuple copy suffices: no array of a
+        chunk is written after it is appended, and eviction pops the live
+        list, not the tuple). Merges and folds that land after it, on the
+        loop while finalize() runs on a worker thread, leave it as it was."""
         return FrozenIngest(
             host_index=dict(self.hosts.index),
             edge_src=self._edge_src.view().copy(),

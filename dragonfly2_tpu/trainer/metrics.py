@@ -108,6 +108,10 @@ class TrainRunTelemetry:
         self.calls: dict | None = None
         # the run's rate from its calls (on_calls), once it has them
         self._calls_rate: float | None = None
+        # set by a caller whose event loop hands the calls back: the (start,
+        # end) of every ingest handler that loop ran during the run, on the
+        # calls' clock (the trainer service's; None where nobody counts them)
+        self.loop_ingest: list[tuple[float, float]] | None = None
         # steps/s anchors at the FIRST report, not construction: the gap
         # between them is XLA setup + first-call compile (5-30 s on CPU),
         # which would understate a short run's throughput 10x+. The first
@@ -185,7 +189,12 @@ class TrainRunTelemetry:
         the chip back, in a run nobody traced; `dispatch_ms_max` and
         `pull_ms_max` say on which side of the enqueue the longest wait fell,
         `gc_ms` what the collector took while the calls ran (None where no
-        `observability.gcwatch` is installed). The run's rate
+        `observability.gcwatch` is installed). With `loop_ingest` set, a gap
+        is the host's part of the wait between two calls, one call's end to
+        the next one's enqueue; `in_ingest` lists the calls during which, or
+        in whose gap after, an ingest handler ran on the loop, and
+        `gap_ms_in_ingest` / `gap_ms_clear` are the median gap after such a
+        call and after the others. The run's rate
         is the steps after the first call (`first_steps` of them in it) over
         the second call's start to the last one's end."""
         starts = [a for a, _, _ in calls]
@@ -205,6 +214,8 @@ class TrainRunTelemetry:
             "pull_ms_max": _round3(max(((c - b) * 1e3 for _, b, c in calls[1:]), default=None)),
             "gc_ms": gc_ms,
         }
+        if self.loop_ingest is not None:
+            summary.update(_ingest_marks(calls, self.loop_ingest))
         with self._lock:
             self.calls = summary
             post = self.steps - first_steps
@@ -254,3 +265,21 @@ class TrainRunTelemetry:
 
 def _round3(value: float | None) -> float | None:
     return None if value is None else round(value, 3)
+
+
+def _ingest_marks(calls: list[tuple[float, float, float]], busy: list[tuple[float, float]]) -> dict:
+    """Which calls an ingest handler on the loop ran during or after (from
+    the call's start to the next call's enqueue; the last call's end), and
+    the median gap (one call's end to the next one's enqueue) after those
+    calls and after the others."""
+    ends = [enqueued for _, enqueued, _ in calls[1:]] + [calls[-1][2]] if calls else []
+    marked = [i for i, ((start, _, _), until) in enumerate(zip(calls, ends))
+              if any(a < until and b > start for a, b in busy)]
+    hit, gaps = set(marked), {True: [], False: []}
+    for i, ((_, _, end), (_, enqueued, _)) in enumerate(zip(calls, calls[1:])):
+        gaps[i in hit].append((enqueued - end) * 1e3)
+    return {
+        "in_ingest": marked,
+        "gap_ms_in_ingest": _round3(statistics.median(gaps[True]) if gaps[True] else None),
+        "gap_ms_clear": _round3(statistics.median(gaps[False]) if gaps[False] else None),
+    }

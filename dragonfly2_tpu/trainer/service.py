@@ -72,8 +72,11 @@ class IngestCounts:
     close's `merge_from` and rotation check, `wait_s` the time from one
     handler's end to the next one's start (the server waiting on the feeder
     and the wire), `open_to_close_s` from `train_open`'s start to
-    `train_close`'s end. A run's manifest carries its sessions' sum under
-    `ingest` (`sessions` > 1 where the drainer coalesced closes)."""
+    `train_close`'s end. `in_run_s` is the part of decode, fold and merge
+    that ran while the drainer was inside a run (on the loop that hands the
+    run's scan calls back), `chunks_in_run` the chunks folded then. A run's
+    manifest carries its sessions' sum under `ingest` (`sessions` > 1 where
+    the drainer coalesced closes)."""
 
     sessions: int = 1
     chunks: int = 0
@@ -84,6 +87,8 @@ class IngestCounts:
     merge_s: float = 0.0
     wait_s: float = 0.0
     open_to_close_s: float = 0.0
+    in_run_s: float = 0.0
+    chunks_in_run: int = 0
 
     def add(self, other: "IngestCounts") -> None:
         for f in fields(self):
@@ -122,10 +127,14 @@ class TrainSession:
     # what the collector took from train_open to the run's manifest
     # (observability/gcwatch.py; None where no watch is installed)
     gc: Any = None
-    # multi-source attribution (federation): every scheduler whose session
-    # committed into the pool this run trains on — stamped at close time,
-    # unioned when the drainer coalesces runs over the same pool
-    contributors: set = field(default_factory=set)
+    # multi-source attribution (federation): the newest upload of every
+    # scheduler whose session committed into the pool this run trains on, in
+    # commit order: name -> its `trainer.ingest` trace id (None where that
+    # trace is not sampled), stamped at close time (a later close into the
+    # same pool holds every earlier one). The registry rows' `contributors`
+    # and the run manifest's `ingest.schedulers` / `ingest.traces`, so each
+    # scheduler's upload trace leads to the model that holds its records
+    uploads: dict = field(default_factory=dict)
     # the pool this session committed into, as the commit left it (the run
     # manifest's `pool`): `epoch` rotations before it, `commits` merged into
     # it since the last rotation, its `hosts` and `edges`, the `hosts_added`
@@ -184,15 +193,19 @@ class TrainerService:
         # CPU one
         self.device = jaxenv.device_report()
         self._acc = datasetlib.DatasetAccumulator(max_pair_rows=self.cfg.pool_rows)
-        # schedulers that have committed into the CURRENT pool epoch —
-        # cleared on rotation with the pool it describes
-        self._pool_contributors: set[tuple[int, str]] = set()
+        # TrainSession.uploads of the CURRENT pool epoch — cleared on
+        # rotation with the pool it describes
+        self._pool_uploads: dict[str, str | None] = {}
         # uploads committed into the CURRENT pool epoch
         self._pool_commits = 0
         self._sessions: dict[str, TrainSession] = {}
         self._next = 0
         self._queue: collections.deque[TrainSession] = collections.deque()  # dflint: disable=DF034 depth is bounded by one pending close per scheduler (the drainer coalesces same-pool entries); a maxlen would silently DROP a committed training run from the far end
         self._drainer: asyncio.Task | None = None
+        # while the drainer is inside a run: the (start, end) of every ingest
+        # handler the loop ran, which the run's GNN call record marks its scan
+        # calls by; None between runs
+        self._ingest_in_run: list[tuple[float, float]] | None = None
         self.last_result: dict | None = None
         self.trains_started = 0
         self.trains_succeeded = 0
@@ -256,6 +269,7 @@ class TrainerService:
         counts.decode_s += t_decoded - t_start
         counts.fold_s += t_end - t_decoded
         counts.wait_s += t_start - sess.t_idle
+        self._count_in_run(counts, t_start, t_end, chunks=1)
         sess.t_idle = t_end
         sess.last_activity = time.time()
         return {"rows": counts.rows}
@@ -276,6 +290,7 @@ class TrainerService:
         counts.merge_s += t_end - t_start
         counts.wait_s += t_start - sess.t_idle
         counts.open_to_close_s += t_end - sess.t_open
+        self._count_in_run(counts, t_start, t_end, chunks=0)
         sess.span.finish()
         # never await the previous run here: queue the session and let the
         # drainer serialize training (one run at a time) off this RPC's back
@@ -284,8 +299,20 @@ class TrainerService:
             self._drainer = asyncio.ensure_future(self._drain())
         return {"queued": True, "queue_depth": len(self._queue)}
 
+    def _count_in_run(self, counts: IngestCounts, t_start: float, t_end: float, *, chunks: int) -> None:
+        """A handler that ran while the drainer is inside a run: its seconds
+        and chunks into the session's `in_run_s` / `chunks_in_run`, its
+        (start, end) into the run's record of the loop's ingest."""
+        if self._ingest_in_run is None:
+            return
+        self._ingest_in_run.append((t_start, t_end))
+        counts.in_run_s += t_end - t_start
+        counts.chunks_in_run += chunks
+
     def _commit(self, sess: TrainSession) -> None:
         named, hosts_before, commits = sess.acc.num_hosts, 0, 1
+        name = sess.scheduler_hostname or f"scheduler-{sess.scheduler_id}"
+        trace = sess.span.trace_id if sess.span.sampled else None
         if self.cfg.pool_rows > 0:
             hosts_before = self._acc.num_hosts
             # commit the session's aggregates into the shared pool — the
@@ -297,12 +324,13 @@ class TrainerService:
             sess.acc = self._acc
             # federation attribution: a model trained on the pool carries
             # every scheduler that fed THIS pool epoch, not just the closer
-            self._pool_contributors.add((sess.scheduler_id, sess.scheduler_hostname))
-            sess.contributors = set(self._pool_contributors)
+            self._pool_uploads.pop(name, None)
+            self._pool_uploads[name] = trace
+            sess.uploads = dict(self._pool_uploads)
             self._pool_commits += 1
             commits = self._pool_commits
         else:
-            sess.contributors = {(sess.scheduler_id, sess.scheduler_hostname)}
+            sess.uploads = {name: trace}
         pool, epoch = sess.acc, self.pool_rotations
         rotated = self._maybe_rotate_pool()  # `pool` stays the one this session committed into
         sess.pool = {
@@ -369,7 +397,7 @@ class TrainerService:
                 self._acc.num_hosts, self._acc.num_edges, self._acc.pair_rows,
             )
             self._acc = datasetlib.DatasetAccumulator(max_pair_rows=cfg.pool_rows)
-            self._pool_contributors = set()
+            self._pool_uploads = {}
             self._pool_commits = 0
             self.pool_rotations += 1
         return over_hosts or over_edges
@@ -410,7 +438,6 @@ class TrainerService:
             sess = self._queue.popleft()
             while self._queue and self._queue[0].acc is sess.acc:
                 nxt = self._queue.popleft()
-                nxt.contributors |= sess.contributors
                 # the run pays for every upload it coalesced; the collector's
                 # window runs from the first one's open
                 nxt.ingest.add(sess.ingest)
@@ -427,6 +454,7 @@ class TrainerService:
         # though the RPC returned long ago
         t_run = time.perf_counter()
         started_at = time.time()
+        self._ingest_in_run = []
         try:
             with default_tracer().span(
                 "trainer.train_run", parent=sess.trace_ctx,
@@ -438,6 +466,11 @@ class TrainerService:
                 if sp.sampled:
                     sp.set_attr("version", result.get("version", ""))
                     sp.set_attr("num_pairs", result.get("num_pairs", 0))
+                    # the other uploads this model holds, each the root of a
+                    # trace of its own that ended at its close
+                    others = [t for t in sess.uploads.values() if t and t != sess.span.trace_id]
+                    if others:
+                        sp.set_attr("upload_traces", ",".join(others))
                 if self.manager is not None:
                     with default_tracer().span("trainer.publish"):
                         await self._register_models(sess, result)
@@ -454,6 +487,8 @@ class TrainerService:
                 sess, {"version": f"run-{self.trains_started}", "error": error},
                 started_at, time.perf_counter() - t_run, status="error",
             )
+        finally:
+            self._ingest_in_run = None
 
     def _note_run(
         self,
@@ -506,7 +541,10 @@ class TrainerService:
                 "nodes": result.get("num_nodes", 0),
                 "build_seconds": result.get("build_seconds", 0.0),
             },
-            "ingest": sess.ingest.report(),
+            "ingest": {
+                **sess.ingest.report(),
+                "schedulers": list(sess.uploads), "traces": list(sess.uploads.values()),
+            },
             "pool": sess.pool,
             "gc": gc_watch().close(sess.gc),
             "models": models,
@@ -517,8 +555,11 @@ class TrainerService:
         t_build = time.perf_counter()
         # freeze() is a cheap loop-side snapshot; the O(nodes+edges+pairs)
         # materialization runs on a worker thread while chunks keep folding
+        # and other sessions' closes merge into the same pool: the run reads
+        # the snapshot alone, never the live pool
         with default_tracer().span("trainer.dataset_build"):
             frozen = acc.freeze()
+            probe_rows = acc.probe_rows
             ds = await asyncio.to_thread(frozen.finalize)
         build_seconds = time.perf_counter() - t_build
         # monotonic suffix: the drainer starts queued runs back-to-back, so
@@ -563,11 +604,12 @@ class TrainerService:
                 "evaluation": evaluation, "telemetry": mlp_tel.summary(),
             }
 
-        if ds.num_pairs >= self.cfg.min_pairs and acc.probe_rows >= self.cfg.min_probe_rows:
+        if ds.num_pairs >= self.cfg.min_pairs and probe_rows >= self.cfg.min_probe_rows:
             cfg = self.cfg.gnn
             gnn_tel = train_metrics.TrainRunTelemetry(
                 "gnn", batch_size=cfg.batch_size
             )
+            gnn_tel.loop_ingest = self._ingest_in_run
             t0 = time.perf_counter()
             with default_tracer().span("trainer.train_gnn", nodes=ds.num_nodes):
                 state, losses = await train_gnn.train_async(
@@ -633,9 +675,7 @@ class TrainerService:
         version out to all of them. The evaluation dict carries the
         contributing schedulers — the attribution proof the cross-scheduler
         cluster test pins."""
-        contributors = sorted(
-            name or f"scheduler-{sid}" for sid, name in sess.contributors
-        )
+        contributors = sorted(sess.uploads)
         for mtype in ("mlp", "gnn"):
             info = result.get(mtype)
             if not info:

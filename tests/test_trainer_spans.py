@@ -128,16 +128,19 @@ def test_the_manifest_counts_the_calls_and_times_the_export(trained):
     calls = manifest["models"]["gnn"]["calls"]
     assert set(calls) == {
         "count", "traced", "first_ms", "period_ms_p50", "period_ms_max", "turn_ms_p50", "turn_ms_max", "stall_ms",
-        "dispatch_ms_max", "pull_ms_max", "gc_ms"}
+        "dispatch_ms_max", "pull_ms_max", "gc_ms", "in_ingest", "gap_ms_in_ingest", "gap_ms_clear"}
     # from the second call on, each on one side of the enqueue: neither longer than the longest period
     assert 0 < calls["dispatch_ms_max"] < calls["period_ms_max"] and 0 < calls["pull_ms_max"] < calls["period_ms_max"]
     assert calls["count"] == GNN_STEPS // STEPS_PER_CALL
     # the run built its scan program: one trace, and a first call (trace, compile, steps) longer than any period
     assert calls["traced"] == 1 and calls["first_ms"] > calls["period_ms_max"]
     assert calls["stall_ms"] >= 0 and 0 < calls["turn_ms_p50"] <= calls["turn_ms_max"] < calls["period_ms_max"]
-    # the MLP loop's 20 steps are one scan call, which this run traced: nothing to pace
+    # the upload had closed before the run began: no call shared the loop with ingest
+    assert (calls["in_ingest"], calls["gap_ms_in_ingest"]) == ([], None) and calls["gap_ms_clear"] > 0
+    # the MLP loop's 20 steps are one scan call, which this run traced: nothing to pace, no loop to share
     mlp_calls = manifest["models"]["mlp"]["calls"]
-    assert set(mlp_calls) == set(calls) and (mlp_calls["count"], mlp_calls["traced"]) == (1, 1)
+    assert set(mlp_calls) == set(calls) - {"in_ingest", "gap_ms_in_ingest", "gap_ms_clear"}
+    assert (mlp_calls["count"], mlp_calls["traced"]) == (1, 1)
     assert mlp_calls["first_ms"] > 0 and mlp_calls["period_ms_p50"] is None and mlp_calls["stall_ms"] == 0
     assert mlp_calls["dispatch_ms_max"] is None and mlp_calls["pull_ms_max"] is None
     for model in ("mlp", "gnn"):
@@ -269,7 +272,11 @@ def test_the_manifest_counts_the_upload(trained):
     manifest, spans, chunks = trained
     ingest = manifest["ingest"]
     assert set(ingest) == {"sessions", "chunks", "bytes", "rows", "decode_s", "fold_s", "merge_s", "wait_s",
-                           "open_to_close_s"}
+                           "open_to_close_s", "in_run_s", "chunks_in_run", "schedulers", "traces"}
+    # no run was training while the upload came in; the pool holds this one upload, whose trace this is
+    assert (ingest["in_run_s"], ingest["chunks_in_run"]) == (0, 0)
+    (root,) = (s for s in spans if s["name"] == "trainer.ingest")
+    assert (ingest["schedulers"], ingest["traces"]) == (["s"], [root["trace_id"]])
     assert (ingest["sessions"], ingest["chunks"]) == (1, len(chunks))
     assert ingest["bytes"] == sum(len(data) for _, data in chunks)
     assert ingest["rows"] == sum(len(unpack_records(data)) for _, data in chunks)
