@@ -28,7 +28,7 @@ the entry points a user would call, at the widths the repo ships as default:
             again) must be served the kept one (`calls.traced` 0) and the
             third must return the first's losses bit for bit. And the kernel
             the training step runs, the gather's VJP (`sum_by_destination`),
-            against `jnp.take`'s at two shapes.
+            against `jnp.take`'s at three shapes.
   platform  the trainer process AND the device child must both report
             platform "tpu" with the same device kind and count, and Mosaic
             must have compiled the kernel at every shape, not the
@@ -363,15 +363,19 @@ def _child_device(tmp: str, stage_mib: int) -> dict:
 
 # (rows, width, hub): K = 16 and bfloat16, as the step's. The first is one
 # source block; the second two, with a row a quarter of all slots point at
-KERNEL_SHAPES = ((1024, 256, False), (4096, 512, True))
+# one; the third three of 5.3 K slices: blocks whose range of the cotangent
+# straddles a slice's edge. On several devices the shapes with a hub are each
+# a row shard's, so that a shard's table holds the blocks one device's does
+KERNEL_SHAPES = ((1024, 256, False), (4096, 512, True), (4608, 512, True))
 
 
 def _pallas_check(compiled: bool, n_dev: int = 1) -> dict:
     """`sum_by_destination`, the kernel the training step runs (the gather's
     VJP), over `edges_by_destination` of a seeded table, against `jnp.take`'s
-    own VJP in float32, at KERNEL_SHAPES; on several devices also the last
-    shape through `neighbor_gather` on the program's own mesh, a table per row
-    shard (`<shape>/<devices>`). On the chip Mosaic compiles the kernel;
+    own VJP in float32, at KERNEL_SHAPES; on several devices also each shape
+    with a hub as a row shard's, through `neighbor_gather` on the program's
+    own mesh, a table per row shard (`<rows>x16x<width>/<devices>`, the rows
+    of all shards). On the chip Mosaic compiles the kernel;
     anywhere else only an interpreter exists (Pallas's HLO interpreter, which
     any truthy value but the TPU interpreter's parameters selects: plain XLA
     ops, so it also runs under a mesh's `shard_map`)."""
@@ -416,15 +420,18 @@ def _pallas_check(compiled: bool, n_dev: int = 1) -> dict:
             "ok": err <= 2.0 ** -7, "compiled": compiled,
             "blocks": int(table.perm.shape[0]), "max_err": err,
         }
-    if n_dev > 1:  # the last shape again, rows over `data`: what a four-chip host's step runs
-        n, width, hub = KERNEL_SHAPES[-1]
+    for rows_a_shard, width, hub in KERNEL_SHAPES if n_dev > 1 else ():
+        if not hub:
+            continue
+        # rows over `data`: what a four-chip host's step runs
+        n = rows_a_shard * n_dev
         rng, nbr, g = seeded(n, width, hub)
         mesh, _ = meshlib.mesh_for_run()
         tables, reason = pk.gather_vjp_tables(nbr, width, g.dtype, mesh)
         key = f"{n}x16x{width}/{n_dev}"
         if tables is None:
             out[key] = {"ok": False, "compiled": compiled, "reason": reason}
-            return out
+            continue
         rows = meshlib.batch_sharding(mesh)
         h = jnp.asarray(rng.standard_normal((n, width)), jnp.bfloat16)
         states, slots, tables, cotangent = jax.device_put(

@@ -19,8 +19,10 @@ neighbors[n, k], because that is how the cotangent lies in memory: the TPU
 compiler keeps the message tensor [N, K, H] with K major-most (the mean over
 K is then a sum over whole [N, H] slabs), so a block, an equal contiguous
 range of those slots, is a bitcast of what the backward pass wrote (with K
-blocks, block k is g[:, k, :]). Blocks of the row-major order cost a layout
-copy of the whole cotangent a layer (PERF.md). The kernel never learns what
+blocks, block k is g[:, k, :]), and each block is staged into VMEM straight
+from there, one after another, and gathered into sorted order in VMEM
+(`_sorted_blocks`). Blocks of the row-major order cost a layout copy of the
+whole cotangent a layer (PERF.md). The kernel never learns what
 a slot number means. It sums, per tile of TILE_DST
 destination rows, each block's run of rows that point into the tile, in
 windows of WINDOW rows that start on a multiple of ALIGN (a DMA out of a
@@ -53,6 +55,7 @@ WINDOW = 384
 ALIGN = 16
 FIRST, LAST = 1, 2  # flags of a window: it opens / closes its tile
 SLOT_ORDER = "k_major"  # the run manifest's word for the table's format
+REORDER = "in_place"  # and for how the blocks reach the reorder: read where the backward wrote them
 PLATFORM = "tpu"  # the devices Mosaic compiles the kernel for; elsewhere it can only be interpreted
 
 
@@ -201,7 +204,7 @@ def gather_vjp_report(
     shards, blocks, per_block = perm.shape
     live = np.asarray(live)
     return {
-        "path": "sorted_kernel", "slot_order": SLOT_ORDER, "shards": shards, "blocks": blocks,
+        "path": "sorted_kernel", "slot_order": SLOT_ORDER, "reorder": REORDER, "shards": shards, "blocks": blocks,
         "block_bytes": per_block * width * jnp.dtype(dtype).itemsize,
         "live_windows": {"least": int(live.min()), "most": int(live.max())},
     }
@@ -297,25 +300,40 @@ def _segment_sum_kernel(tile_ref, block_ref, start_ref, flags_ref, local_ref, *r
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
-@partial(jax.jit, static_argnames="dst_rows")  # traced and lowered once for every layer of a step
-def sum_by_destination(by_dst: EdgesByDst, g: jnp.ndarray, dst_rows: int | None = None) -> jnp.ndarray:
-    """Cotangent [N, K, H] -> [N, H] (a row shard's [N/dp, K, H] -> the whole
-    [dst_rows, H], as its table was built): what a scatter-add by the neighbor
-    table gives, with float32 accumulation (`kernel_sums(H, g.dtype)`).
-    Gathers, the cheap direction, block by block (perm permutes a block's
-    slots; the blocks are ranges of the K-major rows, [K, N, H], which on the
-    TPU is the cotangent as it lies), then one grid step per live window, by
-    tile: a tile's output block stays in VMEM from its first window to its last."""
-    n, _, width = g.shape
-    blocks = jnp.swapaxes(g, 0, 1).reshape(by_dst.perm.shape[0], -1, width)
-    rows = [
-        part.at[perm].get(unique_indices=True, mode="promise_in_bounds")
-        for part, perm in zip(blocks, by_dst.perm)
-    ]
+def _sorted_blocks(by_dst: EdgesByDst, g: jnp.ndarray) -> list[jnp.ndarray]:
+    """The reorder: each block's rows of the cotangent [N, K, H] in its
+    sorted order (perm), a [N*K/B, H] array a block. A block is an equal range
+    of the K-major rows, [K, N, H], which on the TPU is the cotangent as the
+    backward wrote it, so its slice is a bitcast of a range of that buffer
+    (one K slice, half of one, or one and a half, as B and K fall). The
+    blocks are read one after the other (the barrier): the compiler then
+    stages each block's range into VMEM straight out of the cotangent and
+    gathers it there. Read together, the slices of all blocks become one
+    fusion that copies the whole [N, K, H] to HBM (VMEM holds few blocks of
+    BLOCK_BYTES) and the first block's gather reads its table out of HBM,
+    four times slower a row (PERF.md)."""
+    width = g.shape[-1]
+    blocks, per_block = by_dst.perm.shape
+    slots = jnp.swapaxes(g, 0, 1).reshape(blocks * per_block, width)
+    rows = []
+    for block in range(blocks):  # unrolled on purpose: B ops a layer, each block its own slice, in order
+        first = block * per_block
+        part = jax.lax.slice_in_dim(slots, first, first + per_block)  # dflint: disable=DF012 (above)
+        sorted_rows = part.at[by_dst.perm[block]].get(unique_indices=True, mode="promise_in_bounds")
+        slots, sorted_rows = jax.lax.optimization_barrier((slots, sorted_rows))  # dflint: disable=DF012 (above)
+        rows.append(sorted_rows)
+    return rows
+
+
+def _segment_sums(by_dst: EdgesByDst, rows: list[jnp.ndarray], dst_rows: int) -> jnp.ndarray:
+    """The kernel over the blocks' sorted rows: [dst_rows, H], one grid step
+    per live window, by tile: a tile's output block stays in VMEM from its
+    first window to its last."""
+    width, dtype = rows[0].shape[-1], rows[0].dtype
     tile, block, start, flags = by_dst.items
     return pl.pallas_call(
         _segment_sum_kernel,
-        out_shape=jax.ShapeDtypeStruct((n if dst_rows is None else dst_rows, width), g.dtype),
+        out_shape=jax.ShapeDtypeStruct((dst_rows, width), dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(by_dst.live[0],),
@@ -324,12 +342,22 @@ def sum_by_destination(by_dst: EdgesByDst, g: jnp.ndarray, dst_rows: int | None 
             out_specs=pl.BlockSpec((TILE_DST, width), lambda i, tile, *_: (tile[i], 0)),
             scratch_shapes=[
                 pltpu.VMEM((TILE_DST, width), jnp.float32),
-                pltpu.VMEM((2, WINDOW, width), g.dtype),
+                pltpu.VMEM((2, WINDOW, width), dtype),
                 pltpu.SemaphoreType.DMA((2,)),
             ],
         ),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
     )(tile, block, start, flags, by_dst.local, *rows)
+
+
+@partial(jax.jit, static_argnames="dst_rows")  # traced and lowered once for every layer of a step
+def sum_by_destination(by_dst: EdgesByDst, g: jnp.ndarray, dst_rows: int | None = None) -> jnp.ndarray:
+    """Cotangent [N, K, H] -> [N, H] (a row shard's [N/dp, K, H] -> the whole
+    [dst_rows, H], as its table was built): what a scatter-add by the neighbor
+    table gives, with float32 accumulation (`kernel_sums(H, g.dtype)`).
+    Gathers, the cheap direction, block by block where the backward wrote
+    the block (`_sorted_blocks`), then sums the runs (`_segment_sums`)."""
+    return _segment_sums(by_dst, _sorted_blocks(by_dst, g), g.shape[0] if dst_rows is None else dst_rows)
 
 
 def sum_by_shard(by_shard: EdgesByShard, g: jnp.ndarray) -> jnp.ndarray:

@@ -124,25 +124,37 @@ def kernel_leg():
         return chip_smoke._pallas_check(False, n_dev=4)
 
 
-@pytest.mark.parametrize("shape", [*chip_smoke.KERNEL_SHAPES, (*chip_smoke.KERNEL_SHAPES[-1], 4)],
-                         ids=["1024x256", "4096x512", "4096x512_over_4"])
-def test_kernel_leg_checks_the_steps_kernel_interpreted_on_cpu(kernel_leg, shape):
+def _kernel_leg_cases():
+    """A case for each of the smoke's shapes, and one more for each with a hub
+    as the row shard of each of four devices (the id names the shard's shape)."""
+    for rows, width, hub in chip_smoke.KERNEL_SHAPES:
+        yield pytest.param(rows, width, None, id=f"{rows}x{width}")
+        if hub:
+            yield pytest.param(rows, width, 4, id=f"{rows}x{width}_over_4")
+
+
+@pytest.mark.parametrize("rows, width, shards", _kernel_leg_cases())
+def test_kernel_leg_checks_the_steps_kernel_interpreted_on_cpu(kernel_leg, rows, width, shards):
     """The kernel the training step runs (`sum_by_destination` over a seeded
-    table), interpreted, is within bfloat16 of `jnp.take`'s own VJP at both of
-    the smoke's shapes; the second has a hub row and two source blocks, and
-    runs once more with its rows over four devices, a table a row shard. A
-    kernel summing rows into the wrong place fails it."""
-    n, width, hub, *shards = shape
-    assert len(kernel_leg) == len(chip_smoke.KERNEL_SHAPES) + 1
+    table), interpreted, is within bfloat16 of `jnp.take`'s own VJP at each of
+    the smoke's shapes; the second and third have a hub row, the third source
+    blocks of 5.3 K slices, and both run once more as the row shard of each
+    of four devices, a table a row shard holding the same blocks. A table
+    holds as many blocks as its cotangent rows fill BLOCK_BYTES. A kernel
+    summing rows into the wrong place fails it."""
+    from dragonfly2_tpu.ops import neighbor_agg_pallas as pk
+
+    assert len(kernel_leg) == len(list(_kernel_leg_cases()))
+    blocks = -(-rows * 16 * width * 2 // pk.BLOCK_BYTES)  # K = 16 bfloat16 rows a node, in blocks of at most 32 MB
     if shards:
-        result = kernel_leg[f"{n}x16x{width}/4"]
+        result = kernel_leg[f"{rows * shards}x16x{width}/{shards}"]
         assert result["ok"] and result["compiled"] is False and result["forward_exact"]
-        assert (result["shards"], result["blocks"]) == (4, 1) and 0 < result["max_err"] <= 2.0 ** -6
+        assert (result["shards"], result["blocks"]) == (shards, blocks) and 0 < result["max_err"] <= 2.0 ** -6
         assert result["live_windows"]["least"] <= result["live_windows"]["most"]
         return
-    result = kernel_leg[f"{n}x16x{width}"]
+    result = kernel_leg[f"{rows}x16x{width}"]
     assert result["ok"] and result["compiled"] is False
-    assert result["blocks"] == (2 if hub else 1) and 0 < result["max_err"] <= 2.0 ** -7
+    assert result["blocks"] == blocks and 0 < result["max_err"] <= 2.0 ** -7
 
 
 def test_compile_cache_honours_env_else_fixed_checkout_path(tmp_path, monkeypatch):

@@ -207,6 +207,7 @@ KERNEL_CASES = [
     (256, 24, 256, jnp.bfloat16, 2),
     (1024, 2, 128, jnp.bfloat16, 4),  # twice as many blocks as K: half a slice g[:, k, :] a block
     (512, 12, 128, jnp.bfloat16, 8),  # fewer blocks than K, and not whole slices: one and a half a block
+    (512, 12, 128, jnp.bfloat16, 12),  # as many blocks as K: a block is a slice, as in the 32k/512 and 64k/256 cells
 ]
 # where no table is built: the VJP is jnp.take's
 DERIVED_CASES = [
@@ -215,6 +216,18 @@ DERIVED_CASES = [
     (100, 7, 33, jnp.float32, 1),
     (512, 12, 128, jnp.float32, 4),   # the MXU would round float32 rows
 ]
+
+
+def _parents_reorder(table, g):
+    """The reorder as the parent commit composed it, kept as the oracle: the
+    K-major view of the cotangent cut into its blocks, all at once, and one
+    `.at[perm].get` a block."""
+    blocks = jnp.swapaxes(g, 0, 1).reshape(table.perm.shape[0], -1, g.shape[-1])
+    return [part.at[perm].get(unique_indices=True, mode="promise_in_bounds") for part, perm in zip(blocks, table.perm)]
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint16)
 
 
 def _take_vjp(nbr, g):
@@ -226,7 +239,9 @@ def test_gather_vjp_with_the_placed_table(monkeypatch):
     """Against `jax.vjp(jnp.take)` on a graph with a hub, padded slots at row
     0 and destinations nobody points at: the kernel's sum is as close to the
     float32 sum as a bfloat16 result can be; without a table it is the
-    derived VJP, bit for bit."""
+    derived VJP, bit for bit. Where the kernel runs, the blocks read one by
+    one where the cotangent lies give the rows the parent's all-at-once
+    slices gave, bit for bit, and so the sum it gave."""
     for case in KERNEL_CASES + DERIVED_CASES:
         n, k, width, dtype, blocks = case
         nbr = _hub_table(n, k, seed=2)
@@ -236,12 +251,20 @@ def test_gather_vjp_with_the_placed_table(monkeypatch):
         assert (table is not None) == (case in KERNEL_CASES), case
         with pltpu.force_tpu_interpret_mode():  # the kernel, where there is a table, on the CPU
             out, vjp = jax.vjp(lambda x: neighbor_gather(x, nbr, jax.tree.map(jnp.asarray, table)), h)
-            got = np.asarray(vjp(g)[0].astype(jnp.float32))
+            summed = vjp(g)[0]
+            if table is not None:
+                placed = jax.tree.map(jnp.asarray, table)
+                rows, parents = pk._sorted_blocks(placed, g), _parents_reorder(placed, g)
+                parents_sum = pk._segment_sums(placed, parents, n)
+        got = np.asarray(summed.astype(jnp.float32))
         assert out.shape == (n, k, width) and out.dtype == dtype, case
         if table is None:
             np.testing.assert_array_equal(got, np.asarray(_take_vjp(nbr, g), np.float32), err_msg=str(case))
             continue
-        assert table.perm.shape[0] == blocks, case
+        assert table.perm.shape[0] == blocks == len(rows), case
+        for block, (ours, theirs) in enumerate(zip(rows, parents, strict=True)):
+            np.testing.assert_array_equal(_bits(ours), _bits(theirs), err_msg=f"{case} block {block}")
+        np.testing.assert_array_equal(_bits(summed), _bits(parents_sum), err_msg=str(case))
         want = np.asarray(_take_vjp(nbr, g.astype(jnp.float32)))
         np.testing.assert_allclose(got, want, rtol=2.0 ** -8, atol=2.0 ** -8 * np.abs(want).max(), err_msg=str(case))
         assert not got[3 * n // 4:].any(), case  # nobody points there
@@ -279,7 +302,7 @@ def test_a_placed_step_with_the_table(monkeypatch):
     table = _table_in_blocks(monkeypatch, graph.neighbors, 128, jnp.bfloat16, 2)
     placement = train_gnn._placement(mesh, {"rule": "given"}, n, state, g._replace(by_dst=table), 64)
     assert placement["gather_vjp"] == {
-        "path": "sorted_kernel", "slot_order": "k_major", "shards": 1, "blocks": 2,
+        "path": "sorted_kernel", "slot_order": "k_major", "reorder": "in_place", "shards": 1, "blocks": 2,
         "block_bytes": n * k // 2 * 128 * 2, "live_windows": {"least": int(table.live[0]), "most": int(table.live[0])},
         **counts}
     assert placement["graph"]["leaves"] == 4
@@ -375,14 +398,25 @@ def test_one_device_takes_the_table_and_the_program_it_always_had(monkeypatch):
     assert "shard_map" not in ours and "all_gather" not in ours and "all_reduce" not in ours
 
 
+def _computations(text):
+    """{name: body} of every computation of a compiled module's text."""
+    return {m.group(1): m.group(2) for m in re.finditer(r"^(%\S+) \(.*?\) -> .*?\{\n(.*?)\n\}", text, re.M | re.S)}
+
+
 @pytest.mark.slow  # loads the TPU's compiler: alone in its process, never under tier-1's workers
 def test_no_layout_copy_of_the_cotangent_on_a_described_v5e():
     """`gnn-32k-512`'s placed step (32,768 x 16 x 512, the table one TPU chip
-    gets), compiled for a described v5e with no chip attached (30-45 s). The
-    compiler keeps the message tensor K-major, `bf16[N,16,H]{2,0,1}`; blocks of
-    the row-major slot order made it turn the cotangent with a `copy` of the
-    whole `[N, K, H]` a layer (`message/add_any`, 1.7 ms each on the chip).
-    With K-major blocks the entry computation has none."""
+    gets: 16 blocks, a K slice each) and `gnn-40k-512`'s (40,960 rows: 20
+    blocks of 0.8 slices), compiled for a described v5e with no chip attached
+    (30-45 s each). The compiler keeps the message tensor K-major,
+    `bf16[N,16,H]{2,0,1}`; blocks of the row-major slot order made it turn the
+    cotangent with a `copy` of the whole `[N, K, H]` a layer
+    (`message/add_any`, 1.7 ms each on the chip). With K-major blocks the
+    entry computation has none. And the reorder reads each block where the
+    backward wrote it: no fusion writes the blocks' slices together (the
+    parent's `slice_bitcast_fusion`, a second copy of `[N, K, H]` a layer, 1.45
+    ms on the chip), and every reorder gather finds its block in VMEM (the
+    parent's first gather of a layer read it out of HBM, 465 us for 109)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
@@ -392,20 +426,39 @@ def test_no_layout_copy_of_the_cotangent_on_a_described_v5e():
     except Exception as e:  # no libtpu here, or another process holds its lock
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     chip = SingleDeviceSharding(topo.devices[0])
-    n, k, hidden = 32768, 16, 512
+    k, hidden = 16, 512
     cfg = train_gnn.GNNTrainConfig(hidden=hidden, embed_dim=hidden // 2, batch_size=2048)
     state = train_gnn.init_state(cfg, _run_inputs(_table("uniform", 8, k), cfg)[0], 0)  # weights do not depend on N
-    graph, pairs = _run_inputs(_table("uniform", n, k), cfg)
-    graph = graph._replace(by_dst=pk.edges_by_destination(graph.neighbors, hidden, jnp.bfloat16))
-    assert graph.by_dst.perm.shape == (16, n)  # a block is a K slice, g[:, k, :]
-    shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.result_type(x), sharding=chip),
-                          (state, graph, pairs))
-    compiled = jax.jit(train_gnn.make_train_step()).lower(*shapes).compile()
-    text = compiled.as_text()
-    entry = text[text.index("ENTRY "):]
-    assert len(re.findall(r" custom-call\(.*tpu_custom_call", entry)) == 3, "a segmented sum a layer"
-    written = re.findall(rf"^\s*\S+ = bf16\[{n},{k},{hidden}\]\{{([\d,]+)\S* fusion\(", entry, re.M)
-    assert written and set(written) == {"2,0,1"}, set(written)  # K major-most wherever a fusion writes it
-    copies = re.findall(rf"^\s*(\S+ = bf16\[{n},{k},{hidden}\]\S* copy\(.*?op_name=\S+)", entry, re.M)
-    assert not copies, copies
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30  # 2.82 GB; 2.38 with the copies
+    # (rows, blocks, the compiler's temporaries at most: 2.31 / 2.88 GB, 2.82 / 3.41 at the parent)
+    for n, blocks, temp_cap in [(32768, 16, 2.6e9), (40960, 20, 3.2e9)]:
+        graph, pairs = _run_inputs(_table("uniform", n, k), cfg)
+        graph = graph._replace(by_dst=pk.edges_by_destination(graph.neighbors, hidden, jnp.bfloat16))
+        per_block = n * k // blocks
+        assert graph.by_dst.perm.shape == (blocks, per_block), n
+        shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.result_type(x), sharding=chip),
+                              (state, graph, pairs))
+        compiled = jax.jit(train_gnn.make_train_step()).lower(*shapes).compile()
+        text = compiled.as_text()
+        entry = text[text.index("ENTRY "):]
+        assert len(re.findall(r" custom-call\(.*tpu_custom_call", entry)) == 3, "a segmented sum a layer"
+        written = re.findall(rf"^\s*\S+ = bf16\[{n},{k},{hidden}\]\{{([\d,]+)\S* fusion\(", entry, re.M)
+        assert written and set(written) == {"2,0,1"}, set(written)  # K major-most wherever a fusion writes it
+        copies = re.findall(rf"^\s*(\S+ = bf16\[{n},{k},{hidden}\]\S* copy\(.*?op_name=\S+)", entry, re.M)
+        assert not copies, copies
+        block = rf"bf16\[{per_block},{hidden}\]"
+        together = re.findall(rf"^\s*(\S+) = \([^)]*{block}[^)]*{block}.*? fusion\(", entry, re.M)
+        assert not together, together
+        # nor a copy of the [N*K, H] view the reorder reads its blocks from (the forward's gathers write
+        # that shape too: only the backward's ops, `transpose(jvp(...))`, count)
+        viewed = [line.split(" = ")[0].strip() for line in entry.splitlines()
+                  if re.match(rf"\s*\S+ = \(?[^=]*bf16\[{n * k},{hidden}\][^=]* (?:copy|fusion)\(", line)
+                  and "transpose(jvp(" in line]
+        assert not viewed, viewed
+        defined =dict(re.findall(r"^\s*(?:ROOT )?(%\S+) = (\S+)", entry, re.M))
+        computations = _computations(text)
+        tables = [defined[operand] for operand, body in re.findall(
+            rf"= {block}\S* fusion\((%[^,)]+).*?, kind=kCustom, calls=(%[^,\s]+)", entry)
+            if " gather(" in computations[body]]
+        assert len(tables) == 3 * blocks, (n, len(tables))  # a reorder gather a block and layer
+        assert all(t.endswith("S(1)}") for t in tables), (n, tables)  # each out of VMEM
+        assert compiled.memory_analysis().temp_size_in_bytes < temp_cap, n

@@ -276,7 +276,8 @@ def test_the_per_shard_vjp_on_a_four_device_mesh_is_jnp_takes(monkeypatch):
     monkeypatch.setattr(pk, "PLATFORM", "cpu")  # the test's word: these CPU devices take the (interpreted) kernel
     mesh = meshlib.make_mesh(jax.devices()[:4], model_parallel=1)
     rows = meshlib.batch_sharding(mesh)
-    for n, k, width, blocks in [(1024, 12, 128, 1), (512, 16, 256, 2)]:
+    # a shard's [N/4, K, H] in blocks of 12 K slices, of 8, and of one and a half (straddling a slice's edge)
+    for n, k, width, blocks in [(1024, 12, 128, 1), (512, 16, 256, 2), (1024, 6, 128, 4)]:
         case = (n, k, width, blocks)
         monkeypatch.setattr(pk, "BLOCK_BYTES", n // 4 * k * width * 2 // blocks)
         nbr = _hub_table(n, k, seed=4)
