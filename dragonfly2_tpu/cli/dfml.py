@@ -236,7 +236,7 @@ async def _train(args: argparse.Namespace) -> int:
             # what the upload(s) this run trains on cost the server: decode,
             # the accumulator's fold, the close's merge, and waiting on the
             # wire; the part that ran while a run trained; whose uploads the
-            # pool holds
+            # pool holds; what the fold's and merge's key tables resolved
             print(
                 f"    ingest: sessions={ingest['sessions']} chunks={ingest['chunks']} "
                 f"MB={ingest['bytes'] / 1e6:.1f} rows={ingest['rows']} decode={ingest['decode_s']}s "
@@ -244,6 +244,8 @@ async def _train(args: argparse.Namespace) -> int:
                 f"open_to_close={ingest['open_to_close_s']}s"
                 + (f" in_run={ingest['in_run_s']}s ({ingest['chunks_in_run']} chunks)" if "in_run_s" in ingest else "")
                 + (f" schedulers={','.join(ingest['schedulers'])}" if ingest.get("schedulers") else "")
+                + (f" keys: looked_up={ingest['keys_looked_up']} admitted={ingest['keys_admitted']}"
+                   f" collisions={ingest['collisions']}" if "keys_looked_up" in ingest else "")
             )
         pool = r.get("pool")
         if pool:

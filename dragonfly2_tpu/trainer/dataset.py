@@ -18,19 +18,22 @@ Two construction paths share one vectorized core:
                                      they arrive, finalize() in O(nodes+edges
                                      +pairs) at train_close
 
-Both are columnar numpy end-to-end: host-id interning via np.unique over the
-structured-array id columns (first-occurrence order, matching the row-walk's
-insertion order), per-(src,dst) probe aggregation via bincount on packed
-64-bit edge keys, neighbor tables via one lexsort on (src, rtt, arrival) with
-a vectorized top-max_neighbors cut, and node features via bincount weights.
+Both are columnar numpy end-to-end: host ids hashed to 64-bit integers and,
+with the packed 64-bit (src,dst) edge keys, interned by one integer-keyed
+table (_Interner; first-occurrence order, matching the row-walk's insertion
+order, the ids themselves deciding identity), per-(src,dst) probe
+aggregation via bincount over the edge rows, neighbor tables via one lexsort
+on (src, rtt, arrival) with a vectorized top-max_neighbors cut, and node
+features via bincount weights.
 The superseded per-row walk survives as _build_dataset_rowloop — the
 reference implementation the equivalence tests and the bench A/B pin the
 vectorized path against (tests/test_dataset_ingest.py, bench.py
 dataset_build).
 
 Threading model: DatasetAccumulator folds run on the trainer's event loop
-(sub-ms per announcer chunk); freeze() takes a cheap consistent snapshot so
-finalize() can run on a worker thread while new chunks keep folding.
+(about 2.3 ms a 4,096-row announcer chunk at 32,768 hosts on a TPU v5e
+machine's host); freeze() takes a cheap consistent snapshot so finalize()
+can run on a worker thread while new chunks keep folding.
 """
 
 from __future__ import annotations
@@ -79,6 +82,19 @@ class _HostTable:
         return idx
 
 
+@dataclass
+class KeyCounts:
+    """What an accumulator's get-or-add tables resolved, summed over its
+    calls: `looked_up` the positions of a call whose key a table already
+    held, `admitted` a call's distinct new keys, given codes, `collisions`
+    a call's distinct host or parent ids that took the exact path because
+    their hash is another id's."""
+
+    looked_up: int = 0
+    admitted: int = 0
+    collisions: int = 0
+
+
 def _sorted_unique(ids: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     """(sorted unique values, per-element inverse) — np.sort + one searchsorted,
     ~2.5x cheaper than np.unique(return_index/return_inverse) on S-dtype ids
@@ -88,86 +104,248 @@ def _sorted_unique(ids: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return uniq, np.searchsorted(uniq, ids)
 
 
-def _first_occurrence_rank(inv: np.ndarray, n_uniq: int) -> np.ndarray:
-    """rank[u] = arrival order of unique u within the element sequence —
-    rank 0 for whichever unique appears first, matching a row-walk's
-    insertion order without the stable-argsort unique."""
-    first = np.full(n_uniq, len(inv), np.int64)
-    np.minimum.at(first, inv, np.arange(len(inv), dtype=np.int64))
-    rank = np.empty(n_uniq, np.int64)
-    rank[np.argsort(first, kind="stable")] = np.arange(n_uniq)
-    return rank
+def _unique_first(keys: np.ndarray) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """(sorted distinct keys, each one's first position, per-element inverse)
+    for a non-empty integer array — one unstable sort, the first positions
+    by a min-reduce over each run of equal keys."""
+    order = np.argsort(keys)
+    s = keys[order]
+    head = np.empty(len(s), bool)
+    head[0] = True
+    np.not_equal(s[1:], s[:-1], out=head[1:])
+    starts = np.flatnonzero(head)
+    inv = np.empty(len(keys), np.int64)
+    inv[order] = np.cumsum(head) - 1
+    return s[starts], np.minimum.reduceat(order, starts), inv
+
+
+def _in_arrival_order(first: np.ndarray, base: int) -> np.ndarray:
+    """Codes base, base + 1, ... for new keys, ranked by first position —
+    the numbering a row-by-row walk would give them."""
+    codes = np.empty(len(first), np.int64)
+    codes[np.argsort(first)] = np.arange(base, base + len(first))
+    return codes
+
+
+def _merge_runs(
+    a: tuple[np.ndarray, np.ndarray], b: tuple[np.ndarray, np.ndarray]
+) -> tuple[np.ndarray, np.ndarray]:
+    """One sorted run of two with no key in common: b's keys go in at their
+    search positions, a's fill the rest."""
+    (ak, ac), (bk, bc) = a, b
+    at = np.searchsorted(ak, bk) + np.arange(len(bk))
+    keys = np.empty(len(ak) + len(bk), ak.dtype)
+    codes = np.empty(len(keys), np.int64)
+    rest = np.ones(len(keys), bool)
+    rest[at] = False
+    keys[at], codes[at] = bk, bc
+    keys[rest], codes[rest] = ak, ac
+    return keys, codes
+
+
+_PHI = np.uint64(0x9E3779B97F4A7C15)
 
 
 class _Interner:
-    """Vectorized insertion-ordered id interning.
+    """A map from uint64 keys to int64 codes, with get-or-add (`codes`) that
+    numbers new keys 0, 1, 2, ... in order of first occurrence across all
+    calls — the numbering of a row-by-row walk through _HostTable.
 
-    codes() assigns contiguous indices by order of FIRST OCCURRENCE across
-    all calls — identical to walking the rows one by one through _HostTable.
-    A sorted (ids, codes) cache resolves already-known ids with one binary
-    search, so steady-state incremental folds never re-sort the id universe;
-    only ids new to a batch touch the dict.
+    Every key has a home slot, the top bits of key * φ, in a table kept at
+    most a quarter full; a slot holds the code of the first key placed
+    there. A key whose home holds another key is a stray, kept in a stack of
+    sorted (keys, codes) runs, each more than twice the size of the next. A
+    lookup is one probe of each key's home, and a search of the runs for the
+    keys whose home holds another key. So a call costs its own keys plus a
+    log of the strays for the runs' merges; the table doubles, every key
+    placed again, as it passes a quarter full, and no call rebuilds or
+    inserts into the whole table.
     """
 
-    __slots__ = ("index", "_sorted_ids", "_sorted_codes")
+    __slots__ = ("_bits", "_table", "_keys", "_strays", "n", "counts")
 
-    def __init__(self) -> None:
-        self.index: dict[bytes, int] = {}
-        self._sorted_ids: np.ndarray | None = None
-        self._sorted_codes: np.ndarray | None = None
+    def __init__(self, counts: KeyCounts):
+        self._bits = 10
+        self._table = np.full(1 << self._bits, -1, np.int32)
+        self._keys = _Grow(np.uint64)  # the key of each code
+        self._strays: list[tuple[np.ndarray, np.ndarray]] = []
+        self.n = 0  # keys held
+        self.counts = counts
 
     def __len__(self) -> int:
-        return len(self.index)
+        return self.n
 
-    def _probe(self, ids: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        """(known mask, codes — valid where known) against the sorted cache."""
-        table, codes = self._sorted_ids, self._sorted_codes
-        if table is None or not len(table):
-            return np.zeros(len(ids), bool), np.zeros(len(ids), np.int64)
-        pos = np.minimum(np.searchsorted(table, ids), len(table) - 1)
-        return table[pos] == ids, codes[pos]
+    def _home(self, keys: np.ndarray) -> np.ndarray:
+        return (keys * _PHI) >> np.uint64(64 - self._bits)
 
-    def _admit(self, new_ids: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        """Intern unseen ids (given in arrival order, duplicates allowed);
-        returns (their sorted uniques, per-element codes)."""
-        uniq, inv = _sorted_unique(new_ids)
-        rank = _first_occurrence_rank(inv, len(uniq))
-        base = len(self.index)
-        lut = base + rank
-        index = self.index
-        order = np.empty(len(uniq), np.int64)
-        order[rank] = np.arange(len(uniq))
-        for key in uniq[order].tolist():
-            index[key] = len(index)
-        if self._sorted_ids is None or not len(self._sorted_ids):
-            self._sorted_ids, self._sorted_codes = uniq, lut
-        else:
-            merged = np.concatenate([self._sorted_ids, uniq])
-            mcodes = np.concatenate([self._sorted_codes, lut])
-            o = np.argsort(merged, kind="stable")
-            self._sorted_ids, self._sorted_codes = merged[o], mcodes[o]
-        return uniq, lut[inv]
+    def find(self, keys: np.ndarray) -> np.ndarray:
+        """Code of each key (any order, repeats allowed), -1 where none."""
+        out = self._table[self._home(keys)].astype(np.int64)
+        other = np.flatnonzero(out >= 0)
+        other = other[self._keys.view()[out[other]] != keys[other]]
+        if len(other):
+            out[other] = self._find_strays(keys[other])
+        return out
 
-    # Unknown ids are admitted a segment at a time: sorting S-ids is the
-    # dominant cost, and after one segment most later "unknowns" are really
-    # repeats — a binary-search probe against the refreshed cache is ~3x
-    # cheaper than sorting them (one-shot 100k-row builds hit the same
-    # amortization the chunked fold path gets for free).
-    _ADMIT_SEGMENT = 32768
+    def _find_strays(self, queries: np.ndarray) -> np.ndarray:
+        out = np.full(len(queries), -1, np.int64)
+        pending = np.arange(len(queries))
+        for keys, codes in self._strays:
+            q = queries[pending]
+            pos = np.searchsorted(keys, q)
+            pos[pos == len(keys)] = 0
+            hit = keys[pos] == q
+            out[pending[hit]] = codes[pos[hit]]
+            pending = pending[~hit]
+            if not len(pending):
+                break
+        return out
+
+    def add(self, keys: np.ndarray, codes: np.ndarray) -> None:
+        """Hold distinct keys that have no code, under distinct new codes."""
+        self._keys.ensure(int(codes.max()) + 1)
+        self._keys.view()[codes] = keys
+        self.n += len(keys)
+        if 4 * self.n > len(self._table):
+            held = self._table[self._table >= 0]
+            codes = np.concatenate([held, *(c for _, c in self._strays), codes])
+            keys = self._keys.view()[codes]
+            while 4 * self.n > 1 << self._bits:
+                self._bits += 1
+            self._table = np.full(1 << self._bits, -1, np.int32)
+            self._strays = []
+        self._place(keys, codes)
+
+    def _place(self, keys: np.ndarray, codes: np.ndarray) -> None:
+        home = self._home(keys)
+        free = self._table[home] < 0
+        self._table[home[free]] = codes[free]  # keys that share a free home: one write holds it
+        stray = np.flatnonzero(self._table[home] != codes)
+        if not len(stray):
+            return
+        stray = stray[np.argsort(keys[stray])]
+        runs = self._strays
+        runs.append((keys[stray], codes[stray]))
+        while len(runs) > 1 and len(runs[-2][0]) <= 2 * len(runs[-1][0]):
+            b = runs.pop()
+            runs.append(_merge_runs(runs.pop(), b))
+
+    def codes(self, keys: np.ndarray) -> np.ndarray:
+        """Get-or-add: int64 code per element, first-occurrence ordered."""
+        if not len(keys):
+            return np.zeros(0, np.int64)
+        out = self.find(keys)
+        miss = np.flatnonzero(out < 0)
+        self.counts.looked_up += len(keys) - len(miss)
+        if len(miss):
+            uq, first, inv = _unique_first(keys[miss])
+            new = _in_arrival_order(first, self.n)
+            self.add(uq, new)
+            out[miss] = new[inv]
+            self.counts.admitted += len(uq)
+        return out
+
+
+def _words(ids: np.ndarray) -> np.ndarray:
+    """S64 ids as [n, 8] uint64 rows (a view where they already are S64)."""
+    return np.ascontiguousarray(ids, "S64").view("<u8").reshape(-1, 8)
+
+
+def _hash_ids(words: np.ndarray) -> np.ndarray:
+    """64-bit hash of each id from its eight 8-byte words. Each step is a
+    bijection of the running value, so ids that differ in one word never
+    share a hash; identity is decided by the id itself (_IdInterner)."""
+    h = words[:, 0] * _PHI
+    for i in range(1, 8):
+        h ^= words[:, i]
+        h *= _PHI
+    return h ^ (h >> np.uint64(29))
+
+
+def _same_rows(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    """Per row of two [n, 8] word arrays: equal in every word (the row's
+    eight compare flags read as one uint64)."""
+    return (a != b).view(np.uint64)[:, 0] == 0
+
+
+class _IdInterner:
+    """Get-or-add from S64 ids (host ids, as the records carry them) to
+    contiguous codes in order of first occurrence, through an _Interner over
+    the ids' hashes.
+
+    A hash never decides identity. A position whose hash is known is
+    compared with the stored id of that hash, one whose hash is new with the
+    batch's first id of that hash: two vectorized compares of the ids'
+    words. A position that differs (a hash that is another id's, in the
+    table or earlier in the batch) takes the exact path: a dict over the ids
+    whose hash is another's, with the codes the walk would give them. The
+    ids are kept in code order.
+    """
+
+    __slots__ = ("_hashes", "_ids", "_spill", "counts")
+
+    def __init__(self, counts: KeyCounts):
+        self._hashes = _Interner(counts)  # hash -> the code of the id it is
+        self._ids = _Grow(np.uint64, cols=8)
+        self._spill: dict[bytes, int] = {}
+        self.counts = counts
+
+    def __len__(self) -> int:
+        return self._ids.n
+
+    @property
+    def ids(self) -> np.ndarray:
+        """Every id (S64), at its code (a view: copy to keep)."""
+        return self._ids.view().view("S64")[:, 0]
+
+    def find(self, ids: np.ndarray) -> np.ndarray:
+        """Code of each id, -1 where it has none; admits nothing."""
+        return self._get(_words(ids), admit=False)
 
     def codes(self, ids: np.ndarray) -> np.ndarray:
         """Get-or-add: int64 code per element, first-occurrence ordered."""
-        if len(ids) == 0:
-            return np.zeros(0, np.int64)
-        known, out = self._probe(ids)
-        pending = np.flatnonzero(~known)
-        while len(pending):
-            seg, pending = pending[: self._ADMIT_SEGMENT], pending[self._ADMIT_SEGMENT :]
-            _, out[seg] = self._admit(ids[seg])
-            if len(pending):
-                k2, o2 = self._probe(ids[pending])
-                out[pending[k2]] = o2[k2]
-                pending = pending[~k2]
+        return self._get(_words(ids), admit=True)
+
+    def _get(self, words: np.ndarray, *, admit: bool) -> np.ndarray:
+        h = _hash_ids(words)
+        out = self._hashes.find(h)
+        known = np.flatnonzero(out >= 0)
+        doubt = known[~_same_rows(words.take(known, 0), self._ids.view().take(out[known], 0))]
+        miss = np.flatnonzero(out < 0)
+        uh, first = np.zeros(0, np.uint64), miss[:0]
+        if len(miss):
+            uh, first, inv = _unique_first(h[miss])
+            first = miss[first]
+            same = _same_rows(words.take(miss, 0), words.take(first[inv], 0))
+            doubt = np.sort(np.concatenate([doubt, miss[~same]]))
+            fresh, group = miss[same], inv[same]
+        exact: list[bytes] = []
+        if len(doubt):
+            ex, at, which = np.unique(words.view("S64")[doubt, 0], return_index=True, return_inverse=True)
+            exact, at = ex.tolist(), doubt[at]
+            ecodes = np.array([self._spill.get(k, -1) for k in exact], np.int64)
+            out[doubt] = ecodes[which]
+        if not admit:
+            return out
+        base = len(self)
+        self.counts.looked_up += int((out >= 0).sum())
+        self.counts.collisions += len(exact)
+        if not len(uh) and not len(exact):
+            return out
+        enew = np.flatnonzero(ecodes < 0) if len(exact) else np.zeros(0, np.int64)
+        firsts = np.concatenate([first, at[enew]]) if len(exact) else first
+        new = _in_arrival_order(firsts, base)
+        if len(uh):
+            self._hashes.add(uh, new[: len(uh)])
+            out[fresh] = new[group]
+        if len(enew):
+            ecodes[enew] = new[len(uh):]
+            for k, c in zip([exact[i] for i in enew.tolist()], new[len(uh):].tolist()):
+                self._spill[k] = c
+            out[doubt] = ecodes[which]
+        self._ids.ensure(base + len(new))
+        self._ids.view()[new] = words.take(firsts, 0)
+        self.counts.admitted += len(new)
         return out
 
 
@@ -208,30 +386,30 @@ class FrozenIngest:
 
     def __init__(
         self,
-        host_index: dict[bytes, int],
+        host_ids: np.ndarray,
         edge_src: np.ndarray,
         edge_dst: np.ndarray,
         edge_sum: np.ndarray,
         edge_cnt: np.ndarray,
-        stat_ids: list[bytes],
+        stat_rows: np.ndarray,
         stat_tot: np.ndarray,
         stat_succ: np.ndarray,
         stat_bw: np.ndarray,
         pair_chunks: tuple[tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray], ...],
     ):
-        self.host_index = host_index
+        self._host_ids = host_ids
         self._edge_src = edge_src
         self._edge_dst = edge_dst
         self._edge_sum = edge_sum
         self._edge_cnt = edge_cnt
-        self._stat_ids = stat_ids
+        self._stat_rows = stat_rows
         self._stat_tot = stat_tot
         self._stat_succ = stat_succ
         self._stat_bw = stat_bw
         self._pair_chunks = pair_chunks
 
     def finalize(self, *, max_neighbors: int = 16, min_nodes: int = 8) -> Dataset:
-        n = max(len(self.host_index), min_nodes)
+        n = max(len(self._host_ids), min_nodes)
         k = max_neighbors
         neighbors = np.zeros((n, k), np.int32)
         mask = np.zeros((n, k), np.float32)
@@ -263,13 +441,8 @@ class FrozenIngest:
 
         # --- node features aggregated from download history ---
         node_feats = np.zeros((n, NODE_FEATURE_DIM), np.float32)
-        if self._stat_ids:
-            index = self.host_index
-            main = np.fromiter(
-                (index.get(h, -1) for h in self._stat_ids),
-                np.int64,
-                count=len(self._stat_ids),
-            )
+        if len(self._stat_rows):
+            main = self._stat_rows
             present = main >= 0  # parents only ever seen in failed rows w/o probes drop out
             rows = main[present]
             total_cnt = np.zeros(n)
@@ -307,10 +480,8 @@ class FrozenIngest:
 
         sketch = FeatureSketch(FEATURE_DIM, names=FEATURE_NAMES)
         sketch.update(pairs.feats)
-        return Dataset(
-            graph=graph, pairs=pairs, host_index=dict(self.host_index),
-            feature_sketch=sketch,
-        )
+        host_index = dict(zip(self._host_ids.tolist(), range(len(self._host_ids))))
+        return Dataset(graph=graph, pairs=pairs, host_index=host_index, feature_sketch=sketch)
 
 
 class DatasetAccumulator:
@@ -328,7 +499,8 @@ class DatasetAccumulator:
                           pool the per-upload row arrays used to provide, at
                           ~76 B/pair instead of ~376 B/raw row)
       - edge stats        per-(src,dst) float64 stat sums + probe-row counts,
-                          keyed by packed 64-bit (src<<32|dst)
+                          rows keyed by packed 64-bit (src<<32|dst), in
+                          first-occurrence order
       - node counters     per-parent-id totals/successes/bandwidth sums, in a
                           side table so a parent first seen in a failed row
                           still counts once (and only once) it enters the host
@@ -338,19 +510,22 @@ class DatasetAccumulator:
     Fold order defines node numbering: per upload the announcer streams all
     download chunks then all probe chunks, which reproduces build_dataset's
     interning order exactly (the chunked≡one-shot equivalence tests pin this).
+    The three get-or-add tables (hosts, edges, parent ids) are _Interner
+    tables over 64-bit keys; `keys` counts what they resolved.
     """
 
     def __init__(self, *, max_pair_rows: int = 0):
-        self.hosts = _Interner()
+        self.keys = KeyCounts()
+        self.hosts = _IdInterner(self.keys)
         self.max_pair_rows = max_pair_rows
         self._pair_chunks: list[tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]] = []
         self.pair_rows = 0
-        self._edge_pos: dict[int, int] = {}
+        self._edges = _Interner(self.keys)
         self._edge_src = _Grow(np.int64)
         self._edge_dst = _Grow(np.int64)
         self._edge_sum = _Grow(np.float64, cols=EDGE_FEATURE_DIM)
         self._edge_cnt = _Grow(np.int64)
-        self._stats = _Interner()
+        self._stats = _IdInterner(self.keys)
         self._stat_tot = _Grow(np.float64)
         self._stat_succ = _Grow(np.float64)
         self._stat_bw = _Grow(np.float64)
@@ -414,32 +589,20 @@ class DatasetAccumulator:
                 )
         return rows
 
-    def _edge_rows(self, keys: np.ndarray) -> np.ndarray:
-        """Get-or-add edge-table rows for packed (src<<32|dst) keys given in
+    def _edge_rows(self, s: np.ndarray, d: np.ndarray) -> np.ndarray:
+        """Get-or-add edge-table rows for (src, dst) node rows given in
         arrival order (duplicates allowed); new edges are appended in
-        first-occurrence order. Returns the edge row per key position."""
-        uniq, inv = _sorted_unique(keys)
-        rank = _first_occurrence_rank(inv, len(uniq))
-        order = np.empty(len(uniq), np.int64)
-        order[rank] = np.arange(len(uniq))
-        edge_pos = self._edge_pos
-        base = self._edge_src.n
-        rows_for = np.empty(len(uniq), np.int64)
-        new_keys: list[int] = []
-        for pos, key in zip(order.tolist(), uniq[order].tolist()):
-            r = edge_pos.get(key)
-            if r is None:
-                r = edge_pos[key] = base + len(new_keys)
-                new_keys.append(key)
-            rows_for[pos] = r
-        if new_keys:
-            nk = np.asarray(new_keys, np.int64)
-            total = base + len(new_keys)
+        first-occurrence order. Returns the edge row per position."""
+        base = len(self._edges)
+        rows = self._edges.codes(((s << 32) | d).view(np.uint64))
+        total = len(self._edges)
+        if total > base:
             for g in (self._edge_src, self._edge_dst, self._edge_sum, self._edge_cnt):
                 g.ensure(total)
-            self._edge_src.view()[base:] = nk >> 32
-            self._edge_dst.view()[base:] = nk & 0xFFFFFFFF
-        return rows_for[inv]
+            fresh = rows >= base
+            self._edge_src.view()[rows[fresh]] = s[fresh]
+            self._edge_dst.view()[rows[fresh]] = d[fresh]
+        return rows
 
     def add_probes(self, arr: np.ndarray) -> int:
         """Fold one PROBE_DTYPE chunk; returns rows folded."""
@@ -450,8 +613,7 @@ class DatasetAccumulator:
 
         ids = _interleave(arr["src_host_id"], arr["dst_host_id"])
         codes = self.hosts.codes(ids)
-        s, d = codes[0::2], codes[1::2]
-        erows = self._edge_rows((s << 32) | d)
+        erows = self._edge_rows(codes[0::2], codes[1::2])
         uniq_rows, inv = _sorted_unique(erows)
 
         stats = np.empty((rows, EDGE_FEATURE_DIM), np.float64)
@@ -478,12 +640,9 @@ class DatasetAccumulator:
         directly."""
         if other.download_rows == 0 and other.probe_rows == 0:
             return
-        # hosts: other's code i sits at position i of its insertion-ordered
-        # key list; get-or-add yields the remap other-code -> self-code
-        remap = np.zeros(0, np.int64)
-        if len(other.hosts):
-            ids = np.array(list(other.hosts.index), dtype="S64")
-            remap = self.hosts.codes(ids)
+        # hosts: other's code i holds the id at position i of its id array;
+        # get-or-add yields the remap other-code -> self-code
+        remap = self.hosts.codes(other.hosts.ids)
 
         for child, parent, feats, labels in other._pair_chunks:
             self._pair_chunks.append(
@@ -501,13 +660,12 @@ class DatasetAccumulator:
         if m:
             s = remap[other._edge_src.view()]
             d = remap[other._edge_dst.view()]
-            erows = self._edge_rows((s << 32) | d)  # other's edges are unique keys
+            erows = self._edge_rows(s, d)  # other's edges are unique keys
             self._edge_sum.view()[erows] += other._edge_sum.view()
             self._edge_cnt.view()[erows] += other._edge_cnt.view()
 
         if len(other._stats):
-            sids = np.array(list(other._stats.index), dtype="S64")
-            scodes = self._stats.codes(sids)
+            scodes = self._stats.codes(other._stats.ids)
             nstat = len(self._stats)
             for g in (self._stat_tot, self._stat_succ, self._stat_bw):
                 g.ensure(nstat)
@@ -535,12 +693,12 @@ class DatasetAccumulator:
         list, not the tuple). Merges and folds that land after it, on the
         loop while finalize() runs on a worker thread, leave it as it was."""
         return FrozenIngest(
-            host_index=dict(self.hosts.index),
+            host_ids=self.hosts.ids.copy(),
             edge_src=self._edge_src.view().copy(),
             edge_dst=self._edge_dst.view().copy(),
             edge_sum=self._edge_sum.view().copy(),
             edge_cnt=self._edge_cnt.view().copy(),
-            stat_ids=list(self._stats.index),
+            stat_rows=self.hosts.find(self._stats.ids),
             stat_tot=self._stat_tot.view().copy(),
             stat_succ=self._stat_succ.view().copy(),
             stat_bw=self._stat_bw.view().copy(),

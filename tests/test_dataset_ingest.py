@@ -5,6 +5,7 @@ snapshot cut, and the event-loop heartbeat during a real GNN train."""
 from __future__ import annotations
 
 import asyncio
+import hashlib
 import time
 
 import numpy as np
@@ -206,6 +207,147 @@ def test_accumulator_freeze_isolated_from_later_folds():
     acc.add_downloads(d2)
     acc.add_probes(p2)
     assert_dataset_equal(frozen.finalize(), want, exact=True)
+
+
+# ---------------------------------------------------------------------------
+# host ids and edge keys interned as integers: every id the records can carry
+
+
+def _xor_hash(words):
+    """A degenerate hash for the tests: four values, so distinct ids share
+    one in every batch and the exact path runs for hits and for new ids."""
+    return np.bitwise_xor.reduce(words, axis=1) & np.uint64(3)
+
+
+def _renamed(arrays, names):
+    """The records with host-i's id replaced by names(i); b"" stays b""."""
+    out = []
+    for arr in arrays:
+        arr = arr.copy()
+        for col in ("child_host_id", "parent_host_id", "src_host_id", "dst_host_id"):
+            if col in arr.dtype.names:
+                old = arr[col]
+                named = old != b""
+                arr[col][named] = [names(int(h[5:])) for h in old[named].tolist()]
+        out.append(arr)
+    return out
+
+
+ID_SETS = {
+    # upstream's host ids: SHA-256 hex digests, all 64 characters
+    "hex64": dict(names=lambda i: hashlib.sha256(b"%d" % i).hexdigest().encode()),
+    # ids that differ only in their last byte
+    "last_byte": dict(names=lambda i: b"h" * 63 + bytes([i + 1])),
+    # short ids that S64 pads with NULs beside full-width ones
+    "short_and_full": dict(names=lambda i: b"host-%06d" % i if i % 2 else (b"%04d" % i) * 16),
+    # half the downloads back-to-source: b"" parents
+    "empty_parents": dict(names=lambda i: b"host-%06d" % i, frac_no_parent=0.5),
+    # every batch's ids share four hashes: the exact path for hits, for
+    # new ids within one batch, and in merge_from
+    "forced_collisions": dict(names=lambda i: hashlib.sha256(b"%d" % i).hexdigest().encode(), hash=_xor_hash),
+    # a second upload of hosts the pool has never seen, merged into it
+    "merge_new_hosts": dict(names=lambda i: b"host-%06d" % i, second=200),
+}
+
+
+@pytest.mark.parametrize("id_set", sorted(ID_SETS))
+def test_interning_over_id_sets(id_set, monkeypatch):
+    case = ID_SETS[id_set]
+    if "hash" in case:
+        monkeypatch.setattr(datasetlib, "_hash_ids", case["hash"])
+    frac = case.get("frac_no_parent", 0.1)
+    d, p = _renamed(synth_telemetry(900, 400, 60, seed=30, frac_no_parent=frac), case["names"])
+    offset = case.get("second", 30)
+    d2, p2 = _renamed(synth_telemetry(500, 250, 60, seed=31, frac_no_parent=frac), lambda i: case["names"](i + offset))
+
+    # one upload: chunked fold == one-shot == the row-by-row walk
+    want = datasetlib._build_dataset_rowloop(d, p)
+    assert_dataset_equal(datasetlib.build_dataset(d, p), want)
+    sessions = []
+    for dd, pp in ((d, p), (d2, p2)):
+        acc = datasetlib.DatasetAccumulator()
+        for s in range(0, len(dd), 37):
+            acc.add_downloads(dd[s : s + 37])
+        for s in range(0, len(pp), 37):
+            acc.add_probes(pp[s : s + 37])
+        sessions.append(acc)
+    assert_dataset_equal(sessions[0].finalize(), want)
+    assert_dataset_equal(sessions[1].finalize(), datasetlib._build_dataset_rowloop(d2, p2))
+
+    # both committed into a pool == both folded into one accumulator
+    pool = datasetlib.DatasetAccumulator()
+    pool.merge_from(sessions[0])
+    merged_before = pool.keys.collisions
+    pool.merge_from(sessions[1])
+    direct = datasetlib.DatasetAccumulator()
+    for dd, pp in ((d, p), (d2, p2)):
+        direct.add_downloads(dd)
+        direct.add_probes(pp)
+    assert_dataset_equal(pool.finalize(), direct.finalize())
+    assert pool.num_hosts == direct.num_hosts == len(direct.finalize().host_index)
+    if id_set == "merge_new_hosts":
+        assert pool.num_hosts == sessions[0].num_hosts + sessions[1].num_hosts
+    collided = [sessions[0].keys.collisions, pool.keys.collisions - merged_before]
+    if "hash" in case:
+        assert min(collided) > 0
+    else:
+        assert collided == [0, 0]
+
+
+def _known_upload():
+    """Three downloads and three probes over hosts a, b, c, d, e."""
+    d = np.zeros(4, DOWNLOAD_DTYPE)
+    d["child_host_id"] = [b"a", b"a", b"d", b"e"]
+    d["parent_host_id"] = [b"b", b"c", b"b", b""]
+    d["success"] = [True, True, False, True]
+    p = np.zeros(3, PROBE_DTYPE)
+    p["src_host_id"] = [b"a", b"b", b"a"]
+    p["dst_host_id"] = [b"b", b"a", b"b"]
+    return d, p
+
+
+def test_key_counts_of_a_known_upload():
+    """Per call, `looked_up` counts the positions whose key a table already
+    held and `admitted` the distinct new keys: hosts [a, b, a, c] admit 3,
+    parents [b, c, b] admit 2, probes [a, b, b, a, a, b] look up 6, edges
+    [ab, ba, ab] admit 2. A commit into an empty pool admits every key of
+    the session once; a second commit of the same upload looks them up."""
+    d, p = _known_upload()
+    acc = datasetlib.DatasetAccumulator()
+    acc.add_downloads(d)
+    acc.add_probes(p)
+    assert acc.keys == datasetlib.KeyCounts(looked_up=6, admitted=7, collisions=0)
+    pool = datasetlib.DatasetAccumulator()
+    pool.merge_from(acc)
+    assert pool.keys == datasetlib.KeyCounts(looked_up=0, admitted=7, collisions=0)
+    pool.merge_from(acc)
+    assert pool.keys == datasetlib.KeyCounts(looked_up=7, admitted=7, collisions=0)
+
+
+def test_the_manifest_carries_the_key_counts(run, tmp_path, monkeypatch):
+    async def upload(svc):
+        d, p = _known_upload()
+        token = (await svc.train_open({"hostname": "s"}))["token"]
+        await svc.train_chunk({"token": token, "kind": "downloads", "data": pack_records(d)})
+        await svc.train_chunk({"token": token, "kind": "probes", "data": pack_records(p)})
+        await svc.train_close({"token": token})
+        await svc.wait_idle()
+
+    async def body(svc):
+        await upload(svc)
+        await upload(svc)  # the same records again: the pool holds every key
+        return [m["ingest"] for m in svc.run_history]
+
+    svc = TrainerService(TrainerConfig(model_dir=str(tmp_path / "a"), min_pairs=10**9))
+    first, second = run(body(svc))
+    # the session's folds, then its commit: into the empty pool, then into one that holds it
+    assert (first["keys_looked_up"], first["keys_admitted"], first["collisions"]) == (6, 14, 0)
+    assert (second["keys_looked_up"], second["keys_admitted"], second["collisions"]) == (13, 7, 0)
+    # one hash for every id: all but the first id of each table take the exact path
+    monkeypatch.setattr(datasetlib, "_hash_ids", lambda words: np.zeros(len(words), np.uint64))
+    svc = TrainerService(TrainerConfig(model_dir=str(tmp_path / "b"), min_pairs=10**9))
+    forced = run(body(svc))
+    assert [m["keys_admitted"] for m in forced] == [14, 7] and min(m["collisions"] for m in forced) > 0
 
 
 # ---------------------------------------------------------------------------

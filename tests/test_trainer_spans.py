@@ -272,7 +272,8 @@ def test_the_manifest_counts_the_upload(trained):
     manifest, spans, chunks = trained
     ingest = manifest["ingest"]
     assert set(ingest) == {"sessions", "chunks", "bytes", "rows", "decode_s", "fold_s", "merge_s", "wait_s",
-                           "open_to_close_s", "in_run_s", "chunks_in_run", "schedulers", "traces"}
+                           "open_to_close_s", "in_run_s", "chunks_in_run", "schedulers", "traces",
+                           "keys_looked_up", "keys_admitted", "collisions"}
     # no run was training while the upload came in; the pool holds this one upload, whose trace this is
     assert (ingest["in_run_s"], ingest["chunks_in_run"]) == (0, 0)
     (root,) = (s for s in spans if s["name"] == "trainer.ingest")
