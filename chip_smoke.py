@@ -10,9 +10,10 @@ the entry points a user would call, at the widths the repo ships as default:
             (hidden 256, embed 128, 3 layers, batch 4096) on a 1,024-host
             K=16 graph. Only the step counts are cut. Checked: run status ok,
             both models in last_result, every loss finite, no swallowed
-            training or native-export error, and on several devices the Dense
-            kernels split over them, not copied. The child is stopped by PID
-            and waited for before anything else opens the chip.
+            training or native-export error, and a run manifest that says
+            where the run was placed (graph rows and the pair batch over
+            `data`, the state replicated). The child is stopped by PID and
+            waited for before anything else opens the chip.
   artifacts params.msgpack + graph.npz + scorer.dfsc on disk.
   scorer    (child, pinned to the host CPU) the produced scorer.dfsc scores
             one 40-candidate round through NativeScorer in agreement with
@@ -185,14 +186,7 @@ def _check_trainer(out: dict) -> dict:
         if info["native_export_error"]:
             problems.append(f"native export failed: {info['native_export_error']}")
     placement = (run["models"].get("gnn") or {}).get("placement")
-    if placement:
-        # Dense kernels split over "model": each device of the mesh holds
-        # bytes / model_parallel — four copies or one device would not
-        kernels, mp = placement["kernels"], placement["mesh"]["model"]
-        per_dev = kernels["per_device_bytes"]
-        if len(per_dev) != device["device_count"] or any(b * mp != kernels["bytes"] for b in per_dev):
-            problems.append(f"kernels not split {mp} ways over the devices: {kernels}")
-    else:
+    if not placement:
         problems.append("run manifest carries no mesh/placement")
     return {
         "ok": not problems, "problems": problems, "device": device,
@@ -488,7 +482,7 @@ def _served_scan_runs(n_dev: int, on_tpu: bool) -> dict:
         all(np.isfinite(run_losses).all() for run_losses in losses)
         and p["decision"] == {**decided, "hosts": 1024, "rows": 1024, "pad_pct": 0.0}
         and off_grid["decision"] == {**decided, "hosts": 1000, "rows": 1024, "pad_pct": 2.4}
-        and p["mesh"] == {"data": n_dev, "model": 1}
+        and p["mesh"] == {"data": n_dev}
         and len(graph["per_device_bytes"]) == n_dev
         and all(b * n_dev == graph["bytes"] for b in graph["per_device_bytes"])
         and rows * n_dev == cfg.batch_size

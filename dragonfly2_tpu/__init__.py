@@ -18,7 +18,7 @@ Layout:
   trainer/    JAX training loops (MLP bandwidth predictor, GraphSAGE GNN)
   models/     Flax model definitions + scorer export
   ops/        Pallas/XLA kernels for the GNN hot ops
-  parallel/   mesh + sharding helpers (dp/tp over ICI)
+  parallel/   the training run's mesh: node rows over `data`
   cli/        dfget / dfcache / dfstore front-ends
 """
 

@@ -34,9 +34,9 @@ all-gathers the states and takes its rows' slots forward, and backward sums
 its own cotangent rows into all N rows with the kernel; the chips' [N, H]
 sums are reduce-scattered (`sum_by_shard`). Which of the three a placed run
 takes is decided in one place, `neighbor_agg_pallas.gather_vjp_tables`, from
-the placed shapes and the mesh. Everywhere else (no table: CPU, a mesh with
-a `model` axis, float32 or odd widths, inference, tools) it is `jnp.take`
-and its derived VJP, to the letter.
+the placed shapes and the mesh. Everywhere else (no table: CPU, float32 or
+odd widths, inference, tools) it is `jnp.take` and its derived VJP, to the
+letter.
 """
 
 from __future__ import annotations

@@ -46,7 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dragonfly2_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, pad_to_multiple
+from dragonfly2_tpu.parallel.mesh import DATA_AXIS, pad_to_multiple
 
 BLOCK_BYTES = 32 << 20
 MAX_BLOCKS = 32
@@ -166,8 +166,6 @@ def why_derived(shape: tuple[int, int], width: int, dtype, mesh: Mesh) -> str:
     platform = mesh.devices.flat[0].platform
     if platform != PLATFORM:
         return f"{platform} devices: the kernel compiles for {PLATFORM} alone"
-    if mesh.shape[MODEL_AXIS] != 1:
-        return f"`{MODEL_AXIS}` axis of {mesh.shape[MODEL_AXIS]}: the kernel sums whole rows, not column shards"
     if n % shards:
         return f"{n} node rows are not whole row shards of {shards}"
     return _table_blocks(n // shards * k, n, width, dtype)[1]

@@ -1,9 +1,1 @@
-"""Mesh + sharding helpers (dp/tp over ICI, scaling-book style)."""
-
-from dragonfly2_tpu.parallel.mesh import (  # noqa: F401
-    DATA_AXIS,
-    MODEL_AXIS,
-    graph_shardings,
-    infer_param_sharding,
-    make_mesh,
-)
+"""The training run's device mesh and sharding rules (`mesh`: rows over `data`)."""

@@ -1,22 +1,17 @@
-"""Device mesh + sharding rules for the trainer.
+"""The device mesh of a training run, and its sharding rules.
 
 Scaling model ("How to Scale Your Model" recipe): pick a mesh, annotate
-shardings on inputs/params, let XLA insert the collectives, profile. Axes:
+shardings on inputs and state, let XLA insert the collectives, profile. One
+axis, `data`: training pairs and graph node rows are row-sharded over it; XLA
+inserts the gradient psum and the per-layer all-gather that the cross-shard
+neighbor gather needs (nodes play the role of sequence positions). The state
+(params and optimizer moments, megabytes) and the pair pool are replicated.
 
-  data  — batch/data parallelism: training pairs and graph node rows are
-          row-sharded here; XLA inserts the gradient psum and the per-layer
-          all-gather that the cross-shard neighbor gather needs (this is the
-          sequence-parallel-shaped axis of the GNN: nodes play the role of
-          sequence positions).
-  model — tensor parallelism: Dense kernels column-sharded on the output dim.
-
-Which mesh a training run gets is decided where the run is placed
-(`mesh_for_run`): every device on `data`, `model` 1. One device is
-`{data: 1, model: 1}`; a four-chip host is `{data: 4, model: 1}`: node rows
-and the pair batch are split, and with them the rows XLA's gather pays for one
-by one and the cotangent rows a chip sums (ops.neighbor_agg_pallas, a sorted
-table a row shard). `make_mesh` builds any other shape for a
-caller that names one (the tests of the `model` rules, `mp_train`).
+Which mesh a training run gets is decided here, once (`mesh_for_run`): every
+device on `data`. One device is `{data: 1}`; a four-chip host is `{data: 4}`:
+node rows and the pair batch are split, and with them the rows XLA's gather
+pays for one by one and the cotangent rows a chip sums
+(ops.neighbor_agg_pallas, a sorted table a row shard).
 
 The reference has no ICI story at all (its parallelism is goroutines + gRPC,
 SURVEY.md §2.4); this module is where the TPU build replaces it.
@@ -32,67 +27,22 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 DATA_AXIS = "data"
-MODEL_AXIS = "model"
-
-
-def make_mesh(devices: list | None = None, *, model_parallel: int | None = None) -> Mesh:
-    """Build a ("data", "model") mesh over the given (or all) devices.
-
-    model_parallel defaults to the largest power of two ≤ min(4, n_devices)
-    that divides the device count — tp stays small (it rides ICI), dp takes
-    the rest.
-    """
-    devices = list(devices if devices is not None else jax.devices())
-    n = len(devices)
-    if model_parallel is None:
-        model_parallel = 1
-        for cand in (2, 4):
-            if n % cand == 0 and cand <= n:
-                model_parallel = cand
-    if n % model_parallel:
-        raise ValueError(f"{n} devices not divisible by model_parallel={model_parallel}")
-    grid = np.asarray(devices).reshape(n // model_parallel, model_parallel)
-    return Mesh(grid, (DATA_AXIS, MODEL_AXIS))
 
 
 def mesh_for_run(devices: list | None = None) -> tuple[Mesh, dict]:
-    """The mesh a GNN training run is placed on when its caller names none,
-    and the record of that for the run manifest: (mesh, decision).
+    """The mesh a GNN training run is placed on, and the record of that for
+    the run manifest: (mesh, decision).
 
-    Every device goes on `data`. `model` splits `[N, K, H]` too (GSPMD
-    carries the kernels' columns through), but leaves every device all N*K
-    rows a fraction as wide, and XLA's TPU gather and scatter-add cost by the
-    row, not by its width. Measured at one size: 65,536 x 16 x 512 on four
-    v5e chips ran 12.9 steps/s on `{data: 1, model: 4}` against 26.7 with
-    rows over `data` (PERF.md, PR 27). The kernels are megabytes and stay
-    whole on each device."""
+    Every device goes on `data`. Splitting the Dense kernels' columns over a
+    second axis instead leaves every device all N*K rows of `[N, K, H]` a
+    fraction as wide, and XLA's TPU gather and scatter-add cost by the row,
+    not by its width. Measured at one size: 65,536 x 16 x 512 on four v5e
+    chips ran 12.9 steps/s with the kernels over four devices against 26.7
+    with rows over `data` (PERF.md, PR 27). The kernels are megabytes and
+    stay whole on each device."""
     devices = list(devices if devices is not None else jax.devices())
     rule = "one_device" if len(devices) == 1 else "rows_over_data"
-    return make_mesh(devices, model_parallel=1), {"rule": rule, "devices": len(devices)}
-
-
-def _shardable(dim: int, mesh: Mesh, axis: str) -> bool:
-    return dim % mesh.shape[axis] == 0
-
-
-def param_leaf_sharding(leaf: Any, mesh: Mesh) -> NamedSharding:
-    """Tensor-parallel rule for one leaf: 2-D kernels column-shard the output
-    dim over "model" when divisible; 1-D biases follow; else replicate.
-
-    Also applied to optimizer-state leaves (adam m/v mirror param shapes) so
-    opt state and params never diverge in sharding.
-    """
-    shape = getattr(leaf, "shape", ())
-    if len(shape) == 2 and _shardable(shape[1], mesh, MODEL_AXIS):
-        return NamedSharding(mesh, P(None, MODEL_AXIS))
-    if len(shape) == 1 and shape[0] > 1 and _shardable(shape[0], mesh, MODEL_AXIS):
-        return NamedSharding(mesh, P(MODEL_AXIS))
-    return NamedSharding(mesh, P())
-
-
-def infer_param_sharding(params: Any, mesh: Mesh) -> Any:
-    """Apply param_leaf_sharding across a whole pytree."""
-    return jax.tree.map(lambda leaf: param_leaf_sharding(leaf, mesh), params)
+    return Mesh(np.asarray(devices), (DATA_AXIS,)), {"rule": rule, "devices": len(devices)}
 
 
 def graph_shardings(mesh: Mesh) -> tuple[NamedSharding, ...]:

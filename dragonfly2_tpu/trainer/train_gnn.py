@@ -1,15 +1,16 @@
 """Sharded GNN training (north-star configs 2-3).
 
-One jitted program over a ("data", "model") mesh, `multi_step`: a `lax.scan`
-of optimizer steps that samples its minibatches on the device
-(`shard_for_training_scan` places a run and builds it, `train_async` drives
-it). Graph node rows and the sampled pair batch are sharded over "data", Dense
-kernels over "model"; the gradient psum, and off the TPU the neighbor gather's
-all-gather, are XLA's, from the sharding annotations. On TPU chips the
-gather's VJP is a `custom_vjp` over a table of the slots sorted by destination
-that placement builds once a run (`ops/neighbor_agg_pallas`): one Pallas
-segmented sum on one chip; on a `data` mesh under `shard_map`, a table per row
-shard, with the gather's own `all_gather` of the states.
+One jitted program over the run's mesh (`parallel.mesh.mesh_for_run`: every
+device on "data"), `multi_step`: a `lax.scan` of optimizer steps that samples
+its minibatches on the device (`shard_for_training_scan` places a run and
+builds it, `train_async` drives it). Graph node rows and the sampled pair
+batch are sharded over "data", the state is replicated; the gradient psum, and
+off the TPU the neighbor gather's all-gather, are XLA's, from the sharding
+annotations. On TPU chips the gather's VJP is a `custom_vjp` over a table of
+the slots sorted by destination that placement builds once a run
+(`ops/neighbor_agg_pallas`): one Pallas segmented sum on one chip; on a `data`
+mesh under `shard_map`, a table per row shard, with the gather's own
+`all_gather` of the states.
 
 The compiled step is kept across runs of the same shapes: model and optimizer
 transform are made once per configuration, so two runs' states have one tree
@@ -133,7 +134,7 @@ def _place_sharded(
     state: train_state.TrainState, g: TopoGraph, mesh: Mesh
 ) -> tuple[train_state.TrainState, Any, TopoGraph, TopoGraph]:
     """Shared placement: pad node rows to their rung (whole tiles and whole
-    row shards: `neighbor_agg_pallas.placed_rows`), kernels over "model",
+    row shards: `neighbor_agg_pallas.placed_rows`), the state replicated,
     node rows over "data". The neighbor table is fixed from here to the run's
     end, so its transpose is fixed here too, once, on the host, where the
     kernel that sums the gather's VJP over it will run
@@ -146,21 +147,10 @@ def _place_sharded(
     dp = mesh.shape[meshlib.DATA_AXIS]
     hosts = g.node_feats.shape[0]
     rows = placed_rows(hosts, dp)
-    param_sh = meshlib.infer_param_sharding(state.params, mesh)
-    state_sh = train_state.TrainState(
-        step=meshlib.replicated(mesh),
-        apply_fn=state.apply_fn,
-        params=param_sh,
-        tx=state.tx,
-        opt_state=jax.tree.map(
-            lambda leaf: meshlib.param_leaf_sharding(leaf, mesh), state.opt_state
-        ),
-    )
+    state_sh = jax.tree.map(lambda _: meshlib.replicated(mesh), state)
     # the host's part of placement, timed: padding, the sorted table(s), and
     # the copies onto the devices (a row shard each on a `data` mesh)
-    with default_tracer().span(
-        "trainer.gnn.place", data=dp, model=mesh.shape[meshlib.MODEL_AXIS], hosts=hosts, rows=rows
-    ):
+    with default_tracer().span("trainer.gnn.place", data=dp, hosts=hosts, rows=rows):
         g = pad_graph(g, rows)
         by_dst, _ = gather_vjp_tables(np.asarray(g.neighbors), *_gathered_states(state), mesh)
         g = g._replace(by_dst=by_dst)
@@ -273,27 +263,21 @@ def shard_for_training_scan(
 
 
 def _placement(mesh: Mesh, decision: dict, hosts: int, state: Any, g: TopoGraph, batch_size: int) -> dict:
-    """What the placed run occupies, for the run manifest: the mesh and who
-    chose it (`parallel.mesh.mesh_for_run`'s record; `{"rule": "given"}` for
-    a caller's own), the hosts the run was given and the rows they were
-    placed at (`pad_pct`: the rows above the cluster's own, of its own, which
-    every step pays for), the Dense kernels the tensor-parallel rule shards and
-    the graph's node rows, both read back from the placed arrays, the rows of
-    one pair batch each device is constrained to inside the step, and which
-    VJP the gather takes: the table(s) placement hung on the graph, or the
-    rule's reason there is none (`neighbor_agg_pallas.gather_vjp_report`)."""
+    """What the placed run occupies, for the run manifest: the mesh and why
+    (`parallel.mesh.mesh_for_run`'s record), the hosts the run was given and
+    the rows they were placed at (`pad_pct`: the rows above the cluster's own,
+    of its own, which every step pays for), the graph's node rows read back
+    from the placed arrays, the rows of one pair batch each device is
+    constrained to inside the step, and which VJP the gather takes: the
+    table(s) placement hung on the graph, or the rule's reason there is none
+    (`neighbor_agg_pallas.gather_vjp_report`)."""
     from dragonfly2_tpu.ops.neighbor_agg_pallas import gather_vjp_report
 
-    kernels = [
-        leaf for leaf in jax.tree.leaves(state.params)
-        if leaf.ndim == 2 and meshlib.MODEL_AXIS in leaf.sharding.spec
-    ]
     batch_size = meshlib.pad_to_multiple(batch_size, mesh.shape[meshlib.DATA_AXIS])
     rows = g.node_feats.shape[0]
     return {
         "mesh": {k: int(v) for k, v in mesh.shape.items()},
         "decision": {**decision, "hosts": hosts, "rows": rows, "pad_pct": round(100.0 * (rows - hosts) / hosts, 2)},
-        "kernels": meshlib.placement_report(kernels),
         "graph": meshlib.placement_report(g._replace(by_dst=None)),
         "batch_rows_per_device": meshlib.batch_sharding(mesh).shard_shape((batch_size,))[0],
         "gather_vjp": {
@@ -304,16 +288,18 @@ def _placement(mesh: Mesh, decision: dict, hosts: int, state: Any, g: TopoGraph,
     }
 
 
+# `train_async` logs a line every this many steps
+LOG_EVERY = 100
+
+
 async def train_async(
     cfg: GNNTrainConfig,
     graph: TopoGraph,
     pairs: PairBatch,
     *,
     steps: int,
-    mesh: Mesh | None = None,
     seed: int = 0,
     steps_per_call: int = 10,
-    log_every: int = 100,
     log: Callable[[str], None] = lambda s: None,
     telemetry=None,
 ) -> tuple[train_state.TrainState, list[float]]:
@@ -335,9 +321,9 @@ async def train_async(
     where it built the program, 0 where the kept one served it), and once
     placed, whether a kept program served the run and how many are kept.
 
-    The mesh, when the caller gives none, is `parallel.mesh.mesh_for_run`'s
-    (every device on `data`); the run manifest's `placement.decision` says so,
-    with the hosts given and the rows placed.
+    The mesh is `parallel.mesh.mesh_for_run`'s (every device on `data`); the
+    run manifest's `placement.decision` says so, with the hosts given and the
+    rows placed. `log` gets a line every `LOG_EVERY` steps and at the end.
 
     Spans (children of the caller's current span; to_thread copies the
     context): `trainer.gnn.setup` around init + placement + building the
@@ -347,9 +333,7 @@ async def train_async(
     D2H pulls). The loop's turn between two calls is the gap between two
     `trainer.gnn.call` spans.
     """
-    decision = {"rule": "given"}
-    if mesh is None:
-        mesh, decision = meshlib.mesh_for_run()
+    mesh, decision = meshlib.mesh_for_run()
     steps_per_call = max(1, min(steps_per_call, steps))
     calls = -(-steps // steps_per_call)
     traces_before = _traces
@@ -417,7 +401,7 @@ async def train_async(
                 )
         losses.extend(float(x) for x in ls)
         done = len(losses)
-        if done % log_every < steps_per_call or i == calls - 1:
+        if done % LOG_EVERY < steps_per_call or i == calls - 1:
             log(
                 f"step {done}/{calls * steps_per_call} loss={losses[-1]:.5f} "
                 f"({done / (time.perf_counter() - t0):.2f} steps/s)"

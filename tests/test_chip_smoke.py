@@ -53,7 +53,7 @@ def test_chip_smoke_on_cpu_fails_only_on_platform(tmp_path):
         "trainer": "ok", "artifacts": "ok", "scorer": "ok", "device": "ok",
         "platform": "FAILED",
     }, summary["detail"]
-    assert summary["mesh"] == {"data": 1, "model": 1}
+    assert summary["mesh"] == {"data": 1}
     platform = summary["detail"]["platform"]
     assert platform["trainer_reported"] == platform["device_child_reported"] == "cpu"
     assert any("trainer ran on 'cpu'" in p for p in platform["problems"])
@@ -75,7 +75,7 @@ def test_result_line_has_exactly_the_contract_keys(monkeypatch, capsys):
     """On success stdout's one line is {"ok", "device": {"platform", "kind",
     "count"}} and nothing else; the detail is the stderr summary."""
     tpu = {"platform": "tpu", "device_kind": "TPU v5 lite", "device_count": 1}
-    trainer = {"ok": True, "device": tpu, "mesh": {"data": 1, "model": 1}, "gnn_artifact": "g"}
+    trainer = {"ok": True, "device": tpu, "mesh": {"data": 1}, "gnn_artifact": "g"}
     child = {"ok": True, **tpu, "pallas": {"1024x16x256": {"ok": True, "compiled": True}}}
     monkeypatch.setattr(chip_smoke, "_trainer_phase", lambda *a: trainer)
     monkeypatch.setattr(chip_smoke, "_artifacts_phase", lambda t: {"ok": True, "missing": []})

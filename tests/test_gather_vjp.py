@@ -290,17 +290,17 @@ def test_a_placed_step_with_the_table(monkeypatch):
     n, k = 256, 16
     cfg = train_gnn.GNNTrainConfig(hidden=128, embed_dim=16, num_layers=2, batch_size=64)
     graph, pairs = _run_inputs(_hub_table(n, k), cfg)
-    mesh = meshlib.make_mesh()
+    mesh, decision = meshlib.mesh_for_run()
     unplaced = train_gnn.init_state(cfg, graph, 0)
     state, g, _, _ = train_gnn.shard_for_training_scan(
         unplaced, graph, pairs, mesh, batch_size=64, steps_per_call=2)
     assert g.by_dst is None
     counts = {"slots": n * k, "max_in_degree": int(np.bincount(graph.neighbors.ravel()).max())}
-    assert train_gnn._placement(mesh, {"rule": "given"}, n, state, g, 64)["gather_vjp"] == {
+    assert train_gnn._placement(mesh, decision, n, state, g, 64)["gather_vjp"] == {
         "path": "derived", "reason": "cpu devices: the kernel compiles for tpu alone", **counts}
     assert train_gnn._gathered_states(state) == (128, jnp.bfloat16)
     table = _table_in_blocks(monkeypatch, graph.neighbors, 128, jnp.bfloat16, 2)
-    placement = train_gnn._placement(mesh, {"rule": "given"}, n, state, g._replace(by_dst=table), 64)
+    placement = train_gnn._placement(mesh, decision, n, state, g._replace(by_dst=table), 64)
     assert placement["gather_vjp"] == {
         "path": "sorted_kernel", "slot_order": "k_major", "reorder": "in_place", "shards": 1, "blocks": 2,
         "block_bytes": n * k // 2 * 128 * 2, "live_windows": {"least": int(table.live[0]), "most": int(table.live[0])},
@@ -323,15 +323,19 @@ def _one_device_mesh():
     return meshlib.mesh_for_run(jax.devices()[:1])[0]
 
 
+def _three_device_mesh():
+    return meshlib.mesh_for_run(jax.devices()[:3])[0]
+
+
 # (reason's words, neighbors' shape, width, dtype, the mesh): each alone keeps `jnp.take`'s VJP
 DERIVED_BY_RULE = {
     "cpu": ("cpu devices", (512, 16), 128, jnp.bfloat16, _one_device_mesh),
-    "model_axis": ("`model` axis of 2", (512, 16), 128, jnp.bfloat16,
-                   lambda: meshlib.make_mesh(jax.devices()[:4], model_parallel=2)),
     "float32": ("not float32[128]", (512, 16), 128, jnp.float32, _one_device_mesh),
     "lanes": ("not bfloat16[96]", (512, 16), 96, jnp.bfloat16, _one_device_mesh),
     "tiles": ("384 destination rows are not whole tiles of 256", (384, 16), 128, jnp.bfloat16, _one_device_mesh),
     "max_blocks": ("do not tile into at most 32 blocks", (65536, 16), 1024, jnp.bfloat16, _one_device_mesh),
+    "row_shards": ("16384 node rows are not whole row shards of 3", (16384, 16), 128, jnp.bfloat16,
+                   _three_device_mesh),
 }
 
 
@@ -350,10 +354,8 @@ def test_the_one_rule_says_why_the_vjp_stays_derived(monkeypatch, rule):
     assert table is None and said in reason, reason
     assert pk.gather_vjp_report(None, shape, width, dtype, mesh) == {"path": "derived", "reason": reason}
     if rule == "max_blocks":  # 2 GB of cotangent rows: four row shards hold 16 blocks each (ROADMAP R11)
-        data4 = meshlib.make_mesh(jax.devices()[:4], model_parallel=1)
+        data4 = meshlib.mesh_for_run(jax.devices()[:4])[0]
         assert pk.why_derived(shape, width, dtype, data4) == ""
-        assert "16384 node rows are not whole row shards of 3" in pk.why_derived(
-            (16384, 16), width, dtype, meshlib.make_mesh(jax.devices()[:3], model_parallel=1))
 
 
 def _parents_gather(h, neighbors, by_dst=None):

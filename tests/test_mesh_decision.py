@@ -43,7 +43,7 @@ import telemetry_gen  # noqa: E402
 @pytest.mark.parametrize("n_devices", [1, 2, 4, 8])
 def test_every_device_of_a_run_goes_on_data(n_devices):
     mesh, decision = meshlib.mesh_for_run(jax.devices()[:n_devices])
-    assert dict(mesh.shape) == {"data": n_devices, "model": 1}
+    assert dict(mesh.shape) == {"data": n_devices}
     assert decision == {"rule": "one_device" if n_devices == 1 else "rows_over_data", "devices": n_devices}
     if n_devices == len(jax.devices()):  # no devices named: all there are
         assert meshlib.mesh_for_run()[0] == mesh
@@ -116,8 +116,8 @@ def _leaf_norms(grads) -> np.ndarray:
 
 @pytest.fixture(scope="module")
 def runs(dataset) -> dict:
-    data4 = meshlib.make_mesh(jax.devices()[:4], model_parallel=1)
-    one = meshlib.make_mesh(jax.devices()[:1], model_parallel=1)
+    data4 = meshlib.mesh_for_run(jax.devices()[:4])[0]
+    one = meshlib.mesh_for_run(jax.devices()[:1])[0]
     ref = reference.follow_steps(TINY, dataset, STEPS)
     inputs = _tiny_inputs(dataset)
     return {
@@ -236,12 +236,11 @@ def kernel_runs() -> dict:
         {k: jnp.asarray(v)[rows] for k, v in dataset["pairs"].items()}, num_layers=m["num_layers"])
     out = {
         "reference": Run(None, None, np.asarray(ref["loss"]), np.asarray(ref["grad_norm"]), _leaf_norms(ref_grads)),
-        "one.derived": _program(inputs, meshlib.make_mesh(jax.devices()[:1], model_parallel=1), jnp.bfloat16, rows),
+        "one.derived": _program(inputs, meshlib.mesh_for_run(jax.devices()[:1])[0], jnp.bfloat16, rows),
     }
     with pytest.MonkeyPatch.context() as patch, _kernel_as_xla_ops():
         patch.setattr(pk, "PLATFORM", "cpu")
-        out["data4.tables"] = _program(
-            inputs, meshlib.make_mesh(jax.devices()[:4], model_parallel=1), jnp.bfloat16, rows)
+        out["data4.tables"] = _program(inputs, meshlib.mesh_for_run(jax.devices()[:4])[0], jnp.bfloat16, rows)
     return out
 
 
@@ -274,7 +273,7 @@ def test_the_per_shard_vjp_on_a_four_device_mesh_is_jnp_takes(monkeypatch):
     from test_gather_vjp import _hub_table
 
     monkeypatch.setattr(pk, "PLATFORM", "cpu")  # the test's word: these CPU devices take the (interpreted) kernel
-    mesh = meshlib.make_mesh(jax.devices()[:4], model_parallel=1)
+    mesh = meshlib.mesh_for_run(jax.devices()[:4])[0]
     rows = meshlib.batch_sharding(mesh)
     # a shard's [N/4, K, H] in blocks of 12 K slices, of 8, and of one and a half (straddling a slice's edge)
     for n, k, width, blocks in [(1024, 12, 128, 1), (512, 16, 256, 2), (1024, 6, 128, 4)]:
@@ -332,21 +331,33 @@ def test_a_node_count_the_devices_do_not_divide_trains_as_on_one_device(hosts, n
     assert loss_gap < F32_TOLERANCE and gnorm_gap < 10 * F32_TOLERANCE, (loss_gap, gnorm_gap)
 
 
-def test_one_device_lowers_to_the_program_a_hand_built_mesh_gives(dataset):
-    """On one device the decision is `{data: 1, model: 1}`, `make_mesh`'s
-    answer before there was a decision: the lowered scan step is the same
-    text."""
-    cfg, graph, pairs = _tiny_inputs(dataset)
-    decided, decision = meshlib.mesh_for_run(jax.devices()[:1])
-    assert decision["rule"] == "one_device" and dict(decided.shape) == {"data": 1, "model": 1}
-    texts = []
-    for mesh in (decided, meshlib.make_mesh(jax.devices()[:1])):
-        train_gnn._kept.clear()  # equal meshes would be served one kept program: each text from a build of its own
-        state, g, pool, multi_step = train_gnn.shard_for_training_scan(
-            train_gnn.init_state(cfg, graph, 0), graph, pairs, mesh,
-            batch_size=cfg.batch_size, steps_per_call=STEPS)
-        texts.append(multi_step.lower(state, g, pool, jax.random.PRNGKey(0)).as_text())
-    assert texts[0] == texts[1]
+def test_a_run_is_placed_on_the_mesh_the_one_decision_gives(monkeypatch):
+    """`train_async` asks `mesh_for_run` once a run, with no devices named,
+    and places the run on what it returns: the manifest's `placement` is that
+    mesh, that decision and what placement read back, with no other entry."""
+    from dragonfly2_tpu.trainer.metrics import TrainRunTelemetry
+
+    asked = []
+
+    def decide(devices=None, run=meshlib.mesh_for_run):
+        asked.append(devices)
+        return run(jax.devices()[:2])
+
+    monkeypatch.setattr(meshlib, "mesh_for_run", decide)
+    m = TINY["model"]
+    cluster = synthetic.make_cluster(num_nodes=30, num_neighbors=m["num_neighbors"], num_pairs=256, seed=30)
+    cfg = train_gnn.GNNTrainConfig(hidden=m["hidden"], embed_dim=m["embed_dim"], num_layers=m["num_layers"],
+                                   batch_size=m["pair_batch"])
+    sink = TrainRunTelemetry("gnn")
+    asyncio.run(train_gnn.train_async(cfg, cluster.graph, cluster.pairs, steps=2, steps_per_call=2, telemetry=sink))
+    placement = sink.placement
+    assert asked == [None]
+    assert set(placement) == {"mesh", "decision", "graph", "batch_rows_per_device", "gather_vjp"}
+    assert placement["mesh"] == {"data": 2}
+    assert placement["decision"] == {"rule": "rows_over_data", "devices": 2, "hosts": 30, "rows": 256,
+                                     "pad_pct": round(100 * (256 - 30) / 30, 2)}
+    assert placement["graph"]["per_device_bytes"] == [placement["graph"]["bytes"] // 2] * 2
+    assert placement["batch_rows_per_device"] * 2 == cfg.batch_size
 
 
 def test_the_run_manifest_names_the_decision(tmp_path):
@@ -380,7 +391,7 @@ def test_the_run_manifest_names_the_decision(tmp_path):
     gnn = svc.run_history[-1]["models"]["gnn"]
     placement = gnn["placement"]
     n = len(jax.devices())
-    assert placement["mesh"] == {"data": n, "model": 1}
+    assert placement["mesh"] == {"data": n}
     hosts = svc.run_history[-1]["dataset"]["nodes"]
     assert placement["decision"] == {"rule": "rows_over_data", "devices": n, "hosts": hosts, "rows": 256,
                                      "pad_pct": round(100 * (256 - hosts) / hosts, 2)}

@@ -21,7 +21,7 @@ from dragonfly2_tpu.parallel import mesh as meshlib
 from dragonfly2_tpu.trainer import artifacts, synthetic, train_gnn
 from dragonfly2_tpu.trainer.synthetic import PairBatch
 from test_mesh_decision import F32_TOLERANCE
-from test_trainer import KEPT_CFG, nothing_kept  # noqa: F401  (a fixture)
+from test_trainer import KEPT_CFG, nothing_kept, one_device  # noqa: F401  (a fixture)
 
 POOL_MAX_HOSTS = 65_536  # TrainerConfig.pool_max_hosts: no run is given more
 SHARDS = [1, 4, 8]
@@ -77,7 +77,7 @@ def test_the_one_rule_takes_every_rung_within_the_tables_reach(rungs, monkeypatc
     widths the trainer ships, on one device and on `{data: 4}` (these CPU
     devices stand in for chips: the test's word, `PLATFORM`)."""
     monkeypatch.setattr(pk, "PLATFORM", "cpu")
-    mesh = meshlib.make_mesh(jax.devices()[:shards], model_parallel=1)
+    mesh = meshlib.mesh_for_run(jax.devices()[:shards])[0]
     reach = pk.MAX_BLOCKS * pk.BLOCK_BYTES // (16 * width * 2) * shards  # rows whose shard's slots fill MAX_BLOCKS blocks
     assert reach >= POOL_MAX_HOSTS
     for rung in np.unique(rungs[shards][8:]):
@@ -166,7 +166,7 @@ def test_a_host_count_that_moves_inside_a_rung_keeps_the_program(nothing_kept): 
     ]
 
 
-def test_two_placements_that_alternate_are_both_kept_and_a_third_evicts_the_least_recently_used(nothing_kept):  # noqa: F811
+def test_two_placements_that_alternate_are_both_kept_and_a_third_evicts_the_least_recently_used(nothing_kept, monkeypatch):  # noqa: F811
     """A pool that rotates every second upload alternates between two rungs:
     one upload's hosts (300, 420: 512 rows) and two uploads' (600, 700: 768).
     Each builds its program once, and from then on both are served; a third
@@ -177,14 +177,14 @@ def test_two_placements_that_alternate_are_both_kept_and_a_third_evicts_the_leas
 
     from dragonfly2_tpu.trainer.metrics import TrainRunTelemetry
 
-    mesh = meshlib.make_mesh(jax.devices()[:1])
+    one_device(monkeypatch)
     runs, programs = [], {}
     for hosts in (300, 600, 420, 700, 900, 600, 300):
         cluster = synthetic.make_cluster(num_nodes=hosts, num_neighbors=4, num_pairs=512, seed=hosts)
         sink = TrainRunTelemetry("gnn")
         asyncio.run(train_gnn.train_async(
             train_gnn.GNNTrainConfig(**KEPT_CFG), cluster.graph, cluster.pairs, steps=6, steps_per_call=3,
-            telemetry=sink, mesh=mesh))
+            telemetry=sink))
         manifest = sink.summary()
         rows = manifest["placement"]["decision"]["rows"]
         programs.setdefault(rows, weakref.ref(list(train_gnn._kept.values())[-1]))

@@ -46,7 +46,7 @@ def op_names() -> set[str]:
         np.zeros((256, FEATURE_DIM), np.float32), np.zeros(256, np.float32),
     )
     state, g, pool, multi_step = train_gnn.shard_for_training_scan(
-        train_gnn.init_state(CFG, _graph(), 0), _graph(), pairs, meshlib.make_mesh(),
+        train_gnn.init_state(CFG, _graph(), 0), _graph(), pairs, meshlib.mesh_for_run()[0],
         batch_size=CFG.batch_size, steps_per_call=3,
     )
     text = multi_step.lower(state, g, pool, jax.random.PRNGKey(0)).compile().as_text()

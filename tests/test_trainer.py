@@ -16,25 +16,33 @@ from dragonfly2_tpu.trainer.synthetic import PairBatch
 
 
 def test_make_mesh_axes():
-    mesh = meshlib.make_mesh()
-    assert set(mesh.axis_names) == {"data", "model"}
-    assert mesh.shape["data"] * mesh.shape["model"] == len(jax.devices())
-    assert mesh.shape["model"] in (2, 4)  # 8 devices → real tensor parallelism
+    """A run's mesh has one axis, `data`, and every device is on it."""
+    mesh, decision = meshlib.mesh_for_run()
+    assert mesh.axis_names == ("data",)
+    assert dict(mesh.shape) == {"data": len(jax.devices())} == {"data": 8}
+    assert decision == {"rule": "rows_over_data", "devices": 8}
 
 
-def test_param_sharding_rule():
-    mesh = meshlib.make_mesh()
-    params = {
-        "kernel": jnp.zeros((16, 64)),
-        "bias": jnp.zeros((64,)),
-        "odd": jnp.zeros((16, 7)),
-        "scalar": jnp.zeros(()),
-    }
-    sh = meshlib.infer_param_sharding(params, mesh)
-    assert "model" in str(sh["kernel"].spec)
-    assert "model" in str(sh["bias"].spec)
-    assert sh["odd"].spec == jax.sharding.PartitionSpec()
-    assert sh["scalar"].spec == jax.sharding.PartitionSpec()
+def test_param_sharding_rule(monkeypatch):
+    """Placement replicates every leaf of the state, params and optimizer
+    moments alike, on one device and on a `data` mesh of four, while the
+    graph's node rows and the sorted table split over `data` (the test, not
+    the program, says these CPU devices get tables)."""
+    from dragonfly2_tpu.ops import neighbor_agg_pallas as pk
+
+    monkeypatch.setattr(pk, "PLATFORM", "cpu")
+    cluster = synthetic.make_cluster(num_nodes=1024, num_neighbors=16, num_pairs=256, seed=3)
+    cfg = train_gnn.GNNTrainConfig(hidden=128, embed_dim=64, num_layers=2, batch_size=64)
+    for n_devices in (1, 4):
+        mesh = meshlib.mesh_for_run(jax.devices()[:n_devices])[0]
+        state, _, g, _ = train_gnn._place_sharded(train_gnn.init_state(cfg, cluster.graph), cluster.graph, mesh)
+        leaves = jax.tree.leaves((state.params, state.opt_state, state.step))
+        assert len(leaves) > 2 * len(jax.tree.leaves(state.params))  # the moments are there too
+        assert all(leaf.sharding.is_fully_replicated and leaf.sharding.mesh == mesh for leaf in leaves)
+        table = jax.tree.leaves(g.by_dst)
+        assert table, "no sorted table placed"
+        for leaf in [*g[:4], *table]:  # a row shard (or a shard's table) a device
+            assert leaf.sharding.shard_shape(leaf.shape)[0] * n_devices == leaf.shape[0], leaf.sharding
 
 
 class TestShardedTraining:
@@ -44,14 +52,13 @@ class TestShardedTraining:
         return synthetic.make_cluster(num_nodes=128, num_neighbors=8, num_pairs=10240, seed=3)
 
     def test_one_sharded_step_runs_on_mesh(self, cluster):
-        mesh = meshlib.make_mesh()
+        mesh = meshlib.mesh_for_run()[0]
         cfg = train_gnn.GNNTrainConfig(hidden=32, embed_dim=16, num_layers=2, batch_size=64, warmup_steps=2)
         state = train_gnn.init_state(cfg, cluster.graph)
         state, g, pool, multi_step = train_gnn.shard_for_training_scan(
             state, cluster.graph, cluster.pairs, mesh, batch_size=64, steps_per_call=1)
-        # params actually sharded over the model axis
-        kernels = [p for p in jax.tree.leaves(state.params) if getattr(p, "ndim", 0) == 2]
-        assert any("model" in str(k.sharding.spec) for k in kernels)
+        # the params whole on every device
+        assert all(p.sharding.is_fully_replicated for p in jax.tree.leaves(state.params))
         # graph rows actually sharded over the data axis, the pool on every device
         assert "data" in str(g.node_feats.sharding.spec)
         assert pool.child.sharding.is_fully_replicated
@@ -66,7 +73,7 @@ class TestShardedTraining:
             hidden=64, embed_dim=32, num_layers=2, batch_size=512, warmup_steps=10, learning_rate=3e-3
         )
         state, g, pool, multi_step = train_gnn.shard_for_training_scan(
-            train_gnn.init_state(cfg, cluster.graph), cluster.graph, train_pairs, meshlib.make_mesh(),
+            train_gnn.init_state(cfg, cluster.graph), cluster.graph, train_pairs, meshlib.mesh_for_run()[0],
             batch_size=cfg.batch_size, steps_per_call=120)
         state, (losses, _) = multi_step(state, g, pool, jax.random.PRNGKey(0))
         losses = np.asarray(losses).tolist()
@@ -97,24 +104,28 @@ class TestShardedTraining:
 def test_scan_training_converges_and_matches_semantics():
     """Device-resident scan path (shard_for_training_scan): sampling inside
     lax.scan over the on-device pool must converge over several calls and
-    keep params sharded over the model axis."""
+    keep the params replicated on the run's mesh."""
     cluster = synthetic.make_cluster(num_nodes=128, num_neighbors=8, num_pairs=8192, seed=3)
     cfg = train_gnn.GNNTrainConfig(hidden=64, embed_dim=32, num_layers=2, warmup_steps=5)
-    mesh = meshlib.make_mesh()
+    mesh = meshlib.mesh_for_run()[0]
     state = train_gnn.init_state(cfg, cluster.graph, rng_seed=3)
     state, g, pool, multi = train_gnn.shard_for_training_scan(
         state, cluster.graph, cluster.pairs, mesh, batch_size=512, steps_per_call=10
     )
-    kernels = [p for p in jax.tree.leaves(state.params) if getattr(p, "ndim", 0) == 2]
-    assert any("model" in str(k.sharding.spec) for k in kernels)
     key = jax.random.PRNGKey(0)
     losses = []
     for _ in range(8):  # 80 steps in 8 dispatches
         key, sub = jax.random.split(key)
         state, (batch_losses, _) = multi(state, g, pool, sub)
         losses.extend(np.asarray(batch_losses).tolist())
+    assert all(p.sharding.is_fully_replicated for p in jax.tree.leaves(state.params))
     assert len(losses) == 80 and all(np.isfinite(v) for v in losses)
     assert np.mean(losses[-10:]) < np.mean(losses[:10]) * 0.5, losses[:3] + losses[-3:]
+
+
+def one_device(patch):
+    """Runs placed while `patch` holds get the mesh the program gives one device."""
+    patch.setattr(meshlib, "mesh_for_run", lambda run=meshlib.mesh_for_run: run(jax.devices()[:1]))
 
 
 @pytest.fixture(scope="module")
@@ -136,10 +147,10 @@ def served_runs():
     runs = {}
     with pytest.MonkeyPatch.context() as m:
         m.setattr(train_gnn, "shard_for_training_scan", spy)
+        one_device(m)  # a quick compile
         for telemetry in (sink, None):
             _, losses = asyncio.run(train_gnn.train_async(
-                cfg, cluster.graph, cluster.pairs, steps=6, steps_per_call=3, telemetry=telemetry,
-                mesh=meshlib.make_mesh(jax.devices()[:1])))  # one device: a quick compile
+                cfg, cluster.graph, cluster.pairs, steps=6, steps_per_call=3, telemetry=telemetry))
             state, g, pool, multi_step = built.pop()
             shapes = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding), (state, g, pool))
@@ -177,14 +188,14 @@ def newest_kept():
     return list(train_gnn._kept.values())[-1]
 
 
-def _served(cluster, *, mesh, steps_per_call=3, **cfg):
+def _served(cluster, *, steps_per_call=3, **cfg):
     """One `train_async` run of 6 steps: (losses, the run manifest's `calls`)."""
     from dragonfly2_tpu.trainer.metrics import TrainRunTelemetry
 
     sink = TrainRunTelemetry("gnn")
     _, losses = asyncio.run(train_gnn.train_async(
         train_gnn.GNNTrainConfig(**{**KEPT_CFG, **cfg}), cluster.graph, cluster.pairs,
-        steps=6, steps_per_call=steps_per_call, telemetry=sink, mesh=mesh))
+        steps=6, steps_per_call=steps_per_call, telemetry=sink))
     return losses, sink.summary()["calls"]
 
 
@@ -192,21 +203,23 @@ def test_a_run_of_the_kept_shapes_is_served_the_kept_program(nothing_kept):
     """The second run of one configuration and shapes (another graph, fresh
     weights) traces nothing: its first call hits the kept function's one
     cache entry. What it computes is what a process that kept nothing does.
-    On a caller's mesh of one device, made anew for every run, and on the
-    program's own (`mesh_for_run`: a `data` mesh of every device, anew too)."""
+    On the mesh the program gives one device and every device (`mesh_for_run`:
+    a `data` mesh, made anew for every run)."""
     first = synthetic.make_cluster(num_nodes=64, num_neighbors=4, num_pairs=512, seed=1)
     second = synthetic.make_cluster(num_nodes=64, num_neighbors=4, num_pairs=512, seed=2)
     assert not np.array_equal(first.graph.neighbors, second.graph.neighbors)
-    for mesh in (lambda: meshlib.make_mesh(jax.devices()[:1]), lambda: None):
-        _, calls = _served(first, mesh=mesh())
-        assert calls["traced"] == 1 and calls["count"] == 2 and calls["first_ms"] > 0
-        program = newest_kept()
-        kept_losses, calls = _served(second, mesh=mesh())
-        assert calls["traced"] == 0 and newest_kept() is program and program._cache_size() == 1
-        train_gnn._kept.clear()
-        fresh_losses, calls = _served(second, mesh=mesh())
-        assert calls["traced"] == 1 and newest_kept() is not program
-        assert kept_losses == fresh_losses and len(kept_losses) == 6
+    for placed in (one_device, lambda patch: None):
+        with pytest.MonkeyPatch.context() as patch:
+            placed(patch)
+            _, calls = _served(first)
+            assert calls["traced"] == 1 and calls["count"] == 2 and calls["first_ms"] > 0
+            program = newest_kept()
+            kept_losses, calls = _served(second)
+            assert calls["traced"] == 0 and newest_kept() is program and program._cache_size() == 1
+            train_gnn._kept.clear()
+            fresh_losses, calls = _served(second)
+            assert calls["traced"] == 1 and newest_kept() is not program
+            assert kept_losses == fresh_losses and len(kept_losses) == 6
 
 
 # two runs a case, each other than the one before it in one thing the program is built from
@@ -218,7 +231,7 @@ OTHER_RUNS = {
 
 
 @pytest.mark.parametrize("others", OTHER_RUNS)
-def test_a_run_of_other_shapes_builds_its_own_and_one_program_is_kept(nothing_kept, others):
+def test_a_run_of_other_shapes_builds_its_own_and_one_program_is_kept(nothing_kept, monkeypatch, others):
     """Whatever the program was built from is part of what it is kept under:
     a run that differs in any of it traces its own; of the programs built, the
     newest `KEPT_PROGRAMS` are kept, and the one a new build replaces (the
@@ -226,12 +239,12 @@ def test_a_run_of_other_shapes_builds_its_own_and_one_program_is_kept(nothing_ke
     import gc
     import weakref
 
-    mesh = meshlib.make_mesh(jax.devices()[:1])
+    one_device(monkeypatch)
     sizes = dict(num_nodes=64, num_neighbors=4, num_pairs=512, seed=1)
     built = []
     for other in [{}, *OTHER_RUNS[others]]:
         cluster = synthetic.make_cluster(**{**sizes, **{k: v for k, v in other.items() if k in sizes}})
-        _, calls = _served(cluster, mesh=mesh, **{k: v for k, v in other.items() if k not in sizes})
+        _, calls = _served(cluster, **{k: v for k, v in other.items() if k not in sizes})
         assert calls["traced"] == 1 and newest_kept()._cache_size() == 1, other
         built.append(weakref.ref(newest_kept()))
         gc.collect()
@@ -262,7 +275,7 @@ def test_the_body_and_the_scan_agree():
     cfg = train_gnn.GNNTrainConfig(hidden=32, embed_dim=16, num_layers=2, batch_size=64, warmup_steps=2)
     unplaced = train_gnn.init_state(cfg, cluster.graph)
     state, g, pool, multi_step = train_gnn.shard_for_training_scan(
-        unplaced, cluster.graph, cluster.pairs, meshlib.make_mesh(jax.devices()[:1]),
+        unplaced, cluster.graph, cluster.pairs, meshlib.mesh_for_run(jax.devices()[:1])[0],
         batch_size=cfg.batch_size, steps_per_call=1)
     key = jax.random.PRNGKey(5)
     # the rows the scan's one step samples: its key is split off `key` as multi_step does

@@ -9,6 +9,7 @@ import gc
 import time
 from collections import Counter
 
+import jax
 import numpy as np
 import pytest
 
@@ -120,6 +121,7 @@ def test_a_training_run_leaves_the_span_tree(trained):
     (place,) = (s["attrs"] for s in spans if s["name"] == "trainer.gnn.place")
     nodes = next(s["attrs"]["nodes"] for s in spans if s["name"] == "trainer.train_gnn")
     assert (place["hosts"], place["rows"]) == (nodes, 256) and nodes < 256
+    assert place["data"] == len(jax.devices()) and "model" not in place  # the one axis, every device on it
     assert sorted(s["attrs"]["model"] for s in spans if s["name"] == "trainer.export") == ["gnn", "mlp"]
 
 
